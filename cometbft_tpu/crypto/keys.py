@@ -170,7 +170,16 @@ class Ed25519BatchVerifier:
         n = len(self._pubs)
         eff = self._batch_size or 1 << (n - 1).bit_length()
         from ..libs.jax_cache import is_device_platform, ledger
-        if not is_device_platform() and eff > 64 \
+        on_device = is_device_platform()
+        if on_device:
+            # pad up to the pallas lane tile: a sub-TILE batch would
+            # take the XLA kernel (ops/ed25519._rlc_dispatch alignment
+            # check) and pay a separate multi-minute compile per
+            # width, where the TILE bucket is the one blocksync
+            # already keeps warm
+            from ..ops.pallas_verify import TILE
+            eff = -(-eff // TILE) * TILE
+        if not on_device and eff > 64 \
                 and not ledger().warm_in_process("ed25519-rlc", eff):
             # CPU backend: jitting the RLC kernel at batch >= 256
             # takes minutes and can crash the XLA:CPU compiler
@@ -191,7 +200,7 @@ class Ed25519BatchVerifier:
         from ..ops.ed25519 import verify_batch
         with ledger().compile_guard("ed25519-rlc", eff):
             out = verify_batch(self._pubs, self._msgs, self._sigs,
-                               batch_size=self._batch_size)
+                               batch_size=eff)
         oks = [bool(v) for v in out]
         return all(oks), oks
 

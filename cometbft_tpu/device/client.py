@@ -14,12 +14,11 @@ import threading
 from typing import Dict, List, Optional, Tuple
 
 from ..libs.env import env_float
+from ..libs.jax_cache import DEVICE_SERVER_ENV as ENV_VAR  # host:port
 from ..trace.context import ctx_of
 from . import health
 from .protocol import (decode_response, encode_request, recv_frame,
                        send_frame)
-
-ENV_VAR = "COMETBFT_TPU_DEVICE_SERVER"  # host:port
 
 # Per-request deadline = base + per_sig * lanes (env-overridable): a
 # 64-lane consensus commit should fail over to local verification in

@@ -1,5 +1,5 @@
 """Device health supervisor: the per-process state machine that decides
-whether the verification backend (TPU tunnel / device server / pipeline
+whether the verification backend (TPU / device server / pipeline
 backend) may be trusted with signature batches.
 
 PR 2's watchdog made a wedged device survivable but paid for it with a

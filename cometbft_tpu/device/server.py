@@ -336,7 +336,11 @@ def main(argv=None) -> int:
                     help="mesh sig-axis width (0 = auto)")
     ap.add_argument("--tiles-per-shard", type=int, default=4)
     args = ap.parse_args(argv)
-    from ..libs.jax_cache import enable_compile_cache
+    # this process is the chip's owner, nobody's client — even when it
+    # inherits the address its clients are pointed at
+    import os
+    from ..libs.jax_cache import DEVICE_SERVER_ENV, enable_compile_cache
+    os.environ.pop(DEVICE_SERVER_ENV, None)
     enable_compile_cache()
     host, _, port = args.laddr.rpartition(":")
     srv = DeviceServer(host or "127.0.0.1", int(port),

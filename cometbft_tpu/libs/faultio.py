@@ -9,7 +9,7 @@ faults are each a pure function of (seed, schedule) — the same
 determinism contract as simnet's virtual clock and seeded PRNGs, so a
 failing (scenario, seed, plan) triple replays byte-identically.
 
-Fault taxonomy (docs/STORAGE.md):
+Fault classes (docs/STORAGE.md):
   * torn write — the Nth write through a label persists only a prefix
     (explicit `keep` offset, or seeded) and then the process "loses
     power": `fail_point("faultio:torn-write")` is crossed (env modes

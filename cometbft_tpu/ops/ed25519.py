@@ -260,7 +260,7 @@ verify_rlc_kernel_pallas = jax.jit(verify_rlc_core_pallas,
 
 
 def use_pallas_rlc() -> bool:
-    """Pallas point-stage on real TPU backends; XLA path on CPU (the
+    """Pallas point-stage on a TPU backend; XLA path on CPU (the
     mosaic kernels target the chip; interpret mode is for tests)."""
     import os
     env = os.environ.get("COMETBFT_TPU_PALLAS")
@@ -401,10 +401,10 @@ _pallas_broken = False
 
 # Mosaic miscompile canary (reference posture: attribution safety,
 # types/validation.go:306-315 — a batch verifier may NEVER accept what
-# per-signature verification would reject). The sticky exception latch
-# above catches pallas kernels that *crash*; a kernel that silently
-# MISCOMPILES and returns batch_ok=True on a batch containing an
-# invalid signature would accept a forgery. So every CANARY_INTERVAL-th
+# per-signature verification would reject). A pallas kernel that fails
+# to lower, compile or run RAISES (see _rlc_dispatch); a kernel that
+# silently MISCOMPILES and returns batch_ok=True on a batch containing
+# an invalid signature would accept a forgery. So every CANARY_INTERVAL-th
 # aligned dispatch (including the very first — node prewarm and
 # device/server._warm both route here) first re-runs the pallas kernel
 # on the same batch with one lane's s deliberately corrupted: the
@@ -419,6 +419,12 @@ def canary_stats() -> dict:
     """Snapshot of mosaic-canary counters ({"runs", "trips"}) — wired
     into the Prometheus registry as callback gauges (node/node.py)."""
     return dict(_canary)
+
+
+def pallas_degraded() -> bool:
+    """True once a canary trip has latched this process onto the XLA
+    kernel for good — exported beside the canary counters."""
+    return _pallas_broken
 
 
 @functools.lru_cache(maxsize=8)
@@ -470,28 +476,25 @@ def _run_canary(batch_size: int, n_blocks: int) -> None:
 
 
 def _rlc_dispatch(pub_a, sig_a, hb, hn, z):
-    """RLC verify via the pallas point-stage on device platforms,
-    degrading PERMANENTLY to the proven XLA kernel on a real pallas
-    failure (mosaic compile/runtime errors must not crash blocksync,
-    and a failing compile must not be re-paid per batch) or on a
-    canary-detected silent miscompile (see _run_canary). Batches not
-    aligned to the pallas lane tile take the XLA kernel WITHOUT
-    tripping the sticky latch — a small one-off verify must not
-    disable pallas for later aligned blocksync tiles."""
-    global _pallas_broken, _dispatches
+    """RLC verify via the pallas point-stage on a TPU backend.
+
+    The ONE degradation to the XLA kernel is the canary's: a kernel
+    caught accepting a known-invalid lane is never trusted again
+    (`_pallas_broken`, counted in canary_stats). A pallas kernel that
+    fails to lower, compile or run is a bug in the tree, not a
+    condition to route around — the exception propagates, so a device
+    host can never end up measuring or serving the XLA kernel under
+    the pallas path's name. Batches not aligned to the pallas lane
+    tile take the XLA kernel by design (a small one-off verify)."""
+    global _dispatches
     from .pallas_verify import TILE
     aligned = pub_a.shape[0] % TILE == 0
     if use_pallas_rlc() and aligned and not _pallas_broken:
-        try:
-            if _dispatches % _CANARY_INTERVAL == 0:
-                _run_canary(pub_a.shape[0], hb.shape[1])
-            _dispatches += 1
-            if not _pallas_broken:
-                return verify_rlc_kernel_pallas(pub_a, sig_a, hb, hn, z)
-        except Exception:  # noqa: BLE001
-            _pallas_broken = True
-            import traceback
-            traceback.print_exc()
+        if _dispatches % _CANARY_INTERVAL == 0:
+            _run_canary(pub_a.shape[0], hb.shape[1])
+        _dispatches += 1
+        if not _pallas_broken:
+            return verify_rlc_kernel_pallas(pub_a, sig_a, hb, hn, z)
     return verify_rlc_kernel(pub_a, sig_a, hb, hn, z)
 
 
@@ -514,7 +517,7 @@ def prewarm_verify_kernels(batch_size: int = 4096,
                                             batch_size, msg_cap)
     z = make_rlc_coefficients(batch_size)
     # warm the kernel the live path will actually dispatch to (pallas
-    # on device platforms, with its own sticky XLA degradation). The
+    # on a TPU backend, behind its miscompile canary). The
     # compile guard attributes the warm in the ledger AND marks the
     # bucket process-warm, which is what lifts the 64-lane CPU clamp
     # in crypto/keys.Ed25519BatchVerifier for this bucket.

@@ -35,7 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .field import MASK, LIMB_BITS, FOUR_P_LIMBS, bc
+from .field import MASK, LIMB_BITS, FOUR_P_LIMBS
+from .scalar import bytes_to_limbs
 
 # lanes per grid program. 512 int32 lanes x (2 tables of 16 entries x
 # 4 coords x 16 limbs) = 4MB of table scratch, well under the ~16MB
@@ -50,8 +51,8 @@ A_WINDOWS = 64   # radix-16 digits of t_i = z_i * k_i (256-bit)
 R_WINDOWS = 32   # radix-16 digits of the 128-bit z_i
 N_WINDOWS = A_WINDOWS + R_WINDOWS
 TAIL = 8         # lanes left unreduced per (tile, window) — folded by
-#                  the XLA epilogue; keeps the in-kernel tree off the
-#                  worst sub-128-lane shapes
+#                  the epilogue kernel
+LANES = 128      # vreg lane width: the narrowest row a kernel stores
 
 
 # --- field/point helpers on (16, T) arrays, traced INSIDE kernels ---------
@@ -101,25 +102,37 @@ def _mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     p = au[:, None] * bu[None]                     # (16, 16, ...) exact
     lo = (p & MASK).astype(jnp.int32)
     hi = (p >> LIMB_BITS).astype(jnp.int32)
-    zero = jnp.zeros_like(jnp.broadcast_to(a[0], p.shape[2:]))
-    acc = [zero for _ in range(32)]
+    # schoolbook as pad-shift-add (field.spread_mul's form): row i of
+    # the outer product lands at limb offset i (lo) / i+1 (hi) of a
+    # 32-limb accumulator — whole (16, ...) rows at a time, so a
+    # multiply is ~100 equations to trace and lower, not ~1,750
+    zeros = jnp.zeros_like(lo[0])                  # (16, ...)
+
+    def shifted(row, off):
+        wide = jnp.concatenate([row, zeros], axis=0)       # (32, ...)
+        return pltpu.roll(wide, off, 0) if off else wide
+
+    acc = shifted(lo[0], 0)
     for i in range(16):
-        for j in range(16):
-            acc[i + j] = acc[i + j] + lo[i, j]
-            acc[i + j + 1] = acc[i + j + 1] + hi[i, j]
-    folded = [acc[k] + 38 * acc[k + 16] for k in range(16)]
-    return _carry(jnp.stack(folded))
+        if i:
+            acc = acc + shifted(lo[i], i)
+        acc = acc + shifted(hi[i], i + 1)
+    return _carry(acc[:16] + 38 * acc[16:])
 
 
 # Pallas kernels may not close over constant arrays — the field
-# constants ride in as a (5, 16) input:
-# row 0 = 4p, 1 = 2d, 2 = p, 3 = d, 4 = sqrt(-1).
+# constants ride in as a (6, 16) input:
+# row 0 = 4p, 1 = 2d, 2 = p, 3 = d, 4 = sqrt(-1), 5 = 1.
+N_CONSTS = 6
+
+
 def _consts_array() -> jnp.ndarray:
     from .edwards import D_LIMBS, SQRT_M1_LIMBS, TWO_D_LIMBS
-    from .field import P_LIMBS
+    from .field import P_LIMBS, limbs_from_int
     import numpy as np
     return jnp.asarray(np.stack([FOUR_P_LIMBS, TWO_D_LIMBS, P_LIMBS,
-                                 D_LIMBS, SQRT_M1_LIMBS]),
+                                 D_LIMBS, SQRT_M1_LIMBS,
+                                 limbs_from_int(1)]),
                        dtype=jnp.int32)
 
 
@@ -162,9 +175,17 @@ def _pt_double(p: jnp.ndarray, four_p) -> jnp.ndarray:
     return jnp.stack([_mul(e, f), _mul(g, h), _mul(f, g), _mul(e, h)])
 
 
-def _pt_identity(t: int) -> jnp.ndarray:
-    z = jnp.zeros((16, t), dtype=jnp.int32)
-    one = z.at[0].set(1)
+def _one_like(x: jnp.ndarray, one_limbs) -> jnp.ndarray:
+    """The field element 1 broadcast to x's (16, *batch) shape. The
+    constant limb row rides in with the consts block: a scatter
+    (`.at[0].set(1)`) has no Mosaic lowering."""
+    return jnp.zeros_like(x) + _bcast(one_limbs, x)
+
+
+def _pt_identity(like: jnp.ndarray, one_limbs) -> jnp.ndarray:
+    """Identity point shaped like the packed point `like` (4, 16, T)."""
+    z = jnp.zeros_like(like[0])
+    one = _one_like(z, one_limbs)
     return jnp.stack([z, one, one, z])
 
 
@@ -233,25 +254,22 @@ def _pow2523(z: jnp.ndarray) -> jnp.ndarray:
     return _mul(t0, z)
 
 
-def _bytes_to_limbs(b: jnp.ndarray) -> jnp.ndarray:
-    """(32, T) int32 bytes -> (16, T) 16-bit limbs (scalar.bytes_to_limbs)."""
-    return b[0::2] | (b[1::2] << 8)
-
-
-def _decompress(b: jnp.ndarray, consts):
-    """(32, T) int32 bytes -> packed point (4, 16, T), valid (T,).
-    ZIP-215 semantics, mirroring edwards.pt_decompress."""
+def _decompress(enc: jnp.ndarray, consts):
+    """(16, T) int32 16-bit limbs of the 32-byte encoding (bit 255 =
+    the x sign, still in limb 15) -> packed point (4, 16, T), valid
+    (T,). ZIP-215 semantics, mirroring edwards.pt_decompress. The
+    bytes are paired into limbs on the XLA side of the call: a strided
+    value slice lowers to a gather Mosaic refuses."""
     four_p = consts[0]
     p_limbs = consts[2]
     d_limbs = consts[3]
     sqrt_m1 = consts[4]
 
-    sign = (b[31] >> 7) & 1
-    yb = jnp.concatenate([b[:31], (b[31] & 0x7F)[None]], axis=0)
-    y = _bytes_to_limbs(yb)
+    sign = (enc[15] >> 15) & 1
+    y = jnp.stack([enc[i] for i in range(15)] + [enc[15] & 0x7FFF])
 
     yy = _mul(y, y)
-    one = jnp.zeros_like(y).at[0].set(1)
+    one = _one_like(y, consts[5])
     u = _sub(yy, one, four_p)
     v = _add(_mul(yy, d_limbs), one)
     v3 = _mul(_mul(v, v), v)
@@ -287,7 +305,7 @@ def pt_add_tiled(p: jnp.ndarray, q: jnp.ndarray,
         _pt_add_kernel,
         out_shape=jax.ShapeDtypeStruct(p.shape, jnp.int32),
         grid=grid,
-        in_specs=[pl.BlockSpec((5, 16), lambda i: (0, 0),
+        in_specs=[pl.BlockSpec((N_CONSTS, 16), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
                   spec, spec],
         out_specs=spec,
@@ -298,24 +316,39 @@ def pt_add_tiled(p: jnp.ndarray, q: jnp.ndarray,
 # --- kernel 2: fused table-build + select + lane-tree ----------------------
 
 def _tree_to_tail(pt: jnp.ndarray, four_p, two_d) -> jnp.ndarray:
-    """(4, 16, T) -> (4, 16, TAIL) pairwise-halving point reduction."""
+    """(4, 16, T) -> (4, 16, min(T, LANES)) pairwise-halving point
+    reduction; lanes [0, TAIL) of the result hold the TAIL partial
+    sums, the lanes above them are scratch.
+
+    Down to one vreg width the halves are lane-aligned slices. Below
+    it a half is not a slice Mosaic can take for free, so the upper
+    half is ROTATED onto the lower instead (lane i picks up lane i+h —
+    the same operand pairing, so the sums are bit-identical to the
+    slicing tree) and the store stays a full, lane-dense vreg row."""
     n = pt.shape[-1]
-    while n > TAIL:
+    while n > LANES:
         h = n // 2
         pt = _pt_add(pt[..., :h], pt[..., h:], four_p, two_d)
         n = h
+    h = n // 2
+    while h >= TAIL:
+        pt = _pt_add(pt, pltpu.roll(pt, n - h, 2), four_p, two_d)
+        h //= 2
     return pt
 
 
-def _build_table(pt: jnp.ndarray, tab_ref, four_p, two_d) -> None:
+def _build_table(pt: jnp.ndarray, tab_ref, consts) -> None:
     """tab_ref (16, 4, 16, T) <- [j]pt for j in 0..15 (entry leading)."""
-    t = pt.shape[-1]
-    tab_ref[0] = _pt_identity(t)
+    four_p, two_d = consts[0], consts[1]
+    tab_ref[0] = _pt_identity(pt, consts[5])
     tab_ref[1] = pt
-    acc = pt
-    for j in range(2, 16):
+
+    def step(j, acc):
         acc = _pt_add(acc, pt, four_p, two_d)
         tab_ref[j] = acc
+        return acc
+
+    jax.lax.fori_loop(2, 16, step, pt)
 
 
 def _select(tab_ref, dig: jnp.ndarray) -> jnp.ndarray:
@@ -331,8 +364,8 @@ def _select(tab_ref, dig: jnp.ndarray) -> jnp.ndarray:
 def _rlc_kernel(c_ref, a_ref, r_ref, tdig_ref, zdig_ref, o_ref,
                 tab_a, tab_r):
     four_p, two_d = c_ref[0], c_ref[1]
-    _build_table(a_ref[:], tab_a, four_p, two_d)
-    _build_table(r_ref[:], tab_r, four_p, two_d)
+    _build_table(a_ref[:], tab_a, c_ref)
+    _build_table(r_ref[:], tab_r, c_ref)
 
     def a_window(w, _):
         sel = _select(tab_a, tdig_ref[w])
@@ -369,15 +402,18 @@ def rlc_window_sums_impl(a_pt: jnp.ndarray, r_pt: jnp.ndarray,
     n = a_pt.shape[-1]
     assert n % TILE == 0, (n, TILE)
     g = n // TILE
+    # the kernel stores a full lane-dense row per window; only its
+    # first TAIL lanes are partial sums (see _tree_to_tail)
+    row = min(TILE, LANES)
     pt_spec = pl.BlockSpec((4, 16, TILE), lambda i: (0, 0, i),
                            memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _rlc_kernel,
-        out_shape=jax.ShapeDtypeStruct((g, N_WINDOWS, 4, 16, TAIL),
+        out_shape=jax.ShapeDtypeStruct((g, N_WINDOWS, 4, 16, row),
                                        jnp.int32),
         grid=(g,),
         in_specs=[
-            pl.BlockSpec((5, 16), lambda i: (0, 0),
+            pl.BlockSpec((N_CONSTS, 16), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
             pt_spec, pt_spec,
             pl.BlockSpec((A_WINDOWS, TILE), lambda i: (0, i),
@@ -385,7 +421,7 @@ def rlc_window_sums_impl(a_pt: jnp.ndarray, r_pt: jnp.ndarray,
             pl.BlockSpec((R_WINDOWS, TILE), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, N_WINDOWS, 4, 16, TAIL),
+        out_specs=pl.BlockSpec((1, N_WINDOWS, 4, 16, row),
                                lambda i: (i, 0, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -394,6 +430,7 @@ def rlc_window_sums_impl(a_pt: jnp.ndarray, r_pt: jnp.ndarray,
         ],
         interpret=interpret,
     )(_consts_array(), a_pt, r_pt, t_dig, z_dig)
+    return out[..., :TAIL]
 
 
 rlc_window_sums = jax.jit(rlc_window_sums_impl,
@@ -402,8 +439,8 @@ rlc_window_sums = jax.jit(rlc_window_sums_impl,
 
 # --- kernel 3: tiled ZIP-215 point decompression ---------------------------
 
-def _decompress_kernel(c_ref, b_ref, pt_ref, ok_ref):
-    pt, valid = _decompress(b_ref[:], c_ref)
+def _decompress_kernel(c_ref, enc_ref, pt_ref, ok_ref):
+    pt, valid = _decompress(enc_ref[:], c_ref)
     pt_ref[:] = pt
     ok_ref[:] = valid[None].astype(jnp.int32)
 
@@ -417,15 +454,16 @@ def pt_decompress_tiled_impl(enc: jnp.ndarray,
     (packed (4,16,N) int32, valid (N,) bool)."""
     n = enc.shape[-1]
     assert n % TILE == 0, (n, TILE)
+    limbs = bytes_to_limbs(enc)                        # (16, N) int32
     pt, ok = pl.pallas_call(
         _decompress_kernel,
         out_shape=(jax.ShapeDtypeStruct((4, 16, n), jnp.int32),
                    jax.ShapeDtypeStruct((1, n), jnp.int32)),
         grid=(n // TILE,),
         in_specs=[
-            pl.BlockSpec((5, 16), lambda i: (0, 0),
+            pl.BlockSpec((N_CONSTS, 16), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((32, TILE), lambda i: (0, i),
+            pl.BlockSpec((16, TILE), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(pl.BlockSpec((4, 16, TILE), lambda i: (0, 0, i),
@@ -433,7 +471,7 @@ def pt_decompress_tiled_impl(enc: jnp.ndarray,
                    pl.BlockSpec((1, TILE), lambda i: (0, i),
                                 memory_space=pltpu.VMEM)),
         interpret=interpret,
-    )(_consts_array(), enc.astype(jnp.int32))
+    )(_consts_array(), limbs)
     return pt, ok[0].astype(bool)
 
 
@@ -448,55 +486,60 @@ pt_decompress_tiled = jax.jit(pt_decompress_tiled_impl,
 # in XLA on the chip those ops are latency-bound at ~1-2ms each, which
 # would cap the whole verify once the wide stages are fused. One
 # single-program kernel keeps the entire tail in VMEM.
+#
+# Layout: the WINDOW index rides the lane axis, padded 96 -> LANES
+# (lanes 0..63 the -A windows, 64..95 the -R windows, 96..127 identity
+# points), and the M partials per window are M such rows on the
+# leading axis. Every access is then a whole vreg-aligned row, and the
+# steps that move data ACROSS windows (combine, Horner) are lane
+# rotations of a row rather than sub-vreg slices or a dynamic slice of
+# a value, neither of which Mosaic lowers. Lanes past the ones a step
+# reads carry well-defined scratch (limbs stay < 2^16) that nothing
+# consumes.
 
-def _epilogue_kernel(c_ref, w_ref, btab_ref, sdig_ref, ok_ref):
+def _epilogue_kernel(c_ref, w_ref, sel_ref, ok_ref):
     four_p = c_ref[0]
     two_d = c_ref[1]
     p_limbs = c_ref[2]
 
-    # fold the (96, M) lane axis: coords (4, 16, 96, M) -> (4, 16, 96)
-    w = w_ref[:]
-    m = w.shape[-1]
-    while m > 1:
-        h = m // 2
-        w = _pt_add(w[..., :h], w[..., h:], four_p, two_d)
-        m = h
-    w = w[..., 0]                                     # (4, 16, 96)
+    # fold the M partials of every window, (M, 4, 16, LANES) -> one
+    # row; row by row off the ref, so the live set stays one row wide
+    # however many tiles fed the fold (a halving tree over the whole
+    # block overflows VMEM at 8192 lanes)
+    def fold(j, acc):
+        return _pt_add(acc, w_ref[j], four_p, two_d)
 
-    # combine: windows 0..31 of -A pick up -R's 32 windows
-    lo = _pt_add(w[..., :R_WINDOWS], w[..., A_WINDOWS:],
-                 four_p, two_d)
-    w = jnp.concatenate([lo, w[..., R_WINDOWS:A_WINDOWS]], axis=-1)
+    w = jax.lax.fori_loop(1, w_ref.shape[0], fold, w_ref[0])
 
-    # fold [S]B via the shared base table: btab (16, 4, 16),
-    # sdig (64, 1) -> selected (4, 16, 64)
-    sdig = sdig_ref[:, 0]                             # (64,)
-    sel = jnp.zeros((4, 16, A_WINDOWS), dtype=jnp.int32)
-    for e in range(16):
-        mask = (sdig == e).astype(jnp.int32)[None, None, :]
-        sel = sel + btab_ref[e][:, :, None] * mask
-    w = _pt_add(w, sel, four_p, two_d)
+    # combine: windows 0..31 of -A pick up -R's 32 windows (lanes
+    # 64..95 rotate onto 0..31; lanes 32..63 pick up the identity pad)
+    w = _pt_add(w, pltpu.roll(w, LANES - A_WINDOWS, 2), four_p, two_d)
 
-    # radix-16 Horner over the 64 windows, most significant first
-    def step(i, acc):
-        idx = A_WINDOWS - 2 - i
+    # fold [S]B: sel holds the shared-base table entries the radix-16
+    # digits of S select, one window per lane
+    w = _pt_add(w, sel_ref[:], four_p, two_d)
+
+    # radix-16 Horner over the 64 windows, most significant first, read
+    # off lane 0: each step rotates the next lower window into lane 0
+    def step(_, carry):
+        acc, wr = carry
+        wr = pltpu.roll(wr, 1, 2)
         acc = _pt_double(acc, four_p)
         acc = _pt_double(acc, four_p)
         acc = _pt_double(acc, four_p)
         acc = _pt_double(acc, four_p)
-        wi = jax.lax.dynamic_slice(
-            w, (0, 0, idx), (4, 16, 1))[..., 0]
-        return _pt_add(acc, wi, four_p, two_d)
+        return _pt_add(acc, wr, four_p, two_d), wr
 
-    acc = w[..., A_WINDOWS - 1]
-    acc = jax.lax.fori_loop(0, A_WINDOWS - 1, step, acc)
+    top = pltpu.roll(w, LANES - (A_WINDOWS - 1), 2)   # lane 0 <- w[63]
+    acc, _ = jax.lax.fori_loop(0, A_WINDOWS - 1, step, (top, top))
 
-    # clear the cofactor, then the projective identity test
+    # clear the cofactor, then the projective identity test; the
+    # verdict is lane 0 of a lane-dense row
     acc = _pt_double(_pt_double(_pt_double(acc, four_p), four_p), four_p)
     x_zero = jnp.all(_canonical(acc[0], p_limbs) == 0, axis=0)
     yz_eq = jnp.all(
         _canonical(_sub(acc[1], acc[2], four_p), p_limbs) == 0, axis=0)
-    ok_ref[0, 0] = (x_zero & yz_eq).astype(jnp.int32)
+    ok_ref[:] = (x_zero & yz_eq)[None].astype(jnp.int32)
 
 
 def rlc_epilogue_impl(folded: jnp.ndarray, b_tab: jnp.ndarray,
@@ -508,22 +551,33 @@ def rlc_epilogue_impl(folded: jnp.ndarray, b_tab: jnp.ndarray,
     """folded: (4, 16, 96, M) window partials (M = G*TAIL lanes);
     b_tab: (16, 4, 16) shared [j]B table; s_dig: (64,) radix-16 digits
     of S = sum(z_i s_i). Returns the scalar batch verdict (bool)."""
+    from .edwards import _lookup_shared
     m = folded.shape[-1]
-    assert (m & (m - 1)) == 0, m   # power-of-two fold
+    # partials leading, windows onto the lane axis, identity-padded to
+    # a full row
+    ident = _consts_array()[5]
+    zero = jnp.zeros_like(ident)
+    pad = jnp.stack([zero, ident, ident, zero])            # (4, 16)
+    pad = jnp.broadcast_to(pad[None, :, :, None],
+                           (m, 4, 16, LANES - N_WINDOWS))
+    w = jnp.concatenate(
+        [jnp.transpose(folded, (3, 0, 1, 2)), pad], axis=-1)
+    # the [S]B table entries, window per lane; a select by 64 digits
+    # of one shared 16-entry table is a lookup, not point math
+    sel = jnp.stack(_lookup_shared(b_tab.astype(jnp.int32), s_dig))
+    sel = jnp.concatenate(
+        [sel, jnp.zeros((4, 16, LANES - A_WINDOWS), jnp.int32)], axis=-1)
     ok = pl.pallas_call(
         _epilogue_kernel,
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.int32),
         in_specs=[
-            pl.BlockSpec((5, 16), memory_space=pltpu.VMEM),
-            pl.BlockSpec((4, 16, N_WINDOWS, m),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((16, 4, 16), memory_space=pltpu.VMEM),
-            pl.BlockSpec((A_WINDOWS, 1), memory_space=pltpu.VMEM),
+            pl.BlockSpec((N_CONSTS, 16), memory_space=pltpu.VMEM),
+            pl.BlockSpec((m, 4, 16, LANES), memory_space=pltpu.VMEM),
+            pl.BlockSpec((4, 16, LANES), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1), memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((1, LANES), memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(_consts_array(), folded, b_tab.astype(jnp.int32),
-      s_dig.reshape(A_WINDOWS, 1).astype(jnp.int32))
+    )(_consts_array(), w, sel)
     return ok[0, 0].astype(bool)
 
 

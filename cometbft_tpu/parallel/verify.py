@@ -39,11 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..ops import edwards as ed
 from ..ops.ed25519 import rlc_finish_stage, rlc_local_stage, verify_core
 from ..ops.scalar import sc_add
@@ -55,14 +50,9 @@ _ALL_AXES = (COMMIT_AXIS, SIG_AXIS)
 def _smap(f, mesh, in_specs, out_specs):
     """shard_map with replication checking off (the RLC path's
     batch_ok is replicated BY CONSTRUCTION — all_gather + identical
-    math — which the checker cannot always infer), across the jax
-    API rename (check_vma >= 0.9, check_rep before)."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover — older jax
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    math — which the checker cannot always infer)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # --- exact voting-power planes (int64 <-> 4x16-bit int32) ---------------------
 

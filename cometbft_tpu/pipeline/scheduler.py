@@ -27,7 +27,7 @@ does. `depth=1` IS the synchronous path, one tile at a time.
 
 Wedge handling: every dispatch is bounded by the DeviceWatchdog; a
 deadline miss drains this and all in-flight tiles to the CPU fallback
-(native per-signature verify) so a wedged TPU tunnel degrades catch-up
+(native per-signature verify) so a wedged device degrades catch-up
 speed, never liveness. With a DeviceSupervisor attached (device/
 health.py) the drain is no longer a one-way door: the scheduler probes
 the suspect device with a cheap known-answer batch once per backoff
@@ -198,8 +198,8 @@ class DeviceClientBackend:
 class FixedLatencyBackend:
     """Bench/test stub of an RTT-bound device: every dispatch answers a
     fixed latency after submit, independent of other in-flight
-    dispatches (the tunnel's cost is dominated by round-trip + queueing,
-    not lane occupancy). verify_fn=None answers all-true (valid-chain
+    dispatches (a remote device's cost is dominated by round-trip +
+    queueing, not lane occupancy). verify_fn=None answers all-true (valid-chain
     benchmarks); otherwise verdicts are computed in the timer thread."""
 
     def __init__(self, latency_s: float, verify_fn=None):

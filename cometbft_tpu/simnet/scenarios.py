@@ -195,7 +195,7 @@ def _setup_torn_storage(sim: Simulation) -> None:
 
 def _setup_blocksync_wedge(sim: Simulation) -> None:
     # node 0 joins late and catches up through the PIPELINED blocksync
-    # engine whose verify backend never answers (the wedged-TPU-tunnel
+    # engine whose verify backend never answers (the wedged-device
     # model, docs/PERF.md): the watchdog must drain every tile to the
     # CPU fallback and still complete the sync — a wedged device
     # degrades catch-up speed, never liveness

@@ -5,6 +5,7 @@ attribution inside a batch, and ZIP-215 permissive decoding semantics
 (reference: crypto/ed25519/ed25519.go:40-42,181-188)."""
 
 import numpy as np
+import pytest
 
 from cometbft_tpu.crypto import ref_ed25519 as ref
 from cometbft_tpu.ops.ed25519 import verify_batch
@@ -146,7 +147,7 @@ def test_cpu_clamp_lifts_on_process_warm_bucket(tmp_path, monkeypatch):
             return np.ones((len(pubs),), dtype=bool)
 
         monkeypatch.setattr(ops_ed, "verify_batch", fake)
-        monkeypatch.setattr(jax_cache, "first_configured_platform",
+        monkeypatch.setattr(jax_cache, "backend_platform",
                             lambda: "cpu")
 
         seed = b"\x07" * 32
@@ -184,3 +185,72 @@ def test_cpu_clamp_lifts_on_process_warm_bucket(tmp_path, monkeypatch):
         assert calls["kernel"] == 1
     finally:
         jax_cache.reset_ledger()
+
+
+# --- the device sniff and the compile-cache decision (libs/jax_cache) ---------
+
+def test_device_sniff_answers_from_the_backend(monkeypatch):
+    """The sniff is the truth of the initialised backend: the suite runs
+    on the CPU platform, so it answers cpu / False, and every consumer
+    takes its native branch; a TPU backend flips them all."""
+    from cometbft_tpu.libs import jax_cache
+    from cometbft_tpu.node.node import Node
+    from cometbft_tpu.ops import ed25519 as e5
+    from cometbft_tpu.ops.pallas_verify import TILE
+
+    monkeypatch.delenv(jax_cache.DEVICE_SERVER_ENV, raising=False)
+    monkeypatch.delenv("COMETBFT_TPU_PALLAS", raising=False)
+    assert jax_cache.backend_platform() == "cpu"
+    assert not jax_cache.is_device_platform()
+    assert Node._device_batch_size() == 0
+    assert not e5.use_pallas_rlc()
+
+    monkeypatch.setattr(jax_cache, "backend_platform", lambda: "tpu")
+    assert jax_cache.is_device_platform()
+    assert Node._device_batch_size() == TILE
+    assert e5.use_pallas_rlc()
+
+
+@pytest.mark.parametrize("platform,env_dir,want", [
+    ("cpu", None, (False, None)),
+    ("cpu", "/some/dir", (False, None)),
+    ("tpu", None, (True, "DEFAULT")),
+    ("tpu", "/some/dir", (True, None)),
+])
+def test_compile_cache_plan(platform, env_dir, want):
+    """On a TPU the persistent cache is ON: where
+    JAX_COMPILATION_CACHE_DIR is set JAX's own handling stands and the
+    code sets no directory, otherwise the fixed <checkout>/.jax_cache.
+    On cpu it stays off."""
+    import os
+    from cometbft_tpu.libs import jax_cache
+    on, directory = jax_cache.compile_cache_plan(platform, env_dir)
+    if want[1] == "DEFAULT":
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = (True, os.path.join(root, ".jax_cache"))
+    assert (on, directory) == want
+
+
+def test_enable_compile_cache_leaves_env_dir_alone(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, enable_compile_cache() on a
+    TPU backend updates no directory in code; unset, it sets the fixed
+    checkout path. (jax.config.update is intercepted — nothing here
+    may switch the real suite's cache on.)"""
+    import jax
+    from cometbft_tpu.libs import jax_cache
+    updates = {}
+    monkeypatch.setattr(jax_cache, "backend_platform", lambda: "tpu")
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    jax_cache.enable_compile_cache()
+    assert "jax_compilation_cache_dir" not in updates
+    assert "jax_enable_compilation_cache" not in updates  # stays on
+    assert jax_cache.cache_dir() == "/some/dir"
+
+    updates.clear()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    jax_cache.enable_compile_cache()
+    assert updates["jax_compilation_cache_dir"] == jax_cache.DEFAULT_CACHE_DIR
+    assert jax_cache.cache_dir() == jax_cache.DEFAULT_CACHE_DIR

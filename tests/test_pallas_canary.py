@@ -1,6 +1,6 @@
 """Mosaic-miscompile canary tests (ops/ed25519._run_canary).
 
-The sticky exception latch only catches pallas kernels that *crash*; a
+A pallas kernel that crashes raises (test_pallas_failure_propagates); a
 silent miscompile returning batch_ok=True on a batch with an invalid
 lane would accept a forged signature (the reference's batch verifier
 must never accept what per-sig verify rejects, types/validation.go:
@@ -81,6 +81,26 @@ def test_honest_kernel_passes_canary(pallas_env, monkeypatch):
     got = e5.verify_batch(pubs, msgs, sigs, batch_size=BATCH)
     assert got.all()
     assert e5.canary_stats()["runs"] == 1
+
+
+def test_pallas_failure_propagates(pallas_env, monkeypatch):
+    """A pallas kernel that fails to lower, compile or run is a bug in
+    the tree, not a condition to route around: _rlc_dispatch re-raises
+    instead of latching onto the XLA kernel and answering with ITS
+    verdicts — a device host can never serve XLA under pallas's name.
+    The latch stays clear, so the failure repeats on the next dispatch
+    rather than hiding after the first."""
+    def refused(pub, sig, hb, hn, z):
+        raise NotImplementedError(
+            "Unimplemented primitive in Pallas TPU lowering: scatter")
+
+    monkeypatch.setattr(e5, "verify_rlc_kernel_pallas", refused)
+    pubs, msgs, sigs = _batch()
+    for _ in range(2):
+        with pytest.raises(NotImplementedError, match="scatter"):
+            e5.verify_batch(pubs, msgs, sigs, batch_size=BATCH)
+        assert not e5._pallas_broken and not e5.pallas_degraded()
+    assert e5.canary_stats()["trips"] == 0
 
 
 def test_canary_batch_construction(pallas_env):

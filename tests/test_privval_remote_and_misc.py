@@ -27,7 +27,7 @@ def test_remote_signer_end_to_end(tmp_path):
     server = SignerServer(pv, *client.addr)
     server.start()
     try:
-        # identity through the tunnel
+        # identity through the link
         assert client.get_pub_key().bytes_() == pv.get_pub_key().bytes_()
 
         bid = BlockID(b"\x21" * 32, PartSetHeader(1, b"\x22" * 32))

@@ -60,7 +60,7 @@ def test_blocksync_wedge_completes_via_watchdog():
     """Mid-sync device wedge: the late joiner's pipelined blocksync
     engine dispatches to a backend that never answers; the watchdog
     must drain every tile to the CPU fallback and the sync must still
-    complete (liveness through a wedged tunnel)."""
+    complete (liveness through a wedged device)."""
     r = run_scenario("blocksync-wedge", 1, quick=True)
     assert r.ok, r.violations
     wedge = [ln for ln in r.log_lines if "blocksync_wedge" in ln]

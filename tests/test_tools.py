@@ -5,7 +5,10 @@ scripts/wal2json + json2wal) and the randomized e2e manifest generator
 import io
 import json
 import os
+import subprocess
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "tools"))
@@ -81,3 +84,41 @@ def test_manifest_generator_deterministic():
     c = generate_manifests(seed=8, n=5)
     assert [(m.validators, m.timeout_commit_ms) for m in a] != \
         [(m.validators, m.timeout_commit_ms) for m in c]
+
+
+# --- chip-only entry points refuse to answer without a chip -------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run_cpu(script):
+    """Run a repo script in a child handed the CPU platform through its
+    environment; (returncode, stdout lines)."""
+    r = subprocess.run(
+        [sys.executable, os.path.join(_ROOT, script)],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=_ROOT,
+        capture_output=True, text=True, timeout=300)
+    return r.returncode, r.stdout.strip().splitlines()
+
+
+def test_chip_smoke_fails_without_a_tpu():
+    """chip_smoke.py on the CPU platform: non-zero exit, and the last
+    stdout line is the JSON verdict with ok=false and the device as JAX
+    reports it — never a pass on a backend that is not the chip."""
+    rc, out = _run_cpu("chip_smoke.py")
+    assert rc != 0
+    verdict = json.loads(out[-1])
+    assert verdict["ok"] is False
+    assert verdict["device"]["platform"] == "cpu"
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+
+
+@pytest.mark.parametrize("script", [
+    "bench.py", "tools/bench_blocksync.py", "tools/bench_light.py",
+    "tools/bench_vote_ingest.py"])
+def test_device_benches_exit_nonzero_without_a_tpu(script):
+    """A device metric is measured on the chip or not at all: no CPU
+    fallback number, no JSON line on stdout."""
+    rc, out = _run_cpu(script)
+    assert rc != 0
+    assert not [ln for ln in out if ln.startswith("{")]

@@ -1,5 +1,5 @@
 """bench_ingest: A/B the batched admission pipeline against sequential
-check_tx on a fixed-latency stub device (the tunnel-RTT model bench.py
+check_tx on a fixed-latency stub device (the device-RTT model bench.py
 --pipeline and the blocksync A/B already use).
 
 Both sides run the REAL IngestPipeline over a real CListMempool; the

@@ -12,9 +12,8 @@ Prints ONE JSON line:
   {"metric": "vote_ingest_amortized", "value": <µs/vote>, "unit": "us",
    "budget_us": 100, "within_budget": bool, "backend": "..."}
 
-Env knobs: VOTES (default 200), ROUNDS (default 4),
-BENCH_ALLOW_CPU=1 to run on the CPU backend (numbers then miss the
-budget by design — dev only).
+Env knobs: VOTES (default 200), ROUNDS (default 4). The budget is a
+device number: with no TPU the script exits non-zero.
 """
 
 import json
@@ -25,7 +24,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from cometbft_tpu.libs.jax_cache import enable_compile_cache  # noqa: E402
 
 BUDGET_US = 100.0
 
@@ -43,24 +41,13 @@ def _valset(n, seed=5):
 
 
 def main():
-    from bench import probe_backend  # reuse the wedge-safe probe
+    from bench import require_tpu
 
     n_votes = int(os.environ.get("VOTES", "200"))
     rounds = int(os.environ.get("ROUNDS", "4"))
-    allow_cpu = os.environ.get("BENCH_ALLOW_CPU") == "1"
 
-    platform = probe_backend()
-    if platform is None:
-        print("bench_vote_ingest: FATAL: backend unavailable "
-              "(see probe log)", file=sys.stderr)
-        return 1
-    if platform == "cpu" and not allow_cpu:
-        print("bench_vote_ingest: FATAL: only CPU available and "
-              "BENCH_ALLOW_CPU!=1 — the budget is a device number",
-              file=sys.stderr)
-        return 1
-    enable_compile_cache()
-    import jax
+    # the budget is a device number: exits non-zero with no TPU
+    device = require_tpu()
 
     from cometbft_tpu.types.block import BlockID, PartSetHeader
     from cometbft_tpu.types.proto import Timestamp
@@ -103,7 +90,7 @@ def main():
         "unit": "us",
         "budget_us": BUDGET_US,
         "within_budget": us_per_vote <= BUDGET_US,
-        "backend": jax.devices()[0].platform,
+        "backend": device["platform"], "device": device,
     }))
     return 0
 

@@ -74,8 +74,6 @@ python tools/sim_run.py --scenario mesh-degrade --seeds 0..4 --quick || rc=$?
 # clients (tiny config — the PERF.md datum is the N=32 run)
 echo "=== light-farm quick sweep + farm A/B smoke ===" >&2
 python tools/sim_run.py --scenario light-farm --seeds 0..4 --quick || rc=$?
-python tools/bench_light.py --farm --clients 8 --blocks 12 \
-    --validators 20 --json || rc=$?
 # ingest front door: the flash-crowd sweep pins overload behavior
 # (sheds, dup-filter hits, recheck-eviction release) byte-identical
 # per seed; the bench A/B proves batched admission still amortizes the

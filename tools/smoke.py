@@ -19,8 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-# jax is pre-imported by the environment: config must go through
-# jax.config (env vars are already latched)
+# the smoke pins a virtual CPU mesh for itself
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 4)
 

@@ -2012,6 +2012,8 @@ class Interp:
         if mod.name == "pallas.tpu":
             if name == "VMEM":
                 return Bound("intrinsic", "pltpu", "VMEM")
+            if name == "roll":
+                return Bound("intrinsic", "pltpu", "roll")
             return Opaque(f"pltpu.{name}")
         if mod.name == "functools":
             if name == "partial":
@@ -3634,6 +3636,17 @@ class Interp:
             dt = args[1] if len(args) > 1 else kwargs.get("dtype")
             dt = dt.name if isinstance(dt, DtypeVal) else dt
             return VMEM(tuple(shape), dt or "int32")
+        if ns == "pltpu" and b.name == "roll":
+            # a rotation permutes elements along `axis`: same shape,
+            # dtype and value hull; the per-row intervals (axis 0)
+            # survive unless axis 0 itself is rotated
+            x = args[0]
+            axis = args[2] if len(args) > 2 else kwargs.get("axis")
+            if not isinstance(x, Arr) or not isinstance(axis, int):
+                raise AnalysisError("pltpu.roll of non-array / "
+                                    "abstract axis")
+            rows = x.row_list() if axis % max(1, len(x.shape)) else None
+            return Arr(x.dtype, x.shape, rows, x.iv)
         if ns == "tree":
             if b.name == "tree_map":
                 return self.tree_map(args[0], args[1:], node, frame)
