@@ -235,31 +235,35 @@ def test_byzantine_double_sign_surfaces_conflict():
         # bogus block at the current height/round of node 1's view
         byz_pv = c.pvs[3]
         target = c.nodes[1].cs
-        h, r = target.rs.height, target.rs.round
-        state_vals = target.state.validators
-        idx, _ = state_vals.get_by_address(byz_pv.address())
-        fake = Vote(type_=PREVOTE_TYPE, height=h, round=r,
-                    block_id=BlockID(b"\xee" * 32,
-                                     PartSetHeader(1, b"\xff" * 32)),
-                    timestamp=Timestamp.now(),
-                    validator_address=byz_pv.address(),
-                    validator_index=idx)
-        # bypass the guard the way a malicious binary would
-        sb = fake.sign_bytes(c.gen.chain_id)
-        fake.signature = byz_pv.priv_key.sign(sb)
-        target.send(VoteMessage(fake), peer_id="byz")
 
+        def equivocate():
+            h, r = target.rs.height, target.rs.round
+            state_vals = target.state.validators
+            idx, _ = state_vals.get_by_address(byz_pv.address())
+            fake = Vote(type_=PREVOTE_TYPE, height=h, round=r,
+                        block_id=BlockID(b"\xee" * 32,
+                                         PartSetHeader(1, b"\xff" * 32)),
+                        timestamp=Timestamp.now(),
+                        validator_address=byz_pv.address(),
+                        validator_index=idx)
+            # bypass the guard the way a malicious binary would
+            sb = fake.sign_bytes(c.gen.chain_id)
+            fake.signature = byz_pv.priv_key.sign(sb)
+            target.send(VoteMessage(fake), peer_id="byz")
+            return h
+
+        # a crafted vote that lands after its height was decided (a
+        # loaded box) conflicts with nothing: sign another at the new
+        # height, until one lands in time or the deadline passes
+        h = equivocate()
         deadline = time.monotonic() + 60
-        while time.monotonic() < deadline:
-            if target.conflicting_votes:
-                err = target.conflicting_votes[0]
-                assert err.vote_a.validator_address == byz_pv.address()
-                break
-            # keep the height advancing so the real vote also arrives
+        while time.monotonic() < deadline and not target.conflicting_votes:
             time.sleep(0.02)
             if target.rs.height > h + 2:
-                break
+                h = equivocate()
         assert target.conflicting_votes, "conflict never detected"
+        err = target.conflicting_votes[0]
+        assert err.vote_a.validator_address == byz_pv.address()
     finally:
         c.stop()
 

@@ -15,6 +15,7 @@ and tamper rejection validate the composition on top.
 import random
 
 import pytest
+from _kernel_shape import CLAMPED_LANES
 
 from cometbft_tpu.crypto.batch import (MixedBatchVerifier,
                                        create_batch_verifier,
@@ -183,7 +184,10 @@ def test_sr25519_batch_verifier():
 # --- mixed-curve dispatch (BASELINE config) ----------------------------------
 
 def test_mixed_curve_batch_dispatch():
-    eds = [Ed25519PrivKey.generate(RNG) for _ in range(3)]
+    # CLAMPED_LANES ed25519 keys: the dispatch is the point, not the
+    # kernel, and over 64 lanes a CPU backend verifies natively
+    # (_kernel_shape.py)
+    eds = [Ed25519PrivKey.generate(RNG) for _ in range(CLAMPED_LANES)]
     srs = [Sr25519PrivKey.generate(RNG) for _ in range(2)]
     secps = [Secp256k1PrivKey.generate(RNG) for _ in range(2)]
 
@@ -195,7 +199,7 @@ def test_mixed_curve_batch_dispatch():
     mixed = MixedBatchVerifier()
     expect = []
     for i, k in enumerate([eds[0], srs[0], secps[0], eds[1], secps[1],
-                           srs[1], eds[2]]):
+                           srs[1], *eds[2:]]):
         m = f"mixed-{i}".encode()
         sig = k.sign(m)
         if i == 4:  # corrupt the second secp sig

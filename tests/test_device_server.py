@@ -8,6 +8,7 @@ import os
 import threading
 
 import pytest
+from _kernel_shape import KERNEL_LANES, KERNEL_MSG_CAP
 
 from cometbft_tpu.crypto import ref_ed25519 as ref
 from cometbft_tpu.device.client import DeviceClient, RemoteBatchVerifier
@@ -51,14 +52,16 @@ def test_protocol_roundtrip():
 
 @pytest.fixture(scope="module")
 def server():
-    srv = DeviceServer(bucket=64, max_msg_len=64, flush_us=2000)
+    # the suite's one compiled shape (_kernel_shape.py): start() warms the
+    # kernel pair at it, and the local fallbacks below land on it too
+    srv = DeviceServer(bucket=KERNEL_LANES, max_msg_len=64, flush_us=2000)
     srv.start()
     yield srv
     srv.stop()
 
 
 def test_client_verify_and_attribution(server):
-    pubs, msgs, sigs = _sigs(8)
+    pubs, msgs, sigs = _sigs(KERNEL_LANES)
     bad = bytearray(sigs[3])
     bad[5] ^= 0xFF
     sigs[3] = bytes(bad)
@@ -66,7 +69,7 @@ def test_client_verify_and_attribution(server):
     try:
         batch_ok, oks = client.verify(pubs, msgs, sigs)
         assert not batch_ok
-        assert oks == [True] * 3 + [False] + [True] * 4
+        assert oks == [True] * 3 + [False] + [True] * (KERNEL_LANES - 4)
     finally:
         client.close()
 
@@ -103,9 +106,13 @@ def test_oversized_message_unprocessable_falls_back(server):
     failures — that would brand valid signatures forged), and the batch
     seam degrades to local verification."""
     from cometbft_tpu.device.client import DeviceUnprocessable
-    pubs, msgs, sigs = _sigs(2, seed=33)
+    # a full bucket, so that the size-less local verifier takes
+    # KERNEL_LANES too; the long message is beyond the server's
+    # max_msg_len and within the capacity of the compiled shape
+    pubs, msgs, sigs = _sigs(KERNEL_LANES, seed=33)
     seed = b"\x21" * 32
-    msgs[1] = b"\x01" * 1000  # beyond the server's max_msg_len
+    msgs[1] = b"\x01" * KERNEL_MSG_CAP
+    assert len(msgs[1]) > server.max_msg_len
     pubs[1] = ref.pubkey_from_seed(seed)
     sigs[1] = ref.sign(seed, msgs[1])
     client = DeviceClient(*server.addr)
@@ -117,7 +124,7 @@ def test_oversized_message_unprocessable_falls_back(server):
         for p, m, s in zip(pubs, msgs, sigs):
             rbv.add(Ed25519PubKey(p), m, s)
         batch_ok, oks = rbv.verify()  # local fallback
-        assert batch_ok and oks == [True, True]
+        assert batch_ok and oks == [True] * KERNEL_LANES
     finally:
         client.close()
 
@@ -130,7 +137,7 @@ def test_bucket_cap_grants_canary_headroom():
     oversized message) stays rejected. Predicate-level test: no kernel
     compile, no traffic."""
     from cometbft_tpu.device import health
-    srv = DeviceServer(bucket=8, max_msg_len=64)
+    srv = DeviceServer(bucket=KERNEL_LANES, max_msg_len=64)
     try:
         pubs = [b"\x01" * 32] * srv.bucket
         msgs = [b"m" * 31] * srv.bucket
@@ -152,13 +159,14 @@ def test_dead_server_falls_back_locally(monkeypatch):
     from cometbft_tpu.crypto.keys import Ed25519PubKey
     monkeypatch.setenv(dc.ENV_VAR, "127.0.0.1:1")  # nothing listens
     monkeypatch.setattr(dc, "_shared", None)
-    pubs, msgs, sigs = _sigs(3, seed=70)
+    # a full bucket: the size-less local verifier then takes KERNEL_LANES
+    pubs, msgs, sigs = _sigs(KERNEL_LANES, seed=70)
     bv, ok = crypto_batch.create_batch_verifier(Ed25519PubKey(pubs[0]))
     assert ok  # local verifier (connect refused) or remote w/ fallback
     for p, m, s in zip(pubs, msgs, sigs):
         bv.add(Ed25519PubKey(p), m, s)
     batch_ok, oks = bv.verify()
-    assert batch_ok and oks == [True] * 3
+    assert batch_ok and oks == [True] * KERNEL_LANES
     monkeypatch.setattr(dc, "_shared", None)
 
 

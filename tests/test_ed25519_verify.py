@@ -4,11 +4,20 @@ Covers RFC 8032 §7.1 test vectors, malleability (s >= L), corruption
 attribution inside a batch, and ZIP-215 permissive decoding semantics
 (reference: crypto/ed25519/ed25519.go:40-42,181-188)."""
 
+import functools
+
 import numpy as np
 import pytest
+from _kernel_shape import KERNEL_LANES, KERNEL_MSG_CAP
 
 from cometbft_tpu.crypto import ref_ed25519 as ref
-from cometbft_tpu.ops.ed25519 import verify_batch
+from cometbft_tpu.ops import ed25519 as ops_ed25519
+
+# every kernel call of this file at the suite's one compiled shape
+# (tests/_kernel_shape.py); the size-less default (next power of two of the
+# call) would compile a variant for each test
+verify_batch = functools.partial(ops_ed25519.verify_batch,
+                                 batch_size=KERNEL_LANES)
 
 # RFC 8032 §7.1: (seed, pub, msg, sig) hex
 RFC8032 = [
@@ -55,9 +64,10 @@ def test_batch_attribution_and_rejections():
     import random
     rng = random.Random(11)
     pubs, msgs, sigs, expect = [], [], [], []
-    for i in range(12):
+    for i in range(12):  # more than one chunk of KERNEL_LANES
         seed = bytes([rng.randrange(256) for _ in range(32)])
-        msg = bytes([rng.randrange(256) for _ in range(rng.randrange(1, 150))])
+        msg = bytes([rng.randrange(256) for _ in range(
+            rng.randrange(1, KERNEL_MSG_CAP + 1))])
         pub, sig = ref.pubkey_from_seed(seed), ref.sign(seed, msg)
         kind = i % 4
         if kind == 1:    # corrupt signature R
@@ -82,13 +92,18 @@ def test_batch_attribution_and_rejections():
     assert list(got) == expect
 
 
-def test_malformed_inputs():
+@pytest.mark.parametrize("pub_len,sig_len", [
+    (32, 63), (32, 65), (31, 64), (0, 64)],
+    ids=["short-sig", "long-sig", "short-key", "empty-key"])
+def test_malformed_inputs(pub_len, sig_len):
+    """A key or signature of the wrong length is rejected in its own
+    lane, between two good ones."""
     seed = b"\x01" * 32
     msg = b"hello"
     pub, sig = ref.pubkey_from_seed(seed), ref.sign(seed, msg)
-    got = verify_batch([pub, pub[:31], pub], [msg, msg, msg],
-                       [sig[:63], sig, sig])
-    assert list(got) == [False, False, True]
+    got = verify_batch([pub, (pub * 2)[:pub_len], pub], [msg, msg, msg],
+                       [sig, (sig * 2)[:sig_len], sig])
+    assert list(got) == [True, False, True]
 
 
 def test_zip215_small_order_and_noncanonical():
@@ -105,7 +120,20 @@ def test_zip215_small_order_and_noncanonical():
 
     got = verify_batch([ident, ident_nc], [msg, msg], [sig, sig_nc])
     assert list(got) == [True, True]
-    got = verify_batch([ident, ident_nc], [msg, msg], [sig, sig_nc],
+
+
+@pytest.mark.slow
+def test_strict_mode_kernel_rejects_noncanonical():
+    """zip215=False is a static argument of the per-lane kernel: a
+    variant of its own, ~85 s of XLA:CPU compile that no other test
+    reuses and no path of the program asks for (the strict decoding
+    rule itself is tier-1 in test_edwards.py, the strict oracle in the
+    test above)."""
+    msg = b"anything"
+    ident = (1).to_bytes(32, "little")
+    ident_nc = (ref.P + 1).to_bytes(32, "little")
+    got = verify_batch([ident, ident_nc], [msg, msg],
+                       [ident + bytes(32), ident_nc + bytes(32)],
                        zip215=False)
     assert list(got) == [True, False]
 
@@ -114,15 +142,20 @@ def test_empty_batch():
     assert verify_batch([], [], []).shape == (0,)
 
 
-def test_oversized_batch_chunks():
+@pytest.mark.parametrize("n", [
+    KERNEL_LANES + 1, 2 * KERNEL_LANES, 2 * KERNEL_LANES + 1],
+    ids=["full+padded", "full+full", "full+full+padded"])
+def test_oversized_batch_chunks(n):
     """More signatures than batch_size must chunk, not crash."""
     seed = b"\x05" * 32
     pub = ref.pubkey_from_seed(seed)
-    msgs = [bytes([i]) for i in range(5)]
+    msgs = [bytes([i]) for i in range(n)]
     sigs = [ref.sign(seed, m) for m in msgs]
-    sigs[3] = bytes(64)
-    got = verify_batch([pub] * 5, msgs, sigs, batch_size=2)
-    assert list(got) == [True, True, True, False, True]
+    bad = {3, KERNEL_LANES, n - 1}  # one in every chunk
+    for i in bad:
+        sigs[i] = bytes(64)
+    got = verify_batch([pub] * n, msgs, sigs)
+    assert list(got) == [i not in bad for i in range(n)]
 
 
 def test_cpu_clamp_lifts_on_process_warm_bucket(tmp_path, monkeypatch):

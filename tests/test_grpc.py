@@ -244,8 +244,10 @@ def test_grpc_services_and_pruning(tmp_path):
                 "height"] == 3
 
             deadline = time.monotonic() + 30
-            while time.monotonic() < deadline and \
-                    node.block_store.base() < 3:
+            while time.monotonic() < deadline and (
+                    node.block_store.base() < 3
+                    or node.state_store.load_finalize_block_response(1)
+                    is not None):
                 time.sleep(0.1)
             assert node.block_store.base() == 3
             assert node.state_store.load_finalize_block_response(1) \

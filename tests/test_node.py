@@ -309,14 +309,19 @@ def test_node_with_remote_socket_app(tmp_path):
         code, val = node.app_conns.query.query("/store", b"remote")
         assert val == b"app"
         # the snapshot connection's methods ride the wire (interval
-        # snapshots appear at height 5)
-        while node.consensus.state.last_block_height < 6 and \
-                time.monotonic() < deadline:
+        # snapshots appear at height 5). Listing and loading are polled
+        # together under the same deadline: the node keeps committing,
+        # the app retains two snapshots, and a listed one can be gone
+        # when a starved test thread comes to load it
+        snaps, chunk = [], b""
+        while not chunk and time.monotonic() < deadline:
             time.sleep(0.05)
-        snaps = node.app_conns.snapshot.list_snapshots()
+            if node.consensus.state.last_block_height >= 6:
+                snaps = node.app_conns.snapshot.list_snapshots()
+                if snaps:
+                    chunk = node.app_conns.snapshot.load_snapshot_chunk(
+                        snaps[0].height, snaps[0].format, 0)
         assert snaps and snaps[0].height % 5 == 0
-        chunk = node.app_conns.snapshot.load_snapshot_chunk(
-            snaps[0].height, snaps[0].format, 0)
         assert chunk and b"remote" in chunk
     finally:
         if node is not None:

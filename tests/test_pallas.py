@@ -33,7 +33,7 @@ _inproc = pytest.mark.skipif(
     reason="runs via its *_isolated subprocess wrapper")
 
 
-def _run_isolated(name: str, timeout: float = 1800,
+def _run_isolated(name: str, timeout: float = 300,
                   env_extra: dict = None) -> None:
     env = dict(os.environ, PALLAS_TESTS_INPROC="1", **(env_extra or {}))
     r = subprocess.run(

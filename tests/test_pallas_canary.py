@@ -15,13 +15,14 @@ kernel (no mosaic on the CPU test platform) to prove:
 
 import numpy as np
 import pytest
+from _kernel_shape import KERNEL_LANES
 
 from cometbft_tpu.crypto import ref_ed25519 as ref
 from cometbft_tpu.ops import ed25519 as e5
 from cometbft_tpu.ops import pallas_verify as pv
 
 
-BATCH = 16  # keep XLA:CPU compiles small (docs/PERF.md batch>=256 crash)
+BATCH = KERNEL_LANES  # the suite's one compiled shape (_kernel_shape.py)
 
 
 @pytest.fixture

@@ -47,17 +47,21 @@ def test_pex_discovers_full_mesh():
         h0, p0 = nodes[0][0].transport.node_info.listen_addr.split(":")
         nodes[1][0].dial(h0, int(p0))
         nodes[2][0].dial(h0, int(p0))
+        # both conditions under ONE deadline: a peer can be connected a
+        # moment before its address reaches the book
+        def meshed():
+            return all(len(sw.peers()) >= 2 and len(pex.book) >= 2
+                       for sw, pex in nodes)
+
         deadline = time.monotonic() + 20
-        while time.monotonic() < deadline:
-            if all(len(sw.peers()) >= 2 for sw, _ in nodes):
-                break
+        while time.monotonic() < deadline and not meshed():
             time.sleep(0.05)
         assert all(len(sw.peers()) >= 2 for sw, _ in nodes), \
             [(sw._moniker, [p.id[:8] for p in sw.peers()])
              for sw, _ in nodes]
         # address books learned all peers
-        for sw, pex in nodes:
-            assert len(pex.book) >= 2
+        assert all(len(pex.book) >= 2 for _, pex in nodes), \
+            [(sw._moniker, len(pex.book)) for sw, pex in nodes]
     finally:
         for sw, pex in nodes:
             pex.stop()

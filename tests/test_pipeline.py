@@ -17,6 +17,7 @@ The slow-marked depth sweep (run_suite.sh) soaks K in {1,2,4,8}.
 
 import numpy as np
 import pytest
+from _kernel_shape import CLAMPED_LANES
 
 from cometbft_tpu.abci.kvstore import KVStoreApplication
 from cometbft_tpu.db.kv import MemDB
@@ -243,18 +244,25 @@ def test_remote_batch_verifier_retries_once_then_local():
         # process-wide shared instance from a test fixture client
         return DeviceSupervisor(backoff_base_s=0.01, backoff_cap_s=0.1)
 
+    # CLAMPED_LANES signatures a flush: going local is the point, not the
+    # kernel, and over 64 lanes a CPU backend verifies natively
+    # (_kernel_shape.py)
     seed = b"\x05" * 32
-    pk, msg = ref.pubkey_from_seed(seed), b"hello"
-    sig = ref.sign(seed, msg)
+    pk = Ed25519PubKey(ref.pubkey_from_seed(seed))
+    signed = [(m, ref.sign(seed, m)) for m in
+              (b"hello %d" % i for i in range(CLAMPED_LANES))]
+
+    def fill(rbv):
+        for msg, sig in signed:
+            rbv.add(pk, msg, sig)
+        return rbv
 
     # dead link: exactly one retry (shared_client may reconnect), then
     # local; the transport failures report to the supervisor
     flaky = FlakyClient(ConnectionError("link down"))
     s1 = sup()
-    rbv = RemoteBatchVerifier(flaky, supervisor=s1)
-    rbv.add(Ed25519PubKey(pk), msg, sig)
-    ok, oks = rbv.verify()
-    assert ok and oks == [True]
+    ok, oks = fill(RemoteBatchVerifier(flaky, supervisor=s1)).verify()
+    assert ok and oks == [True] * CLAMPED_LANES
     assert flaky.calls == 2
     assert s1.state == SUSPECT and s1.trips == 2
 
@@ -262,10 +270,8 @@ def test_remote_batch_verifier_retries_once_then_local():
     # the consensus-path stall — go local immediately
     wedged = FlakyClient(TimeoutError("wedged"))
     s2 = sup()
-    rbv = RemoteBatchVerifier(wedged, supervisor=s2)
-    rbv.add(Ed25519PubKey(pk), msg, sig)
-    ok, oks = rbv.verify()
-    assert ok and oks == [True]
+    ok, oks = fill(RemoteBatchVerifier(wedged, supervisor=s2)).verify()
+    assert ok and oks == [True] * CLAMPED_LANES
     assert wedged.calls == 1
     assert s2.state == SUSPECT
 
@@ -273,10 +279,8 @@ def test_remote_batch_verifier_retries_once_then_local():
     # and are NOT a health signal: the device answered coherently
     unproc = FlakyClient(DeviceUnprocessable("too big"))
     s3 = sup()
-    rbv = RemoteBatchVerifier(unproc, supervisor=s3)
-    rbv.add(Ed25519PubKey(pk), msg, sig)
-    ok, oks = rbv.verify()
-    assert ok and oks == [True]
+    ok, oks = fill(RemoteBatchVerifier(unproc, supervisor=s3)).verify()
+    assert ok and oks == [True] * CLAMPED_LANES
     assert unproc.calls == 1
     assert s3.state == HEALTHY
 

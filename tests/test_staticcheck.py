@@ -497,11 +497,15 @@ def test_checked_in_baseline_entries_are_justified():
 
 
 def test_cli_clean_on_tree():
-    """`python -m tools.staticcheck` (the run_suite.sh wiring) exits 0."""
+    """`python -m tools.staticcheck` (the run_suite.sh wiring) exits 0
+    and says so. Every rule over the tree is test_full_tree_is_clean's
+    half-minute, in-process; the CLI's own part — arguments, the
+    full-tree branch with the checked-in baseline, the verdict line and
+    the exit code — is proven with one cheap rule over the same tree."""
     import subprocess
     proc = subprocess.run(
-        [sys.executable, "-m", "tools.staticcheck"], cwd=REPO,
-        capture_output=True, text=True, timeout=300)
+        [sys.executable, "-m", "tools.staticcheck", "--rule", "wallclock"],
+        cwd=REPO, capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "clean" in proc.stdout
 

@@ -17,6 +17,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tools.staticcheck import FileCtx, run_checks  # noqa: E402
@@ -747,34 +749,45 @@ def test_cli_list_pragmas(tmp_path):
 
 # --- the real tree (v2 families) ------------------------------------------
 
-def test_real_tree_has_flow_promoted_helpers():
+@pytest.fixture(scope="module")
+def real_tree():
+    """The four v2 families over the real tree, linted ONCE for this
+    file (every run_checks(REPO) parses the tree and builds the project
+    graph again); a finding of one family fails the others' tests too,
+    and names its rule."""
+    return run_checks(REPO, rules=[R.GuardedByRule, R.LockOrderRule,
+                                   R.VerdictTaintRule,
+                                   R.KernelDisciplineRule])
+
+
+def test_real_tree_has_flow_promoted_helpers(real_tree):
     """The flow-aware engine accepts the tree's caller-holds-the-lock
     helpers (ingest _shed_locked, farm _run_batch, supervisor
     _set_state) with NO pragma — if this starts failing, either a new
     unlocked call site appeared (a real bug) or the promotion
     regressed."""
-    res = run_checks(REPO, rules=[R.GuardedByRule])
+    res = real_tree
     assert [f for f in res.findings if f.rule == "guarded-by"] == [], \
         "\n".join(f.render() for f in res.findings)
 
 
-def test_real_tree_lock_graph_acyclic():
-    res = run_checks(REPO, rules=[R.LockOrderRule])
+def test_real_tree_lock_graph_acyclic(real_tree):
+    res = real_tree
     assert res.findings == [], "\n".join(
         f.render() for f in res.findings)
 
 
-def test_real_tree_verdict_taint_clean_with_optout_pragmas():
+def test_real_tree_verdict_taint_clean_with_optout_pragmas(real_tree):
     """The canaried paths (farm/ingest/aggsig/RemoteBatchVerifier) are
     clean; the two deliberate canary-opt-out returns are pragma'd with
     a why and must stay both pragma'd AND exercised (the stale audit
     fails if taint stops reaching them)."""
-    res = run_checks(REPO, rules=[R.VerdictTaintRule])
+    res = real_tree
     assert res.findings == [], "\n".join(
         f.render() for f in res.findings)
 
 
-def test_real_tree_kernel_discipline_clean():
-    res = run_checks(REPO, rules=[R.KernelDisciplineRule])
+def test_real_tree_kernel_discipline_clean(real_tree):
+    res = real_tree
     assert res.findings == [], "\n".join(
         f.render() for f in res.findings)
