@@ -41,6 +41,7 @@ trusted un-canaried.
 
 from __future__ import annotations
 
+import contextlib
 import inspect
 import queue
 import threading
@@ -57,7 +58,8 @@ from ..engine.blocksync import (BlocksyncReactor, SyncStalled,
 from ..libs.fail import fail_point
 from ..state.execution import BlockValidationError
 from ..state.state import State
-from ..trace import shared_tracer
+from ..trace import ctx_of, shared_tracer
+from ..types.block import SIG_ENCODINGS
 
 
 # --- futures + verify backends ------------------------------------------------
@@ -302,6 +304,23 @@ class CorruptBackend:
 
 # --- the scheduler ------------------------------------------------------------
 
+@contextlib.contextmanager
+def _host_stage_span(tracer, name: str, parent):
+    """A main-thread stage's span, carrying the CommitSig wire
+    encodings the stage computed and the ones it reused (fetch pays a
+    commit's one encoding in make_part_set, apply meets it three times
+    more: last_commit_hash and the `C:`/`SC:` store keys). The counters
+    are process-wide; the two stages never overlap and nothing else on
+    the catch-up path encodes a CommitSig."""
+    computed, reused = SIG_ENCODINGS
+    with tracer.start(name, parent=parent) as span:
+        try:
+            yield span
+        finally:
+            span.set_attr("sig_enc_computed", SIG_ENCODINGS[0] - computed)
+            span.set_attr("sig_enc_reused", SIG_ENCODINGS[1] - reused)
+
+
 @dataclass
 class _Tile:
     start: int
@@ -389,7 +408,7 @@ class PipelinedBlocksync:
                            tspan) -> _Tile:
         self._occupy("fetch", 1)
         try:
-            with tracer.start("pipeline.fetch", parent=tspan):
+            with _host_stage_span(tracer, "pipeline.fetch", tspan):
                 fetched, end = self.r._fetch_range(start, target)
         finally:
             self._occupy("fetch", 0)
@@ -595,6 +614,7 @@ class PipelinedBlocksync:
         retries the remainder) or BlockValidationError raises when
         nothing was applied this pass."""
         r = self.r
+        tracer = shared_tracer()
         inflight: "deque[_Tile]" = deque()
         spec_vals = state.validators
         next_start = state.last_block_height + 1
@@ -629,32 +649,36 @@ class PipelinedBlocksync:
 
                 tile = inflight.popleft()
                 self._inflight_gauge(len(inflight))
+                tile_ctx = ctx_of(tile.span)  # _settle ends the span
                 self._settle(tile)
                 self._occupy("apply", 1)
                 try:
-                    by_height = {e.height: e for e in tile.entries}
-                    h = tile.start
-                    while h <= tile.end:
-                        block, parts, block_id = tile.fetched[h]
-                        seal_commit = tile.fetched[h + 1][0].last_commit
-                        try:
-                            state = r._apply_one(
-                                state, h, block, parts, block_id,
-                                seal_commit, by_height.get(h))
-                        except TileApplyError as f:
-                            r.source.ban(h)
-                            # drop everything speculative: the remainder
-                            # refetches (possibly re-routed) in a fresh
-                            # pass; cancel abandoned dispatches so the
-                            # device client doesn't retain their answers
-                            for t in inflight:
-                                self._cancel(t)
-                            inflight.clear()
-                            if applied_any:
-                                return state
-                            raise BlockValidationError(str(f)) from f
-                        applied_any = True
-                        h += 1
+                    with _host_stage_span(tracer, "pipeline.apply",
+                                          tile_ctx):
+                        by_height = {e.height: e for e in tile.entries}
+                        h = tile.start
+                        while h <= tile.end:
+                            block, parts, block_id = tile.fetched[h]
+                            seal_commit = tile.fetched[h + 1][0].last_commit
+                            try:
+                                state = r._apply_one(
+                                    state, h, block, parts, block_id,
+                                    seal_commit, by_height.get(h))
+                            except TileApplyError as f:
+                                r.source.ban(h)
+                                # drop everything speculative: the
+                                # remainder refetches (possibly
+                                # re-routed) in a fresh pass; cancel
+                                # abandoned dispatches so the device
+                                # client doesn't retain their answers
+                                for t in inflight:
+                                    self._cancel(t)
+                                inflight.clear()
+                                if applied_any:
+                                    return state
+                                raise BlockValidationError(str(f)) from f
+                            applied_any = True
+                            h += 1
                 finally:
                     self._occupy("apply", 0)
                 if barrier and not inflight:

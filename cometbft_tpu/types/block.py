@@ -35,6 +35,11 @@ BLOCK_PART_SIZE = 65536  # reference types/part_set.go BlockPartSizeBytes
 # MaxSignatureSize the same way when BLS landed behind its build tag).
 MAX_SIGNATURE_SIZE = 96
 
+# CommitSig wire encodings [computed, reused], one count a signature,
+# process-wide and unlocked: a diagnostic, exact only as one thread's
+# delta (the catch-up pipeline's fetch and apply spans read it so)
+SIG_ENCODINGS = [0, 0]
+
 
 @dataclass(frozen=True)
 class PartSetHeader:
@@ -118,11 +123,31 @@ class CommitSig:
 
     def encode(self) -> bytes:
         """proto CommitSig (types.proto: flag=1, validator_address=2,
-        timestamp=3 nonnull, signature=4)."""
-        return (proto.f_varint(1, self.block_id_flag)
+        timestamp=3 nonnull, signature=4).
+
+        Memoized per instance, as Header.hash is: the dataclass is
+        frozen and its four fields are immutable values, and catch-up
+        meets every commit four times (block parts, last_commit_hash,
+        the `C:` and `SC:` store keys). The memo is not a field, is
+        never seeded from decoded bytes and does not travel through
+        pickle/copy (`__reduce__`), so whoever holds the instance pays
+        its first encoding."""
+        memo = self.__dict__.get("_wire_memo")
+        if memo is not None:
+            SIG_ENCODINGS[1] += 1
+            return memo
+        SIG_ENCODINGS[0] += 1
+        wire = (proto.f_varint(1, self.block_id_flag)
                 + proto.f_bytes(2, self.validator_address)
                 + proto.f_embed(3, self.timestamp.encode())
                 + proto.f_bytes(4, self.signature))
+        object.__setattr__(self, "_wire_memo", wire)
+        return wire
+
+    def __reduce__(self):
+        # pickle and copy rebuild from the four fields: no memo travels
+        return (type(self), (self.block_id_flag, self.validator_address,
+                             self.timestamp, self.signature))
 
     @classmethod
     def decode(cls, buf: bytes) -> "CommitSig":
@@ -216,12 +241,10 @@ class Commit:
     def encode(self) -> bytes:
         """proto Commit (types.proto: height=1, round=2, block_id=3 nonnull,
         signatures=4 repeated)."""
-        out = (proto.f_varint(1, self.height)
-               + proto.f_varint(2, self.round)
-               + proto.f_embed(3, self.block_id.encode()))
-        for cs in self.signatures:
-            out += proto.f_embed(4, cs.encode())
-        return out
+        return b"".join(
+            [proto.f_varint(1, self.height), proto.f_varint(2, self.round),
+             proto.f_embed(3, self.block_id.encode())]
+            + [proto.f_embed(4, cs.encode()) for cs in self.signatures])
 
     @classmethod
     def decode(cls, buf: bytes) -> "Commit":
