@@ -1,7 +1,13 @@
 """Fixtures for the benchmark's own tests: a copy of the benchmark at a
 tiny size (8 validators, 4-block tiles) that runs in seconds on the CPU
 backend, where no kernel is traced or jitted (the node's bucket is 0
-there and every signature takes the native route)."""
+there and every signature takes the native route).
+
+The tiny sizes are data, found by name: `tiny/configs/<config>.json` and
+`tiny/traffic/<mix>.json` beside this file, each a dict merged over the
+real file. A PR that lists a cell in `BENCHMARK.json` adds the two files
+for it (where they are not there yet) and edits nothing here; the tests
+that walk the cells (`CELLS`) then run it."""
 
 import json
 import os
@@ -14,46 +20,89 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-TINY = {
-    "validators": 8,
-    "steady-fresh-chain": dict(blocks_per_window_second=4, warmup_blocks=4,
-                               probe_blocks=12, probe_bad_height=7,
-                               probe_bad_index=3),
-    "closed-loop-commits": dict(commits_per_window_second=20,
-                                warmup_commits=2, probe_commits=4),
-}
+TINY_REL = os.path.join("tests", "benchmark_harness", "tiny")
 
 
-def make_tiny_root(dst: str) -> str:
+def load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _tiny_file(src: str, kind: str, name: str) -> str:
+    return os.path.join(src, TINY_REL, kind, name + ".json")
+
+
+def _survey(src: str) -> tuple:
+    """`src`'s BENCHMARK.json, and `(cell, file)` for every file of tiny
+    sizes that one of its cells needs and that is not there."""
+    doc = load_json(os.path.join(src, "BENCHMARK.json"))
+    return doc, [(w["name"], path) for w in doc["workloads"]
+                 for path in (_tiny_file(src, "configs", w["config"]),
+                              _tiny_file(src, "traffic", w["traffic"]))
+                 if not os.path.isfile(path)]
+
+
+def missing_tiny(src: str = REPO) -> list:
+    return _survey(src)[1]
+
+
+def tiny_cells(src: str = REPO) -> list:
+    """The cells of `src`'s BENCHMARK.json that a tiny checkout holds:
+    those whose tiny sizes are there."""
+    doc, missing = _survey(src)
+    without = {cell for cell, _path in missing}
+    return [w["name"] for w in doc["workloads"] if w["name"] not in without]
+
+
+def _without_cells(doc: dict, gone: set) -> dict:
+    """`doc` with the cells `gone` taken out, and with them whatever
+    only they used, so that what is left still validates."""
+    doc = dict(doc, workloads=[w for w in doc["workloads"]
+                               if w["name"] not in gone])
+    used = {w["config"] for w in doc["workloads"]}
+    doc["configs"] = [c for c in doc["configs"] if c["name"] in used]
+    for group in ("end_to_end", "per_layer"):
+        kept = []
+        for m in doc[group]:
+            if "workloads" in m:
+                m = dict(m, workloads=[c for c in m["workloads"]
+                                       if c not in gone])
+                if not m["workloads"]:
+                    continue
+            kept.append(m)
+        doc[group] = kept
+    return doc
+
+
+def make_tiny_root(dst: str, src: str = REPO) -> str:
     """`dst` becomes a checkout that holds only BENCHMARK.json and the
-    benchmark's files, with the sizes cut down."""
-    for d in ("drivers", "generators", "layer_metrics", "rooflines"):
-        shutil.copytree(os.path.join(REPO, "benchmark", d),
-                        os.path.join(dst, "benchmark", d))
-    shutil.copy(os.path.join(REPO, "benchmark", "peaks.json"),
-                os.path.join(dst, "benchmark", "peaks.json"))
-    for d in ("configs", "traffic"):
-        os.makedirs(os.path.join(dst, "benchmark", d))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    for c in doc["configs"]:
-        with open(os.path.join(REPO, c["file"])) as f:
-            cfg = json.load(f)
-        cfg["validators"] = TINY["validators"]
-        if "tile_size" in cfg:
-            cfg["tile_size"] = 4
-        with open(os.path.join(dst, c["file"]), "w") as f:
-            json.dump(cfg, f)
+    benchmark's files as `src` has them, with the sizes cut down. A cell
+    whose tiny sizes are missing is left out, entries and files, never
+    run at its published size (`missing_tiny` names it, and one test
+    fails on it)."""
+    doc, missing = _survey(src)
+    doc = _without_cells(doc, {cell for cell, _path in missing})
+    bench = doc["paths"][0]
+    shutil.copytree(os.path.join(src, bench), os.path.join(dst, bench),
+                    ignore=shutil.ignore_patterns("__pycache__", "configs",
+                                                  "traffic"))
+    tiny = {c["file"]: _tiny_file(src, "configs", c["name"])
+            for c in doc["configs"]}
     for w in doc["workloads"]:
-        rel = os.path.join("benchmark", "traffic", w["traffic"] + ".json")
-        with open(os.path.join(REPO, rel)) as f:
-            mix = json.load(f)
-        mix.update(TINY[w["traffic"]])
+        tiny[os.path.join(bench, "traffic", w["traffic"] + ".json")] = \
+            _tiny_file(src, "traffic", w["traffic"])
+    for rel, override in tiny.items():
+        sizes = load_json(os.path.join(src, rel))
+        sizes.update(load_json(override))
+        os.makedirs(os.path.dirname(os.path.join(dst, rel)), exist_ok=True)
         with open(os.path.join(dst, rel), "w") as f:
-            json.dump(mix, f)
+            json.dump(sizes, f)
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
     return dst
+
+
+CELLS = tiny_cells()
 
 
 @pytest.fixture

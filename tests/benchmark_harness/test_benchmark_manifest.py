@@ -1,11 +1,12 @@
-"""BENCHMARK.json is valid and every file it names loads by name."""
+"""BENCHMARK.json is valid, every file it names loads by name, and every
+cell it lists has the tiny sizes that the tests run it at."""
 
 import json
 import os
 
 import pytest
 
-from conftest import REPO
+from conftest import REPO, missing_tiny
 from benchmark.harness.manifest import (NAME_RE, UNIT_RE, Manifest,
                                         ManifestError, validate)
 
@@ -66,8 +67,9 @@ def test_every_named_file_loads(doc):
         assert callable(gen.make)
         for ref in cell.config["reference"]:
             manifest.load_module("reference", ref)
-        for key in doc["configs"][0]["reduced"]:
-            assert key in manifest.cell(doc["workloads"][0]["name"]).config
+    for c in doc["configs"]:
+        sizes = manifest.load_json(c["file"], from_repo=True)
+        assert set(c["reduced"]) <= set(sizes), c["name"]
     for m in doc["per_layer"]:
         assert callable(manifest.layer_reader(m["name"]).read)
     assert manifest.peaks("TPU v5 lite")["int8_ops_per_s"] == 393e12
@@ -75,6 +77,17 @@ def test_every_named_file_loads(doc):
         manifest.peaks("TPU v9 imaginary")
     with pytest.raises(ManifestError):
         manifest.cell("no-such.cell")
+
+
+def test_every_cell_has_its_tiny_sizes():
+    """A cell without them is left out of the tests' tiny checkout
+    (conftest.py `make_tiny_root`), so no test would run it: it fails
+    here, once, by name."""
+    missing = [f"cell {cell}: {os.path.relpath(path, REPO)} is missing"
+               for cell, path in missing_tiny()]
+    assert not missing, (
+        "; ".join(missing) + " (its tiny sizes: a JSON dict merged over "
+        "the real file of that name, benchmark/README.md says how)")
 
 
 def test_the_plain_reference_imports_nothing_of_the_program():

@@ -1,18 +1,22 @@
 """The run itself, driven past the look for a chip on the CPU backend at
-a tiny size: `correct` comes out true on sound code and false with the
-timed path broken underneath, adding a cell takes only new files, and
-the command refuses to run without a TPU."""
+a tiny size: `correct` comes out true on sound code, in every cell that
+BENCHMARK.json lists, and false with the timed path broken underneath,
+adding a cell takes only new files and entries, tests included, and the
+command refuses to run without a TPU."""
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
 
 import pytest
 
-from conftest import REPO, make_tiny_root
+from conftest import (CELLS, REPO, TINY_REL, load_json, make_tiny_root,
+                      missing_tiny, tiny_cells)
 from benchmark.harness import runner
+from benchmark.harness.manifest import Manifest, ManifestError, validate
 
 CATCHUP, COMMIT = "catchup-200.steady", "hub-live-150.cold-commit"
 SEED = 2**31 + 77
@@ -29,15 +33,16 @@ def over(out):
             if row["value"] > row["limit"]}
 
 
-@pytest.mark.parametrize("cell, metrics", [
-    (CATCHUP, {"catchup_sigs_per_s", "setup_s"}),
-    (COMMIT, {"commit_verify_p50_ms", "commit_verify_p95_ms", "setup_s"}),
-])
-def test_sound_run_is_correct(tiny_root, fresh_sigcache, cell, metrics,
-                              capfd):
+def names(metrics):
+    return {m["name"] for m in metrics}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_is_correct(tiny_root, fresh_sigcache, cell, capfd):
     out = run(tiny_root, cell)
     assert out["correct"] and over(out) == set()
-    assert set(out["metrics"]) == metrics
+    assert set(out["metrics"]) == names(
+        Manifest(tiny_root).end_to_end_for(cell))
     assert out["failed"] == 0 and out["attempted"] > 0
     assert list(out)[-1] == "checks"
     runner.print_result(out)
@@ -51,6 +56,19 @@ def test_same_seed_twice_in_one_process_hits_the_sigcache(tiny_root,
     assert run(tiny_root, CATCHUP)["correct"]
     again = run(tiny_root, CATCHUP)
     assert not again["correct"] and "sigcache_hits" in over(again)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_run_reports_only_the_cells_per_layer_metrics(
+        tiny_root, fresh_sigcache, cell):
+    out = run(tiny_root, cell, trace=True)
+    assert out["correct"] and over(out) == set()
+    per_layer = Manifest(tiny_root).per_layer_for(cell)
+    assert set(out["metrics"]) <= names(per_layer)
+    # a CPU gives no device trace: nothing read from one, no breakdown
+    assert not set(out["metrics"]) & names(
+        m for m in per_layer if m["source"] == "device_trace")
+    assert "breakdown" not in out and "busy_s" not in out["device"]
 
 
 def test_traced_run_reports_per_layer_metrics_only(tiny_root,
@@ -163,6 +181,139 @@ def test_commit_with_a_fault_is_not_correct(tiny_root, fresh_sigcache,
 
 # --- a new cell is new files and new entries -----------------------------------
 
+def _tree_copy(tmp_path) -> str:
+    """What the benchmark's tests read of the real tree, copied, for a
+    test to add to as a PR would: files and BENCHMARK.json entries."""
+    tree = str(tmp_path / "tree")
+    shutil.copytree(os.path.join(REPO, "benchmark"),
+                    os.path.join(tree, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copytree(os.path.join(REPO, TINY_REL),
+                    os.path.join(tree, TINY_REL))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tree)
+    return tree
+
+
+def _add(tree: str, files: dict, entries) -> None:
+    """New files (`files`: path -> text or JSON value; a path that is
+    there is refused) and the new entries that `entries(doc)` makes."""
+    for rel, content in files.items():
+        path = os.path.join(tree, rel)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "x") as f:
+            f.write(content if isinstance(content, str)
+                    else json.dumps(content))
+    path = os.path.join(tree, "BENCHMARK.json")
+    doc = load_json(path)
+    entries(doc)
+    assert validate(doc) == []
+    with open(path, "w") as f:
+        json.dump(doc, f)
+
+
+def _real(*rel) -> dict:
+    return load_json(os.path.join(REPO, *rel))
+
+
+AGAIN = "catchup-again.other-fresh-chain"
+
+
+def _missing(src: str) -> list:
+    return [(cell, os.path.relpath(path, src))
+            for cell, path in missing_tiny(src)]
+
+
+def _add_a_second_catchup_cell(tree: str, tiny_files: bool) -> dict:
+    """The catch-up configuration and its traffic once more under new
+    names, listed under the cell's end-to-end metric and one per-layer
+    metric. Returns the tiny files' paths by kind."""
+    files = {
+        "benchmark/configs/catchup-again.json":
+            dict(_real("benchmark", "configs", "catchup-200.json"),
+                 name="catchup-again"),
+        "benchmark/traffic/other-fresh-chain.json":
+            dict(_real("benchmark", "traffic", "steady-fresh-chain.json"),
+                 name="other-fresh-chain"),
+    }
+    tiny = {
+        "config": os.path.join(TINY_REL, "configs", "catchup-again.json"),
+        "traffic": os.path.join(TINY_REL, "traffic",
+                                "other-fresh-chain.json"),
+    }
+    if tiny_files:
+        files[tiny["config"]] = _real(TINY_REL, "configs",
+                                      "catchup-200.json")
+        # 6 blocks a second, where the cell that is there has 4: a 2 s
+        # window is 12 blocks in this cell and 8 in that one
+        files[tiny["traffic"]] = dict(
+            _real(TINY_REL, "traffic", "steady-fresh-chain.json"),
+            blocks_per_window_second=6)
+
+    def entries(doc):
+        first = next(c for c in doc["configs"] if c["name"] == "catchup-200")
+        doc["configs"].append(dict(
+            first, name="catchup-again",
+            file="benchmark/configs/catchup-again.json"))
+        doc["workloads"].append({
+            "name": AGAIN, "config": "catchup-again",
+            "traffic": "other-fresh-chain", "chips": 1, "why": "a test"})
+        for group, metric in (("end_to_end", "catchup_sigs_per_s"),
+                              ("per_layer", "marshal_ms_per_tile.catchup")):
+            next(m for m in doc[group]
+                 if m["name"] == metric)["workloads"].append(AGAIN)
+    _add(tree, files, entries)
+    return tiny
+
+
+def test_a_new_cell_needs_only_new_files_and_entries(tmp_path,
+                                                     fresh_sigcache):
+    """What a PR that brings a cell does, in that order: the files and
+    the entries first, the tiny checkout built from them afterwards."""
+    tree = _tree_copy(tmp_path)
+    _add_a_second_catchup_cell(tree, tiny_files=True)
+    assert _missing(tree) == _missing(REPO)
+    assert tiny_cells(tree) == CELLS + [AGAIN]
+    root = make_tiny_root(str(tmp_path / "checkout"), tree)
+    manifest = Manifest(root)
+    assert validate(manifest.doc) == []
+    plain = run(root, AGAIN)
+    assert plain["correct"] and plain["attempted"] == 12
+    assert set(plain["metrics"]) == {"catchup_sigs_per_s", "setup_s"} \
+        == names(manifest.end_to_end_for(AGAIN))
+    # (another seed: a seed's signatures are in the sigcache by now)
+    traced = run(root, AGAIN, trace=True, seed=SEED + 1)
+    assert traced["correct"]
+    assert set(traced["metrics"]) <= names(manifest.per_layer_for(AGAIN))
+    # and the cells that were there still run, at their own sizes
+    there = run(root, CATCHUP, seed=SEED + 2)
+    assert there["correct"] and there["attempted"] == 8
+    assert run(root, COMMIT)["correct"]
+
+
+def test_a_cell_without_tiny_sizes_is_left_out_and_named(tmp_path,
+                                                         fresh_sigcache):
+    tree = _tree_copy(tmp_path)
+    tiny = _add_a_second_catchup_cell(tree, tiny_files=False)
+    assert _missing(tree) == _missing(REPO) + [(AGAIN, tiny["config"]),
+                                               (AGAIN, tiny["traffic"])]
+    assert tiny_cells(tree) == CELLS
+    root = make_tiny_root(str(tmp_path / "checkout"), tree)
+    # the tiny checkout is the one of the tree without that cell: nothing
+    # of it is left to run at its published size
+    before = make_tiny_root(str(tmp_path / "as-before"))
+    assert Manifest(root).doc == Manifest(before).doc
+    with pytest.raises(ManifestError):
+        Manifest(root).cell(AGAIN)
+    for kind in ("configs", "traffic"):
+        assert sorted(os.listdir(os.path.join(root, "benchmark", kind))) \
+            == sorted(os.listdir(os.path.join(before, "benchmark", kind)))
+    # one of the two files is not enough
+    shutil.copy(os.path.join(REPO, TINY_REL, "configs", "catchup-200.json"),
+                os.path.join(tree, tiny["config"]))
+    assert _missing(tree) == _missing(REPO) + [(AGAIN, tiny["traffic"])]
+    assert run(root, COMMIT)["correct"]
+
+
 DUMMY_DRIVER = '''
 import time
 def warm():
@@ -180,52 +331,50 @@ def judge(session, result, compiles):
 '''
 
 
-def test_a_new_cell_needs_only_new_files_and_entries(tiny_root,
-                                                     fresh_sigcache):
-    b = os.path.join(tiny_root, "benchmark")
+def test_a_new_kind_of_cell_brings_its_driver_generator_and_reader(
+        tmp_path, fresh_sigcache):
+    tree = _tree_copy(tmp_path)
     files = {
-        "drivers/dummy_driver.py": DUMMY_DRIVER,
-        "generators/dummy_gen.py":
+        "benchmark/drivers/dummy_driver.py": DUMMY_DRIVER,
+        "benchmark/generators/dummy_gen.py":
             "def make(params):\n    return {'numbers': params['traffic']"
             "['numbers']}\n",
-        "layer_metrics/dummy_sum.py":
+        "benchmark/layer_metrics/dummy_sum.py":
             "def read(ctx):\n    return ctx.result['counters']['sum']\n",
-        "configs/dummy-cfg.json": json.dumps({"driver": "dummy_driver"}),
-        "traffic/dummy-mix.json": json.dumps({"generator": "dummy_gen",
-                                              "numbers": [1, 2, 3]}),
+        "benchmark/configs/dummy-cfg.json": {"driver": "dummy_driver"},
+        "benchmark/traffic/dummy-mix.json": {"generator": "dummy_gen",
+                                             "numbers": [100, 200, 300]},
+        os.path.join(TINY_REL, "configs", "dummy-cfg.json"): {},
+        os.path.join(TINY_REL, "traffic", "dummy-mix.json"):
+            {"numbers": [1, 2, 3]},
     }
-    for rel, text in files.items():
-        with open(os.path.join(b, rel), "w") as f:
-            f.write(text)
-    path = os.path.join(tiny_root, "BENCHMARK.json")
-    with open(path) as f:
-        doc = json.load(f)
-    doc["configs"].append({"name": "dummy-cfg", "source": "a test",
-                           "file": "benchmark/configs/dummy-cfg.json",
-                           "reduced": [], "why": "a test"})
-    doc["workloads"].append({"name": "dummy-cfg.mix", "config": "dummy-cfg",
-                             "traffic": "dummy-mix", "chips": 1,
-                             "why": "a test"})
-    doc["end_to_end"].append({"name": "dummy_per_s", "unit": "1/s",
-                              "better": "higher", "bound": 0.05,
-                              "source": "host_clock",
-                              "workloads": ["dummy-cfg.mix"]})
-    doc["per_layer"].append({"name": "dummy_sum.mix", "unit": "1",
-                             "better": "higher",
-                             "source": "program_counter", "layer": "dummy",
-                             "moves": "dummy_per_s",
-                             "workloads": ["dummy-cfg.mix"]})
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    from benchmark.harness.manifest import validate
-    assert validate(doc) == []
-    plain = run(tiny_root, "dummy-cfg.mix")
+
+    def entries(doc):
+        doc["configs"].append({"name": "dummy-cfg", "source": "a test",
+                               "file": "benchmark/configs/dummy-cfg.json",
+                               "reduced": [], "why": "a test"})
+        doc["workloads"].append({"name": "dummy-cfg.mix",
+                                 "config": "dummy-cfg",
+                                 "traffic": "dummy-mix", "chips": 1,
+                                 "why": "a test"})
+        doc["end_to_end"].append({"name": "dummy_per_s", "unit": "1/s",
+                                  "better": "higher", "bound": 0.05,
+                                  "source": "host_clock",
+                                  "workloads": ["dummy-cfg.mix"]})
+        doc["per_layer"].append({"name": "dummy_sum.mix", "unit": "1",
+                                 "better": "higher",
+                                 "source": "program_counter",
+                                 "layer": "dummy", "moves": "dummy_per_s",
+                                 "workloads": ["dummy-cfg.mix"]})
+    _add(tree, files, entries)
+    root = make_tiny_root(str(tmp_path / "checkout"), tree)
+    plain = run(root, "dummy-cfg.mix")
     assert plain["correct"] and set(plain["metrics"]) == {"dummy_per_s",
                                                           "setup_s"}
-    traced = run(tiny_root, "dummy-cfg.mix", trace=True)
+    traced = run(root, "dummy-cfg.mix", trace=True)
     assert traced["metrics"] == {"dummy_sum.mix": {"value": 6, "unit": "1"}}
     # and the cells that were there still run
-    assert run(tiny_root, COMMIT)["correct"]
+    assert run(root, COMMIT)["correct"]
 
 
 # --- the command without a chip -------------------------------------------------
@@ -249,7 +398,6 @@ def test_no_tpu_exits_non_zero_with_no_result_line():
 def test_benchmark_alone_exits_non_zero(tmp_path):
     """A directory that holds only BENCHMARK.json and the files under
     `paths`: the program is not beside them."""
-    import shutil
     root = str(tmp_path / "alone")
     shutil.copytree(os.path.join(REPO, "benchmark"),
                     os.path.join(root, "benchmark"),
