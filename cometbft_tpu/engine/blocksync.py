@@ -219,6 +219,8 @@ class SyncStats:
     sigs_verified: int = 0
     tiles_flushed: int = 0
     respeculations: int = 0
+    respeculated_sigs: int = 0  # signatures sent down the synchronous route
+    bans: int = 0               # bad blocks reported to the peer source
 
 
 class SyncStalled(Exception):
@@ -357,14 +359,19 @@ class BlocksyncReactor:
             # speculation miss (valset changed mid-tile or header
             # announced a change): verify synchronously, full
             # semantics, against the true set
+            lanes = sum(1 for cs in seal_commit.signatures
+                        if not cs.absent_())
             self.stats.respeculations += 1
-            try:
-                validation.verify_commit(
-                    self.verifier.chain_id, state.validators, block_id,
-                    h, seal_commit)
-                used_ok = True
-            except validation.CommitVerificationError:
-                used_ok = False
+            self.stats.respeculated_sigs += lanes
+            with shared_tracer().start("pipeline.respeculate", height=h,
+                                       lanes=lanes):
+                try:
+                    validation.verify_commit(
+                        self.verifier.chain_id, state.validators,
+                        block_id, h, seal_commit)
+                    used_ok = True
+                except validation.CommitVerificationError:
+                    used_ok = False
         if not used_ok:
             raise TileApplyError(
                 h, f"invalid commit for height {h} from peer")
@@ -441,6 +448,7 @@ class BlocksyncReactor:
                             seal_commit, by_height.get(h))
                     except TileApplyError as f:
                         self.source.ban(h)
+                        self.stats.bans += 1
                         aspan.event("banned", height=h)
                         if applied_any:
                             return state  # retry remainder next tile

@@ -21,6 +21,7 @@ difference.
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Sequence, Tuple
 
 import numpy as np
@@ -391,6 +392,14 @@ def _verify_batch_loop(pubs, msgs, sigs, batch_size, dispatch, fallback
             batch_ok, struct_ok = dispatch(pub_a, sig_a, hb, hn, z)
             if bool(batch_ok):
                 out = np.asarray(struct_ok)
+        # tiles flush on the dispatch thread, single commits on the
+        # caller's: the counters are shared
+        with _batch_lock:
+            _batch["chunks"] += 1
+            _batch["lanes"] += hi - lo
+            if dispatch is not None and out is None:
+                _batch["attributed_chunks"] += 1
+                _batch["attributed_lanes"] += hi - lo
         if out is None:  # attribution fallback / strict mode
             out = np.asarray(fallback(pub_a, sig_a, hb, hn))
         outs.append(out[:hi - lo] & ok_mask[:hi - lo])
@@ -413,12 +422,27 @@ _pallas_broken = False
 _CANARY_INTERVAL = 16
 _canary = {"runs": 0, "trips": 0}
 _dispatches = 0
+# bucket-wide chunks and real lanes through `_verify_batch_loop`, and of
+# those the ones whose RLC equation failed and went to the per-lane
+# fallback for attribution (strict mode, which has no RLC pass, counts
+# under the first pair only)
+_batch = {"chunks": 0, "lanes": 0,
+          "attributed_chunks": 0, "attributed_lanes": 0}
+_batch_lock = threading.Lock()
 
 
 def canary_stats() -> dict:
     """Snapshot of mosaic-canary counters ({"runs", "trips"}) — wired
     into the Prometheus registry as callback gauges (node/node.py)."""
     return dict(_canary)
+
+
+def batch_stats() -> dict:
+    """Snapshot of the batch loop's counters: {"chunks", "lanes"} for
+    everything it verified, {"attributed_chunks", "attributed_lanes"}
+    for the chunks a failed RLC equation sent to the per-lane kernel."""
+    with _batch_lock:
+        return dict(_batch)
 
 
 def pallas_degraded() -> bool:

@@ -333,7 +333,8 @@ class _Tile:
     sigs: List[bytes]
     future: object = None            # None => out already final
     out: Optional[np.ndarray] = None
-    valset_break: bool = False       # a header announced a new valset
+    valset_break: int = 0            # height whose header announced a
+    #                                  new valset (0: none in this tile)
     n_canaries: int = 0              # canary lanes appended at dispatch
     span: object = None              # trace span: build..settle lifetime
 
@@ -384,8 +385,16 @@ class PipelinedBlocksync:
         if supervisor is not None and watchdog is not None \
                 and watchdog.supervisor is None:
             watchdog.supervisor = supervisor
+        # `pipeline.ban`: opened at a ban, closed by the first block a
+        # later pass applies, so it outlives the run() that opened it
+        self._ban_span = None
 
     def close(self) -> None:
+        if self._ban_span is not None:
+            # the sync gave up before a refetched block applied
+            self._ban_span.set_attr("outcome", "gave-up")
+            self._ban_span.end()
+            self._ban_span = None
         if self._own_backend:
             self.backend.close()
 
@@ -418,14 +427,14 @@ class PipelinedBlocksync:
         try:
             spec_hash = spec_vals.hash()
             entries: List[TileEntry] = []
-            valset_break = False
+            valset_break = 0
             for h in range(start, end + 1):
                 block, _parts, bid = fetched[h]
                 if block.header.validators_hash != spec_hash:
                     # valset changes: heights from here respeculate at
                     # apply against the true set, and the scheduler
                     # stops filling until the pipeline drains
-                    valset_break = True
+                    valset_break = h
                     break
                 entries.append(TileEntry(
                     height=h, block=block, block_id=bid, valset=spec_vals,
@@ -619,12 +628,16 @@ class PipelinedBlocksync:
         spec_vals = state.validators
         next_start = state.last_block_height + 1
         applied_any = False
-        barrier = False  # valset change seen: drain before refilling
+        # valset change seen: drain before refilling. The open
+        # `pipeline.barrier` span IS the flag (a no-op span with tracing
+        # off): from the tile that sees the change to the resumption of
+        # speculation from the new set
+        barrier = None
         try:
             while state.last_block_height < target or inflight:
                 # fill: keep up to `depth` tiles fetched+marshaled+
                 # dispatched ahead of the apply stage
-                while (not barrier and len(inflight) < self.depth
+                while (barrier is None and len(inflight) < self.depth
                        and next_start <= target):
                     try:
                         tile = self._build_tile(next_start, target,
@@ -636,14 +649,19 @@ class PipelinedBlocksync:
                     inflight.append(tile)
                     next_start = tile.end + 1
                     if tile.valset_break:
-                        barrier = True
+                        barrier = tracer.start(
+                            "pipeline.barrier",
+                            change_height=tile.valset_break,
+                            tiles_drained=len(inflight))
                 self._inflight_gauge(len(inflight))
                 if not inflight:
                     if state.last_block_height >= target:
                         break
                     # barrier drained (or stall): resume speculation from
                     # the now-current validator set
-                    barrier = False
+                    if barrier is not None:
+                        barrier.end()
+                        barrier = None
                     spec_vals = state.validators
                     continue
 
@@ -666,6 +684,13 @@ class PipelinedBlocksync:
                                     seal_commit, by_height.get(h))
                             except TileApplyError as f:
                                 r.source.ban(h)
+                                r.stats.bans += 1
+                                if self._ban_span is None:
+                                    self._ban_span = tracer.start(
+                                        "pipeline.ban", height=h,
+                                        tiles_cancelled=len(inflight),
+                                        lanes_abandoned=sum(
+                                            t.n_lanes for t in inflight))
                                 # drop everything speculative: the
                                 # remainder refetches (possibly
                                 # re-routed) in a fresh pass; cancel
@@ -678,11 +703,16 @@ class PipelinedBlocksync:
                                     return state
                                 raise BlockValidationError(str(f)) from f
                             applied_any = True
+                            if self._ban_span is not None:
+                                self._ban_span.set_attr("refetched", h)
+                                self._ban_span.end()
+                                self._ban_span = None
                             h += 1
                 finally:
                     self._occupy("apply", 0)
-                if barrier and not inflight:
-                    barrier = False
+                if barrier is not None and not inflight:
+                    barrier.end()
+                    barrier = None
                     spec_vals = state.validators
                     next_start = state.last_block_height + 1
         except BaseException:
@@ -696,4 +726,9 @@ class PipelinedBlocksync:
             raise
         finally:
             self._inflight_gauge(0)
+            if barrier is not None:
+                # a ban or an escape cut the barrier short: the next
+                # pass meets the same change again
+                barrier.set_attr("outcome", "cut-short")
+                barrier.end()
         return state
