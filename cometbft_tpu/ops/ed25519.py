@@ -33,6 +33,7 @@ from .scalar import (bytes_to_limbs, sc_dot_mod_l, sc_lt_l, sc_mul,
                      sc_nibbles, sc_reduce_wide)
 from .sha512 import sha512_blocks, pad_messages
 from ..crypto import ref_ed25519 as ref
+from ..trace import shared_tracer
 
 
 def verify_core(pub: jnp.ndarray, sig: jnp.ndarray,
@@ -294,6 +295,21 @@ def _dummy() -> Tuple[bytes, bytes, bytes]:
     return ref.pubkey_from_seed(seed), ref.sign(seed, msg), msg
 
 
+@functools.lru_cache(maxsize=8)
+def _padding_rows(batch_size: int, max_blocks: int):
+    """What every padding lane of one (batch_size, max_blocks) bucket
+    carries, built once a process: the dummy's key, its signature, its
+    padded hash blocks and their count, each broadcast (read-only, no
+    memory of its own) to `batch_size` rows."""
+    dpub, dsig, dmsg = _dummy()
+    hblocks, hnblocks = pad_messages([dsig[:32] + dpub + dmsg], max_blocks)
+    return tuple(
+        np.broadcast_to(row, (batch_size,) + row.shape)
+        for row in (np.frombuffer(dpub, dtype=np.uint8),
+                    np.frombuffer(dsig, dtype=np.uint8),
+                    hblocks[0], hnblocks[0]))
+
+
 def prepare_batch(pubs: Sequence[bytes], msgs: Sequence[bytes],
                   sigs: Sequence[bytes], batch_size: int,
                   max_msg_len: int = 256
@@ -314,28 +330,30 @@ def prepare_batch(pubs: Sequence[bytes], msgs: Sequence[bytes],
         raise ValueError("pubs/msgs/sigs length mismatch")
     if n > batch_size:
         raise ValueError(f"{n} signatures exceed batch_size {batch_size}")
-    dpub, dsig, dmsg = _dummy()
     max_blocks = (64 + max_msg_len + 17 + 127) // 128
 
-    pub_a = np.zeros((batch_size, 32), dtype=np.uint8)
-    sig_a = np.zeros((batch_size, 64), dtype=np.uint8)
-    live = np.zeros((batch_size,), dtype=bool)
-    forced_bad = np.zeros((batch_size,), dtype=bool)
-    hash_inputs = []
-    for i in range(batch_size):
-        if i < n:
-            p, m, sg = pubs[i], msgs[i], sigs[i]
-            live[i] = True
-            if len(p) != 32 or len(sg) != 64 or len(m) > max_msg_len:
-                forced_bad[i] = True
-                p, m, sg = dpub, dmsg, dsig
-        else:
-            p, m, sg = dpub, dmsg, dsig
-        pub_a[i] = np.frombuffer(p, dtype=np.uint8)
-        sig_a[i] = np.frombuffer(sg, dtype=np.uint8)
-        hash_inputs.append(sg[:32] + p + m)
-    hblocks, hnblocks = pad_messages(hash_inputs, max_blocks)
-    return pub_a, sig_a, hblocks, hnblocks, live & ~forced_bad
+    ok = np.zeros((batch_size,), dtype=bool)
+    ok[:n] = True
+    if not (set(map(len, pubs)) <= {32} and set(map(len, sigs)) <= {64}
+            and max(map(len, msgs), default=0) <= max_msg_len):
+        dpub, dsig, dmsg = _dummy()
+        pubs, msgs, sigs = list(pubs), list(msgs), list(sigs)
+        for i in range(n):
+            if (len(pubs[i]) != 32 or len(sigs[i]) != 64
+                    or len(msgs[i]) > max_msg_len):
+                ok[i] = False
+                pubs[i], msgs[i], sigs[i] = dpub, dmsg, dsig
+    pub_r = np.frombuffer(b"".join(pubs), dtype=np.uint8).reshape(n, 32)
+    sig_r = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
+    hb_r, hn_r = pad_messages(
+        msgs, max_blocks,
+        prefix=np.concatenate([sig_r[:, :32], pub_r], axis=1))
+    # concatenate copies: what jax is handed is never the shared rows
+    pub_a, sig_a, hblocks, hnblocks = (
+        np.concatenate([real, rows[n:]]) for real, rows in zip(
+            (pub_r, sig_r, hb_r, hn_r),
+            _padding_rows(batch_size, max_blocks)))
+    return pub_a, sig_a, hblocks, hnblocks, ok
 
 
 def verify_batch(pubs: Sequence[bytes], msgs: Sequence[bytes],
@@ -384,16 +402,19 @@ def _verify_batch_loop(pubs, msgs, sigs, batch_size, dispatch, fallback
         cap = 64
         while cap < max_msg_len:
             cap *= 2
-        pub_a, sig_a, hb, hn, ok_mask = prepare_batch(
-            pubs[lo:hi], chunk_msgs, sigs[lo:hi], batch_size, cap)
+        # tiles flush on the dispatch thread, single commits on the
+        # caller's: there the host's share of a chunk can be read
+        with shared_tracer().start("ed25519.prepare", lanes=hi - lo,
+                                   batch_size=batch_size):
+            pub_a, sig_a, hb, hn, ok_mask = prepare_batch(
+                pubs[lo:hi], chunk_msgs, sigs[lo:hi], batch_size, cap)
         out = None
         if dispatch is not None:
             z = make_rlc_coefficients(batch_size)
             batch_ok, struct_ok = dispatch(pub_a, sig_a, hb, hn, z)
             if bool(batch_ok):
                 out = np.asarray(struct_ok)
-        # tiles flush on the dispatch thread, single commits on the
-        # caller's: the counters are shared
+        # either thread may be here: the counters are shared
         with _batch_lock:
             _batch["chunks"] += 1
             _batch["lanes"] += hi - lo

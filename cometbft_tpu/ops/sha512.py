@@ -215,23 +215,38 @@ def sha512_blocks(blocks: jnp.ndarray, nblocks: jnp.ndarray) -> jnp.ndarray:
     return out.reshape(*batch, 64)
 
 
-def pad_messages(msgs, max_blocks: int) -> tuple[np.ndarray, np.ndarray]:
+def pad_messages(msgs, max_blocks: int, prefix: np.ndarray | None = None
+                 ) -> tuple[np.ndarray, np.ndarray]:
     """Host helper: list of bytes -> (N, max_blocks, 128) uint8 + (N,) int32.
 
     Standard SHA-512 padding: 0x80, zeros, 128-bit big-endian bit length.
+    `prefix`, an (N, K) uint8 array, puts row i in front of message i.
+
+    One `join` fills the whole array: what follows a message (the
+    padding, and zeros up to `max_blocks`) depends on its length alone
+    and is built once a distinct length, so a batch of one length and
+    a batch of N lengths take the same path.
     """
     n = len(msgs)
-    out = np.zeros((n, max_blocks, 128), dtype=np.uint8)
-    nblocks = np.zeros((n,), dtype=np.int32)
-    for i, m in enumerate(msgs):
-        ln = len(m)
-        nb = (ln + 17 + 127) // 128
+    k = 0 if prefix is None else prefix.shape[1]
+    width = max_blocks * 128
+    lens = list(map(len, msgs))
+    tails = {}
+    for ln in dict.fromkeys(lens):
+        total = k + ln
+        nb = (total + 17 + 127) // 128
         if nb > max_blocks:
-            raise ValueError(f"message {ln}B needs {nb} blocks > {max_blocks}")
-        buf = bytearray(nb * 128)
-        buf[:ln] = m
-        buf[ln] = 0x80
-        buf[-16:] = (8 * ln).to_bytes(16, "big")
-        out[i, :nb] = np.frombuffer(bytes(buf), dtype=np.uint8).reshape(nb, 128)
-        nblocks[i] = nb
-    return out, nblocks
+            raise ValueError(
+                f"message {total}B needs {nb} blocks > {max_blocks}")
+        tails[ln] = (b"\x80" + bytes(nb * 128 - total - 17)
+                     + (8 * total).to_bytes(16, "big")
+                     + bytes(width - nb * 128)), nb
+    gap = bytes(k)
+    out = np.frombuffer(
+        bytearray().join([piece for m, ln in zip(msgs, lens)
+                          for piece in (gap, m, tails[ln][0])]),
+        dtype=np.uint8).reshape(n, width)
+    if k:
+        out[:, :k] = prefix
+    nblocks = np.array([tails[ln][1] for ln in lens], dtype=np.int32)
+    return out.reshape(n, max_blocks, 128), nblocks

@@ -59,7 +59,7 @@ from ..libs.fail import fail_point
 from ..state.execution import BlockValidationError
 from ..state.state import State
 from ..trace import ctx_of, shared_tracer
-from ..types.block import SIG_ENCODINGS
+from ..types.block import SIG_ENCODINGS, SIGN_BYTES_TEMPLATES
 
 
 # --- futures + verify backends ------------------------------------------------
@@ -424,6 +424,7 @@ class PipelinedBlocksync:
 
         self._occupy("marshal", 1)
         marshal_span = tracer.start("pipeline.marshal", parent=tspan)
+        templates, templated = SIGN_BYTES_TEMPLATES
         try:
             spec_hash = spec_vals.hash()
             entries: List[TileEntry] = []
@@ -446,6 +447,13 @@ class PipelinedBlocksync:
                                     msgs, sigs, self.r.cache)
                      for e in entries]
         finally:
+            # as _host_stage_span's: process-wide counters, this
+            # thread's delta (a commit's first lane builds its
+            # sign-bytes template, the others are served from it)
+            marshal_span.set_attr("sign_bytes_templates",
+                                  SIGN_BYTES_TEMPLATES[0] - templates)
+            marshal_span.set_attr("sign_bytes_templated",
+                                  SIGN_BYTES_TEMPLATES[1] - templated)
             marshal_span.end()
             self._occupy("marshal", 0)
 

@@ -40,6 +40,11 @@ MAX_SIGNATURE_SIZE = 96
 # delta (the catch-up pipeline's fetch and apply spans read it so)
 SIG_ENCODINGS = [0, 0]
 
+# `Commit.vote_sign_bytes` calls [that built the commit's template, that
+# were served from one], one count a call, as SIG_ENCODINGS is kept (the
+# catch-up pipeline's marshal span reads the deltas)
+SIGN_BYTES_TEMPLATES = [0, 0]
+
 
 @dataclass(frozen=True)
 class PartSetHeader:
@@ -174,6 +179,43 @@ class CommitSig:
                 raise ValueError("signature absent or oversized")
 
 
+class _SignBytesTemplate:
+    """What the precommits of one commit share in their sign-bytes: a
+    CanonicalVote's head (type, height, round and, per BlockIDFlag, the
+    block id or none) and tail (chain id) around the timestamp, field 5,
+    which alone differs from lane to lane, and with it the outer
+    length. `frames` holds, per (flag, timestamp length) met so far,
+    what precedes and what follows the timestamp's own bytes."""
+
+    __slots__ = ("chain_id", "height", "round", "block_id", "frames")
+
+    def __init__(self, chain_id: str, commit: "Commit"):
+        self.chain_id = chain_id
+        self.height = commit.height
+        self.round = commit.round
+        self.block_id = commit.block_id
+        self.frames = {}
+
+    def fits(self, chain_id: str, commit: "Commit") -> bool:
+        return (self.chain_id == chain_id and self.height == commit.height
+                and self.round == commit.round
+                and (self.block_id is commit.block_id
+                     or self.block_id == commit.block_id))
+
+    def frame(self, cs: "CommitSig", ts_len: int) -> tuple:
+        """(all that precedes, all that follows) a timestamp of `ts_len`
+        bytes in the sign-bytes of a lane with `cs`'s flag."""
+        from .vote import PRECOMMIT_TYPE
+        head, tail = proto.canonical_vote_frame(
+            PRECOMMIT_TYPE, self.height, self.round,
+            cs.block_id(self.block_id).canonical(), self.chain_id)
+        head += proto.embed_header(proto.CANONICAL_VOTE_TIMESTAMP_FIELD,
+                                   ts_len)
+        frame = (proto.uvarint(len(head) + ts_len + len(tail)) + head, tail)
+        self.frames[cs.block_id_flag, ts_len] = frame
+        return frame
+
+
 @dataclass
 class Commit:
     height: int = 0
@@ -220,12 +262,28 @@ class Commit:
     def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
         """Sign-bytes of the precommit this CommitSig attests
         (types/block.go:873-885 -> vote.go:150 -> canonical.go:57)."""
-        from .vote import PRECOMMIT_TYPE
         cs = self.signatures[val_idx]
-        bid = cs.block_id(self.block_id)
-        return proto.marshal_delimited(proto.canonical_vote(
-            PRECOMMIT_TYPE, self.height, self.round, bid.canonical(),
-            cs.timestamp, chain_id))
+        template = self.__dict__.get("_sign_bytes_template")
+        if template is None or not template.fits(chain_id, self):
+            # the template answers for (chain_id, height, round,
+            # block_id) as they are NOW: the dataclass is mutable
+            template = _SignBytesTemplate(chain_id, self)
+            self.__dict__["_sign_bytes_template"] = template
+            SIGN_BYTES_TEMPLATES[0] += 1
+        else:
+            SIGN_BYTES_TEMPLATES[1] += 1
+        ts = cs.timestamp.encode()
+        frame = template.frames.get((cs.block_id_flag, len(ts)))
+        if frame is None:
+            frame = template.frame(cs, len(ts))
+        return frame[0] + ts + frame[1]
+
+    def __getstate__(self):
+        # pickle and copy carry the fields alone: whoever holds the
+        # commit pays for its own template, as with CommitSig's memo
+        state = self.__dict__.copy()
+        state.pop("_sign_bytes_template", None)
+        return state
 
     def validate_basic(self) -> None:
         if self.height < 0 or self.round < 0:

@@ -30,8 +30,13 @@ _FIX64 = 1
 _BYTES = 2
 
 
+_ONE_BYTE = [bytes((i,)) for i in range(0x80)]
+
+
 def uvarint(n: int) -> bytes:
     assert n >= 0
+    if n < 0x80:  # every tag of a field below 16, most lengths
+        return _ONE_BYTE[n]
     out = bytearray()
     while True:
         b = n & 0x7F
@@ -73,9 +78,14 @@ def f_string(field: int, s: str) -> bytes:
     return f_bytes(field, s.encode("utf-8"))
 
 
+def embed_header(field: int, size: int) -> bytes:
+    """Tag and length that precede an embedded message of `size` bytes."""
+    return tag(field, _BYTES) + uvarint(size)
+
+
 def f_embed(field: int, payload: bytes) -> bytes:
     """Embedded message, ALWAYS emitted (gogoproto nullable=false)."""
-    return tag(field, _BYTES) + uvarint(len(payload)) + payload
+    return embed_header(field, len(payload)) + payload
 
 
 def f_embed_opt(field: int, payload: bytes | None) -> bytes:
@@ -236,17 +246,30 @@ def canonical_block_id(hash_: bytes, psh_total: int, psh_hash: bytes) -> bytes:
             + f_embed(2, canonical_part_set_header(psh_total, psh_hash)))
 
 
+CANONICAL_VOTE_TIMESTAMP_FIELD = 5
+
+
+def canonical_vote_frame(type_: int, height: int, round_: int,
+                         block_id: bytes | None,
+                         chain_id: str) -> tuple[bytes, bytes]:
+    """(head, tail) of a CanonicalVote around its timestamp: all that
+    the votes of one commit for one block id have in common."""
+    return (f_varint(1, type_)
+            + f_sfixed64(2, height)
+            + f_sfixed64(3, round_)
+            + f_embed_opt(4, block_id),
+            f_string(6, chain_id))
+
+
 def canonical_vote(type_: int, height: int, round_: int,
                    block_id: bytes | None, ts: Timestamp,
                    chain_id: str) -> bytes:
     """CanonicalVote: type=1, height=2 sfixed64, round=3 sfixed64,
     block_id=4 (nullable), timestamp=5 (non-nullable), chain_id=6."""
-    return (f_varint(1, type_)
-            + f_sfixed64(2, height)
-            + f_sfixed64(3, round_)
-            + f_embed_opt(4, block_id)
-            + f_embed(5, ts.encode())
-            + f_string(6, chain_id))
+    head, tail = canonical_vote_frame(type_, height, round_, block_id,
+                                      chain_id)
+    return (head + f_embed(CANONICAL_VOTE_TIMESTAMP_FIELD, ts.encode())
+            + tail)
 
 
 def canonical_proposal(type_: int, height: int, round_: int, pol_round: int,

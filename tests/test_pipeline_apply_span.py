@@ -127,3 +127,16 @@ def test_the_synchronous_loop_keeps_its_own_spans(chain):
 def test_with_tracing_off_no_span_is_recorded(chain):
     state, _reactor, spans = _sync(chain, depth=4, traced=False)
     assert state.last_block_height == BLOCKS and spans == []
+
+
+def test_marshal_builds_one_sign_bytes_template_a_commit(chain):
+    _state, reactor, spans = _sync(chain, depth=4)
+    marshals = _named(spans, "pipeline.marshal")
+    assert len(marshals) == BLOCKS // TILE
+    for s in marshals:
+        # a tile's commits, each one's first lane building its template
+        assert s["attrs"]["sign_bytes_templates"] == TILE
+        assert s["attrs"]["sign_bytes_templated"] == TILE * (VALIDATORS - 1)
+    assert reactor.stats.sigs_verified == sum(
+        s["attrs"]["sign_bytes_templates"] + s["attrs"]["sign_bytes_templated"]
+        for s in marshals)
