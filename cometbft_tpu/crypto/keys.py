@@ -16,7 +16,9 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass
-from typing import List, Optional, Protocol, Sequence, Tuple, runtime_checkable
+from typing import List, Protocol, Sequence, Tuple, runtime_checkable
+
+import numpy as np
 
 from . import ref_ed25519 as ref
 
@@ -137,6 +139,45 @@ class Ed25519PrivKey:
         return ED25519_KEY_TYPE
 
 
+def kernel_bucket() -> int:
+    """The ONE lane bucket every ed25519 kernel dispatch of this tree
+    rides: the Pallas lane tile, which `prewarm_verify_kernels` compiles
+    before traffic. Not a function of the lane count:
+    `ops.ed25519.verify_batch` cuts a wider batch into bucket-sized
+    chunks and pads a narrower one up, so every chunk of every caller
+    is the warmed executable, and a sub-tile or odd width never pays a
+    compile of its own (minutes) or falls to the XLA kernel
+    (`ops/ed25519._rlc_dispatch`'s alignment check). Read through the
+    module at call time: the canary tests shrink the tile."""
+    from ..ops import pallas_verify
+    return pallas_verify.TILE
+
+
+def kernel_width() -> int:
+    """Where this process's ed25519 lanes verify, and how wide: the
+    kernel bucket on a device platform, 0 = natively everywhere else.
+    A process without a device never jits a verify kernel on a route
+    the program chooses (XLA:CPU takes minutes a bucket and crashes at
+    256 lanes and more, docs/PERF.md); verdicts cannot differ, because
+    `Ed25519PubKey.verify_signature` is ZIP-215 too. Every in-process
+    caller asks here: this module's batch verifier, blocksync through
+    `Node._device_batch_size`, the farm's and ingest's fallback."""
+    from ..libs.jax_cache import is_device_platform
+    return kernel_bucket() if is_device_platform() else 0
+
+
+def verify_native(pubs: Sequence[bytes], msgs: Sequence[bytes],
+                  sigs: Sequence[bytes]) -> np.ndarray:
+    """Per-lane verdicts by the native single-signature verify (~50µs a
+    lane, never a jit): the width-0 route, and what a drained, cold or
+    canary-failed device batch falls back to. The one such loop over
+    byte triples in the tree; a key of the wrong length is a reject,
+    not an exception."""
+    return np.array([
+        len(p) == 32 and Ed25519PubKey(p).verify_signature(m, s)
+        for p, m, s in zip(pubs, msgs, sigs)], dtype=bool)
+
+
 class Ed25519BatchVerifier:
     """Accumulate-and-flush batch verifier backed by the TPU kernel
     (replaces curve25519-voi's CPU batch, reference
@@ -148,11 +189,10 @@ class Ed25519BatchVerifier:
     with no fallback re-verification pass (types/validation.go:306-315).
     """
 
-    def __init__(self, batch_size: Optional[int] = None):
+    def __init__(self):
         self._pubs: List[bytes] = []
         self._msgs: List[bytes] = []
         self._sigs: List[bytes] = []
-        self._batch_size = batch_size
 
     def __len__(self) -> int:
         return len(self._pubs)
@@ -167,40 +207,13 @@ class Ed25519BatchVerifier:
     def verify(self) -> Tuple[bool, List[bool]]:
         if not self._pubs:
             return False, []
-        n = len(self._pubs)
-        eff = self._batch_size or 1 << (n - 1).bit_length()
-        from ..libs.jax_cache import is_device_platform, ledger
-        on_device = is_device_platform()
-        if on_device:
-            # pad up to the pallas lane tile: a sub-TILE batch would
-            # take the XLA kernel (ops/ed25519._rlc_dispatch alignment
-            # check) and pay a separate multi-minute compile per
-            # width, where the TILE bucket is the one blocksync
-            # already keeps warm
-            from ..ops.pallas_verify import TILE
-            eff = -(-eff // TILE) * TILE
-        if not on_device and eff > 64 \
-                and not ledger().warm_in_process("ed25519-rlc", eff):
-            # CPU backend: jitting the RLC kernel at batch >= 256
-            # takes minutes and can crash the XLA:CPU compiler
-            # (docs/PERF.md); a >64-lane flush on a CPU node runs the
-            # native per-sig verify instead — the same clamp blocksync
-            # applies (engine/blocksync.py:79-89). The clamp LIFTS
-            # when this process already compiled the bucket (node
-            # prewarm, or an earlier flush through this verifier): the
-            # warm jit cache makes the wide kernel the cheaper path
-            # (ROADMAP item-5 residual). Process-local warmth only —
-            # XLA:CPU executables are never persisted, so another
-            # process's ledger entry predicts a full recompile, not a
-            # reload (libs/jax_cache.warm_in_process).
-            oks = [Ed25519PubKey(p).verify_signature(m, s)
-                   for p, m, s in zip(self._pubs, self._msgs,
-                                      self._sigs)]
-            return all(oks), oks
-        from ..ops.ed25519 import verify_batch
-        with ledger().compile_guard("ed25519-rlc", eff):
+        width = kernel_width()
+        if width == 0:
+            out = verify_native(self._pubs, self._msgs, self._sigs)
+        else:
+            from ..ops.ed25519 import verify_batch
             out = verify_batch(self._pubs, self._msgs, self._sigs,
-                               batch_size=eff)
+                               batch_size=width)
         oks = [bool(v) for v in out]
         return all(oks), oks
 

@@ -133,16 +133,12 @@ def verify_lanes(pubs: Sequence[bytes], msgs: Sequence[bytes],
     if not pubs:
         return np.zeros((0,), dtype=bool)
     if batch_size <= 0 or len(pubs) < BATCH_VERIFY_THRESHOLD:
-        # batch_size<=0 = no device: CPU-backend nodes must never
-        # jit the RLC kernel mid-sync (a multi-minute XLA:CPU
-        # compile per bucket, and batches >=256 crash the compiler
-        # outright — docs/PERF.md). Small tiles take this path too:
-        # the native single-sig verify beats a device dispatch +
-        # cold compile for boot catch-up over a few heights.
-        from ..crypto.keys import Ed25519PubKey
-        return np.array([
-            len(p) == 32 and Ed25519PubKey(p).verify_signature(m, s)
-            for p, m, s in zip(pubs, msgs, sigs)], dtype=bool)
+        # batch_size<=0 = no device (crypto/keys.kernel_width): a
+        # CPU-backend node never jits the RLC kernel mid-sync. Small
+        # tiles take this path too: the native single-sig verify beats
+        # a device dispatch for boot catch-up over a few heights.
+        from ..crypto.keys import verify_native
+        return verify_native(pubs, msgs, sigs)
     from ..parallel.verify import mesh_available
     if mesh_available():
         # >1 chip: the sharded RLC path — lanes spread over the
@@ -205,12 +201,6 @@ class TiledCommitVerifier:
                                 self.cache) for e in entries]
         out = verify_lanes(pubs, msgs, sigs, self.batch_size)
         settle_tile(metas, out, pubs, msgs, sigs, self.cache)
-
-    def _add_commit(self, e: TileEntry, pubs, msgs, sigs):
-        """Back-compat shim; the standalone marshal stage is
-        `marshal_commit`."""
-        return marshal_commit(self.chain_id, e, pubs, msgs, sigs,
-                              self.cache)
 
 
 @dataclass

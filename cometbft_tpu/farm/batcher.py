@@ -9,12 +9,12 @@ signature once — and dispatches the unique lanes through the
 lanes spliced per batch, a canary mismatch quarantines the device via
 the shared supervisor, and transport failures degrade to the native
 CPU per-signature path. Without a device server at all, WIDE batches
-route through the actual batch kernel when the CompileLedger proves
-the shape bucket warm (`_fallback_verify` — ROADMAP item-4 residual);
-a cold bucket keeps the per-sig native clamp, because a farm flush
-must never pay a multi-minute CPU jit (docs/PERF.md "known compile
-hazard"). The chosen backend per batch (device / kernel / cpu) lands
-in `FarmMetrics.lanes_verified{backend}`.
+on a device platform ride chunks of the one warmed kernel bucket
+(`_fallback_verify`, by `crypto/keys.kernel_width`); a process without
+a device verifies natively, because a farm flush must never pay a
+multi-minute CPU jit (docs/PERF.md "known compile hazard"). The chosen
+backend per batch (device / kernel / cpu) lands in
+`FarmMetrics.lanes_verified{backend}`.
 
 Backpressure is explicit: `submit()` raises QueueFull once the pending
 queue holds `max_pending_lanes` — the RPC layer turns that into a
@@ -115,51 +115,40 @@ class CheckTicket:
 
 def _native_verify(lanes: Sequence[Lane]) -> Tuple[List[bool], str]:
     """CPU fallback: per-signature native verify (~50µs/sig via the C
-    fast path) — the same clamp blocksync applies on CPU nodes."""
+    fast path) through each lane's own key, whatever its curve."""
     return [lane.pk.verify_signature(lane.msg, lane.sig)
             for lane in lanes], "cpu"
 
 
-# a farm flush narrower than this stays per-sig native even when the
-# kernel is warm: dispatch + padding overhead beats ~50µs/sig only
-# once the batch is wide
+# a farm flush narrower than this stays per-sig native even on a
+# device: dispatch + padding overhead beats ~50µs/sig only once the
+# batch is wide
 FARM_KERNEL_MIN_LANES = 128
 
 
 def _fallback_verify(lanes: Sequence[Lane]) -> Tuple[List[bool], str]:
-    """The no-device-server path, with the ROADMAP item-4 residual
-    closed: a WIDE all-ed25519 batch routes through the actual batch
-    kernel when the CompileLedger proves the bucket warm — process-
-    local warmth always (the jit cache makes the wide kernel the
-    cheaper path, same lift as crypto/keys.Ed25519BatchVerifier), or a
-    clean on-disk entry on a real device platform (the persistent
-    cache reloads the executable). A cold or compiler-fatal bucket
-    keeps the per-sig native clamp — a farm flush must never pay a
-    multi-minute XLA:CPU jit (docs/PERF.md "known compile hazard").
-    The chosen backend lands in FarmMetrics.lanes_verified{backend}
-    via the label this returns."""
-    n = len(lanes)
-    if n >= FARM_KERNEL_MIN_LANES \
+    """The no-device-server path: a WIDE all-ed25519 batch on a device
+    platform rides the batch kernel at the one warmed bucket
+    (`crypto/keys.kernel_width`; `verify_batch` chunks and pads to
+    it), everything else verifies natively: a farm flush must never
+    pay a multi-minute XLA:CPU jit (docs/PERF.md "known compile
+    hazard"). The chosen backend lands in
+    FarmMetrics.lanes_verified{backend} via the label this returns."""
+    from ..crypto.keys import kernel_width
+    if len(lanes) >= FARM_KERNEL_MIN_LANES \
             and all(lane.pk.type_() == ED25519 for lane in lanes) \
             and max(len(lane.msg) for lane in lanes) <= 128:
-        # the <=128 guard pins the msg-cap kernel variant: the ledger
-        # keys (kernel, bucket) without the cap dimension, and the
-        # warmed executables (prewarm, earlier flushes) are the
-        # cap-128 ones — a longer message would select a DIFFERENT
-        # never-compiled variant and pay the multi-minute jit this
-        # clamp exists to avoid
-        from ..libs.jax_cache import is_device_platform, ledger
-        eff = 1 << (n - 1).bit_length()
-        lg = ledger()
-        warm = lg.warm_in_process("ed25519-rlc", eff) or (
-            is_device_platform() and lg.seen("ed25519-rlc", eff))
-        if warm and not lg.known_crash("ed25519-rlc", eff):
+        # the <=128 guard pins the msg-cap kernel variant: the warmed
+        # executables (prewarm) are the cap-128 ones — a longer message
+        # would select a DIFFERENT never-compiled variant and pay the
+        # multi-minute jit this route exists to avoid
+        width = kernel_width()
+        if width > 0:
             from ..ops.ed25519 import verify_batch
-            with lg.compile_guard("ed25519-rlc", eff):
-                out = verify_batch([lane.pub for lane in lanes],
-                                   [lane.msg for lane in lanes],
-                                   [lane.sig for lane in lanes],
-                                   batch_size=eff)
+            out = verify_batch([lane.pub for lane in lanes],
+                               [lane.msg for lane in lanes],
+                               [lane.sig for lane in lanes],
+                               batch_size=width)
             return [bool(v) for v in out], "kernel"
     return _native_verify(lanes)
 

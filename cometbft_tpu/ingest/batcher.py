@@ -7,7 +7,8 @@ lanes here. `verify()` collapses identical (pub, msg, sig) lanes
 across txs, dispatches the unique lanes through the same
 `device_or_cpu_backend` the farm uses (DeviceClient.submit() with the
 PR-3 supervisor gating and canary lanes spliced per batch, degrading
-to the native per-signature CPU path — never the XLA kernel, the
+to the warmed kernel bucket on a device platform and to the native
+per-signature path on a CPU one — never an XLA:CPU jit, the
 docs/PERF.md compile hazard), records verified-TRUE lanes in the
 SigCache so a recheck-evicted tx resubmitted later re-enters without a
 lane, and returns a verdict per lane key.
@@ -17,9 +18,9 @@ from __future__ import annotations
 
 import inspect
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence
 
-from ..farm.batcher import device_or_cpu_backend
+from ..farm.batcher import _native_verify, device_or_cpu_backend
 from ..pipeline.cache import SigCache
 from ..trace import shared_tracer
 
@@ -41,11 +42,9 @@ class SigLane:
         return Ed25519PubKey(self.pub)
 
 
-def native_backend(lanes: Sequence[SigLane]) -> Tuple[List[bool], str]:
-    """Per-signature host verify — the deterministic no-device backend
-    (tests and the sequential A/B side inject it explicitly)."""
-    return [lane.pk.verify_signature(lane.msg, lane.sig)
-            for lane in lanes], "cpu"
+# Per-signature host verify — the deterministic no-device backend
+# (tests and the sequential A/B side inject it explicitly)
+native_backend = _native_verify
 
 
 class IngestBatcher:

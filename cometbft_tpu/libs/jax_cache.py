@@ -16,17 +16,17 @@ consistent.
 
 The CompileLedger (ROADMAP item-5 residual) persists which
 (kernel, shape-bucket) pairs have compiled on which platform/jax
-version, how long each compile took, and which pairs CRASHED the
-compiler — so bench and device-server runs can (a) attribute
-hit/miss/cold-compile in their JSON instead of silently eating a
-multi-minute XLA compile, and (b) skip shape buckets known to kill
-XLA:CPU outright (docs/PERF.md "known compile hazard") instead of
-rediscovering the SIGSEGV every round. On device platforms the jax
-persistent cache holds the actual executables; the ledger is the
-keying + attribution layer over it (XLA:CPU executables are never
-persisted — machine-feature reloads risk SIGILL — so on cpu a "seen"
-entry predicts a warm in-process recompile cost, not an artifact
-reload).
+version and how long each compile took — so bench and device-server
+runs can attribute hit/miss/cold-compile in their JSON instead of
+silently eating a multi-minute XLA compile, and the mesh and BLS
+cold-shape gates can tell what this process already compiled. It is
+on no serve path of `crypto/` or `farm/`: where ed25519 lanes verify
+is `crypto/keys.kernel_width()`'s answer, from the platform alone. On
+device platforms the jax persistent cache holds the actual
+executables; the ledger is the keying + attribution layer over it
+(XLA:CPU executables are never persisted — machine-feature reloads
+risk SIGILL — so on cpu a "seen" entry predicts a warm in-process
+recompile cost, not an artifact reload).
 """
 
 from __future__ import annotations
@@ -190,22 +190,15 @@ class CompileLedger:
     def seen(self, kernel: str, bucket: int,
              platform: str | None = None) -> bool:
         with self._lock:
-            e = self._entries.get(self.key(kernel, bucket, platform))
-        return bool(e) and not e.get("crashed")
-
-    def known_crash(self, kernel: str, bucket: int,
-                    platform: str | None = None) -> bool:
-        with self._lock:
-            e = self._entries.get(self.key(kernel, bucket, platform))
-        return bool(e) and bool(e.get("crashed"))
+            return self.key(kernel, bucket, platform) in self._entries
 
     def warm_in_process(self, kernel: str, bucket: int) -> bool:
         """True when THIS process already compiled (kernel, bucket) —
         its jit cache makes the next dispatch to that bucket cheap.
         This is deliberately NOT `seen()`: on cpu a ledger entry from
         another process only predicts the recorded compile_s all over
-        again, so the 64-lane CPU clamp (crypto/keys) lifts on
-        process-local warmth alone."""
+        again, so the cold-shape gates (mesh/executor.is_warm,
+        aggsig/aggregate) open on process-local warmth alone."""
         with self._lock:
             return self.key(kernel, bucket) in self._proc_warm
 
@@ -219,26 +212,12 @@ class CompileLedger:
             }
             self._save(dict(self._entries))
 
-    def record_crash(self, kernel: str, bucket: int,
-                     detail: str = "",
-                     platform: str | None = None) -> None:
-        with self._lock:
-            self._entries[self.key(kernel, bucket, platform)] = {
-                "kernel": kernel, "bucket": bucket, "crashed": True,
-                "detail": detail[:200],
-                "recorded_unix": int(time.time()),  # staticcheck: allow(wallclock)
-            }
-            self._save(dict(self._entries))
-
     @contextlib.contextmanager
     def compile_guard(self, kernel: str, bucket: int):
         """Wrap a possibly-compiling call: attributes a ledger hit or
         miss, times the first-touch cost, and records it on SUCCESS.
-        A raising guard records nothing — a transient runtime failure
-        (transport error mid-warm) must not brand a bucket
-        compiler-fatal; only explicit record_crash calls (e.g. bench's
-        subprocess-killed-by-signal detection) do that, and a later
-        successful record() clears the verdict."""
+        A raising guard records nothing: a transient runtime failure
+        (transport error mid-warm) is not a compile."""
         warm = self.seen(kernel, bucket)
         t0 = time.monotonic()  # staticcheck: allow(wallclock)
         yield

@@ -98,11 +98,10 @@ def _native_verify(pubs: Sequence[bytes], msgs: Sequence[bytes],
                    sigs: Sequence[bytes]) -> List[bool]:
     """The trusted CPU re-verify path (per-sig native, never a jit):
     what a canary-failed or cold-shape batch falls back to. ONE
-    implementation tree-wide: engine/blocksync.verify_lanes with
-    batch_size=0 is the native path blocksync and the pipeline drain
-    already use."""
-    from ..engine.blocksync import verify_lanes
-    return [bool(v) for v in verify_lanes(pubs, msgs, sigs, 0)]
+    implementation tree-wide: crypto/keys.verify_native, the loop
+    blocksync and the pipeline drain already use."""
+    from ..crypto.keys import verify_native
+    return [bool(v) for v in verify_native(pubs, msgs, sigs)]
 
 
 class JaxMeshBackend:
@@ -153,10 +152,10 @@ class JaxMeshBackend:
             # the (1,1) route rides verify_batch: warm when either
             # THIS backend already ran the bucket (mesh-lanes@1x1
             # guard) or the process compiled the underlying
-            # ed25519-rlc bucket (server _warm, node prewarm, an
-            # earlier Ed25519BatchVerifier flush) — a mesh degraded
-            # all the way to one chip must not bypass the cold-shape
-            # gate into a live multi-minute verify_batch compile
+            # ed25519-rlc bucket (server _warm, node prewarm) — a
+            # mesh degraded all the way to one chip must not bypass
+            # the cold-shape gate into a live multi-minute
+            # verify_batch compile
             lg = ledger()
             return (lg.warm_in_process(lanes_kernel_name((1, 1)),
                                        plan.bucket)

@@ -520,20 +520,16 @@ class Node:
     @staticmethod
     def _device_batch_size() -> int:
         """Device tile size for blocksync verification, or 0 = native
-        single-sig path. A TPU backend gets the device path; cpu stays
-        native (jitting the RLC kernel on XLA:CPU costs minutes per
-        bucket and crashes the compiler outright at batch >=256 —
-        docs/PERF.md). A client of the host's device server ships its
-        tiles there while the server is reachable and asks no backend
-        of its own. The device batch matches the pallas lane tile: a
-        sub-TILE batch would route every node verify to the XLA kernel
-        (ops/ed25519._rlc_dispatch alignment check)."""
-        from ..libs.jax_cache import DEVICE_SERVER_ENV, is_device_platform
-        from ..ops.pallas_verify import TILE
+        single-sig path: `crypto/keys.kernel_width()`, the one rule
+        (the kernel bucket on a TPU backend, native on cpu). A client
+        of the host's device server ships its tiles there while the
+        server is reachable and asks no backend of its own."""
+        from ..crypto.keys import kernel_bucket, kernel_width
+        from ..libs.jax_cache import DEVICE_SERVER_ENV
         if os.environ.get(DEVICE_SERVER_ENV):
             from ..device.client import shared_client
-            return TILE if shared_client() is not None else 0
-        return TILE if is_device_platform() else 0
+            return kernel_bucket() if shared_client() is not None else 0
+        return kernel_width()
 
     def _prewarm_kernels(self) -> None:
         """Compile the node bucket's kernels (and run the miscompile

@@ -264,10 +264,6 @@ verify_rlc_kernel_pallas = jax.jit(verify_rlc_core_pallas,
 def use_pallas_rlc() -> bool:
     """Pallas point-stage on a TPU backend; XLA path on CPU (the
     mosaic kernels target the chip; interpret mode is for tests)."""
-    import os
-    env = os.environ.get("COMETBFT_TPU_PALLAS")
-    if env is not None:
-        return env == "1"
     from ..libs.jax_cache import is_device_platform
     return is_device_platform()
 
@@ -564,8 +560,8 @@ def prewarm_verify_kernels(batch_size: int = 4096,
     # warm the kernel the live path will actually dispatch to (pallas
     # on a TPU backend, behind its miscompile canary). The
     # compile guard attributes the warm in the ledger AND marks the
-    # bucket process-warm, which is what lifts the 64-lane CPU clamp
-    # in crypto/keys.Ed25519BatchVerifier for this bucket.
+    # bucket process-warm, which mesh/executor's single-shard view
+    # reads as "no cold compile on a live flush".
     with ledger().compile_guard("ed25519-rlc", batch_size):
         _rlc_dispatch(pub_a, sig_a, hb, hn, z)
         pub_a, sig_a, hb, hn, _ = prepare_batch([pub], [msg], [bad],

@@ -13,13 +13,12 @@ same 2 SHA-512 blocks as the 64-byte bucket (ops/ed25519.prepare_batch:
 (64 + cap + 17 + 127) // 128).
 
 A test whose point is the kernel reaches the pair by passing
-KERNEL_LANES as its batch size or bucket, or by handing a size-less
-verifier 5-8 signatures (it takes the next power of two), with no
-message over KERNEL_MSG_CAP. A test whose point is only that a batch is
-verified locally hands the size-less verifier CLAMPED_LANES signatures:
-over 64 lanes a CPU backend verifies natively and compiles nothing
-(the clamp of crypto/keys.Ed25519BatchVerifier.verify, which
-test_blocksync.py and test_mesh.py also stay under).
+KERNEL_LANES as its batch size or bucket to `ops.ed25519.verify_batch`,
+`verify_lanes` or a device server, with no message over KERNEL_MSG_CAP.
+Nothing else reaches a kernel: on a CPU backend the program's own
+routes verify natively at every width and compile nothing
+(`crypto/keys.kernel_width()` is 0). A test whose point is only that a
+batch is verified locally hands the verifier LOCAL_LANES signatures.
 
 A module of its own, not names in conftest.py: a whole run imports
 tests/benchmark_harness/conftest.py under the module name `conftest`
@@ -28,4 +27,4 @@ too, so `from conftest import ...` is whichever came last.
 
 KERNEL_LANES = 8
 KERNEL_MSG_CAP = 128
-CLAMPED_LANES = 65
+LOCAL_LANES = 65

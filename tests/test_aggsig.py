@@ -429,22 +429,21 @@ def test_compile_ledger(tmp_path):
     with led.compile_guard("k", 64):
         pass
     assert led.attribution()["hits"] == 1
-    # a RAISING guard records nothing: transient failures must not
-    # brand a bucket compiler-fatal (only explicit record_crash does)
+    # a RAISING guard records nothing: a transient failure is not a
+    # compile
     with pytest.raises(RuntimeError):
         with led.compile_guard("k", 256):
             raise RuntimeError("transient stand-in")
-    assert not led.known_crash("k", 256) and not led.seen("k", 256)
-    led.record_crash("k", 256, "signal 11")
-    assert led.known_crash("k", 256) and not led.seen("k", 256)
-    # a later successful compile clears the crash verdict
+    assert not led.seen("k", 256)
+    assert not led.warm_in_process("k", 256)
     led.record("k", 256, 1.0)
-    assert led.seen("k", 256) and not led.known_crash("k", 256)
-    led.record_crash("k", 256, "signal 11")
-    # persisted: a fresh instance reads the same verdicts, and saves
-    # MERGE over foreign writers' entries instead of erasing them
+    assert led.seen("k", 256) and led.warm_in_process("k", 256)
+    # persisted: a fresh instance reads the same entries (warmth is
+    # the process's own and is not), and saves MERGE over foreign
+    # writers' entries instead of erasing them
     led2 = CompileLedger(path)
-    assert led2.seen("k", 64) and led2.known_crash("k", 256)
+    assert led2.seen("k", 64) and led2.seen("k", 256)
+    assert not led2.warm_in_process("k", 256)
     led3 = CompileLedger(path)
     led2.record("other-kernel", 4, 2.0)     # concurrent writer A
     led3.record("third-kernel", 8, 3.0)     # concurrent writer B
@@ -673,10 +672,6 @@ def test_ledger_platform_override_keys(tmp_path):
                              "compile_s": 1.0}
     assert led.seen("rlc-xla", 256, platform="cpu")
     assert not led.seen("rlc-xla", 256, platform="tpu")
-    assert not led.known_crash("rlc-xla", 256, platform="cpu")
-    led.record_crash("rlc-xla", 512, "signal 11", platform="cpu")
-    assert led.known_crash("rlc-xla", 512, platform="cpu")
-    assert not led.known_crash("rlc-xla", 512, platform="tpu")
 
 
 # --- PairingChecker: fused Miller + final-exp verdicts ------------------------

@@ -30,7 +30,7 @@ def test_blocksync_at_qa_valset_scale():
     175 validators per net, CometBFT-QA-v1.md) — the tile carries
     175 sigs/commit through the tiled verifier's marshalling path.
     Runs the native verify path (CPU platform; the device path is the
-    TPU bench's job — tools/bench_blocksync.py measures both)."""
+    benchmark's job on the chip — the catchup-200 cells)."""
     from cometbft_tpu.abci.kvstore import KVStoreApplication
     from cometbft_tpu.db.kv import MemDB
     from cometbft_tpu.engine.blocksync import BlocksyncReactor

@@ -15,7 +15,7 @@ and tamper rejection validate the composition on top.
 import random
 
 import pytest
-from _kernel_shape import CLAMPED_LANES
+from _kernel_shape import LOCAL_LANES
 
 from cometbft_tpu.crypto.batch import (MixedBatchVerifier,
                                        create_batch_verifier,
@@ -184,10 +184,10 @@ def test_sr25519_batch_verifier():
 # --- mixed-curve dispatch (BASELINE config) ----------------------------------
 
 def test_mixed_curve_batch_dispatch():
-    # CLAMPED_LANES ed25519 keys: the dispatch is the point, not the
-    # kernel, and over 64 lanes a CPU backend verifies natively
+    # LOCAL_LANES ed25519 keys: the dispatch is the point, not the
+    # kernel: a CPU backend verifies natively at any width
     # (_kernel_shape.py)
-    eds = [Ed25519PrivKey.generate(RNG) for _ in range(CLAMPED_LANES)]
+    eds = [Ed25519PrivKey.generate(RNG) for _ in range(LOCAL_LANES)]
     srs = [Sr25519PrivKey.generate(RNG) for _ in range(2)]
     secps = [Secp256k1PrivKey.generate(RNG) for _ in range(2)]
 

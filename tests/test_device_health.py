@@ -16,7 +16,7 @@ Pins the properties the subsystem exists for:
 
 import numpy as np
 import pytest
-from _kernel_shape import CLAMPED_LANES
+from _kernel_shape import LOCAL_LANES
 
 from cometbft_tpu.device import health
 from cometbft_tpu.device.health import (DeviceSupervisor, HEALTHY,
@@ -408,16 +408,16 @@ def test_remote_verifier_quarantines_lying_client_and_goes_local():
     sup = _sup(clock=FakeClock())
     client = LyingClient()
     rbv = RemoteBatchVerifier(client, supervisor=sup)
-    # CLAMPED_LANES of them: going local is the point, not the kernel,
-    # and over 64 lanes a CPU backend verifies natively (_kernel_shape.py)
-    triples = _triples(CLAMPED_LANES, seed=12)
+    # LOCAL_LANES of them: going local is the point, not the kernel,
+    # and a CPU backend verifies natively at any width (_kernel_shape.py)
+    triples = _triples(LOCAL_LANES, seed=12)
     # tamper one real signature: the lying device would have admitted it
     bad_sig = bytes([triples[1][2][0] ^ 1]) + triples[1][2][1:]
     for i, (p, m, s) in enumerate(triples):
         rbv.add(Ed25519PubKey(p), m, bad_sig if i == 1 else s)
     ok, oks = rbv.verify()
     # the LOCAL (CPU) reference
-    assert not ok and oks == [i != 1 for i in range(CLAMPED_LANES)]
+    assert not ok and oks == [i != 1 for i in range(LOCAL_LANES)]
     assert client.calls == 1
     assert sup.state == QUARANTINED
     # quarantined: the next verify never touches the device again

@@ -106,9 +106,9 @@ def test_oversized_message_unprocessable_falls_back(server):
     failures — that would brand valid signatures forged), and the batch
     seam degrades to local verification."""
     from cometbft_tpu.device.client import DeviceUnprocessable
-    # a full bucket, so that the size-less local verifier takes
-    # KERNEL_LANES too; the long message is beyond the server's
+    # a full bucket; the long message is beyond the server's
     # max_msg_len and within the capacity of the compiled shape
+    # (the local verifier it degrades to is native on a CPU backend)
     pubs, msgs, sigs = _sigs(KERNEL_LANES, seed=33)
     seed = b"\x21" * 32
     msgs[1] = b"\x01" * KERNEL_MSG_CAP
@@ -159,7 +159,6 @@ def test_dead_server_falls_back_locally(monkeypatch):
     from cometbft_tpu.crypto.keys import Ed25519PubKey
     monkeypatch.setenv(dc.ENV_VAR, "127.0.0.1:1")  # nothing listens
     monkeypatch.setattr(dc, "_shared", None)
-    # a full bucket: the size-less local verifier then takes KERNEL_LANES
     pubs, msgs, sigs = _sigs(KERNEL_LANES, seed=70)
     bv, ok = crypto_batch.create_batch_verifier(Ed25519PubKey(pubs[0]))
     assert ok  # local verifier (connect refused) or remote w/ fallback

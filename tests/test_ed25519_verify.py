@@ -158,66 +158,103 @@ def test_oversized_batch_chunks(n):
     assert list(got) == [i not in bad for i in range(n)]
 
 
-def test_cpu_clamp_lifts_on_process_warm_bucket(tmp_path, monkeypatch):
-    """ROADMAP item-5 residual: the 64-lane CPU clamp in
-    Ed25519BatchVerifier lifts once THIS process compiled the bucket
-    (CompileLedger.warm_in_process) — and stays clamped both cold and
-    when only an on-disk entry from another process exists (XLA:CPU
-    executables are never persisted; a disk entry predicts a full
-    recompile)."""
-    import os
+# --- the one route: where ed25519 lanes verify, and how wide (crypto/keys) ----
+
+def _one_bad_lane(n):
+    """n lanes signed by one key, the middle one's s altered (still
+    canonical: the structural mask cannot decide it); want[i] is the
+    verdict, checked against the reference on every lane of a small
+    batch and on the first, the bad and the last lane of a wide one."""
+    from cometbft_tpu.crypto.keys import Ed25519PrivKey
+    key = Ed25519PrivKey(b"\x07" * 32)
+    pub = key.pub_key().raw
+    msgs = [b"lane %d" % i for i in range(n)]
+    sigs = [key.sign(m) for m in msgs]
+    bad = n // 2
+    if n:
+        sigs[bad] = (sigs[bad][:40] + bytes([sigs[bad][40] ^ 1])
+                     + sigs[bad][41:])
+    want = [i != bad for i in range(n)]
+    for i in (range(n) if n <= 150 else (0, bad, n - 1)):
+        assert ref.verify(pub, msgs[i], sigs[i], zip215=True) == want[i]
+    return pub, msgs, sigs, want
+
+
+@pytest.mark.parametrize("platform,n", [
+    ("cpu", 0), ("cpu", 1), ("cpu", 64), ("cpu", 150),
+    ("tpu", 0), ("tpu", 1), ("tpu", 150), ("tpu", 512), ("tpu", 513),
+    ("tpu", 3200)])
+def test_lanes_route_by_platform_alone(platform, n, monkeypatch):
+    """`kernel_width()` is 0 on a CPU backend and the Pallas lane tile
+    on a device, whatever the lane count; `Ed25519BatchVerifier.verify`
+    asks it and nothing else: on cpu the native loop and never
+    `verify_batch`, on a device ONE `verify_batch` call at the tile,
+    which cuts n lanes into ceil(n / tile) chunks of the warmed bucket
+    (the real chunking loop runs here under a per-lane stand-in that
+    accepts, so the verdicts are the native ones: no kernel is jitted).
+    n = 0 is the width rule alone and the empty batch's refusal."""
     from cometbft_tpu.crypto import keys as K
     from cometbft_tpu.libs import jax_cache
-    import cometbft_tpu.ops.ed25519 as ops_ed
+    from cometbft_tpu.ops.pallas_verify import TILE
 
-    path = os.path.join(str(tmp_path), "ledger.json")
-    jax_cache.reset_ledger(path)
-    try:
-        calls = {"kernel": 0}
+    calls, chunks = [], []
 
-        def fake(pubs, msgs, sigs, batch_size=None, **kw):
-            calls["kernel"] += 1
-            return np.ones((len(pubs),), dtype=bool)
+    def accept(pub_a, sig_a, hb, hn):
+        chunks.append(pub_a.shape[0])
+        return np.ones((pub_a.shape[0],), dtype=bool)
 
-        monkeypatch.setattr(ops_ed, "verify_batch", fake)
-        monkeypatch.setattr(jax_cache, "backend_platform",
-                            lambda: "cpu")
+    def counting_verify_batch(pubs, msgs, sigs, batch_size=None):
+        calls.append((len(pubs), batch_size))
+        shaped = ops_ed25519._verify_batch_loop(
+            pubs, msgs, sigs, batch_size, None, accept)
+        return shaped & K.verify_native(pubs, msgs, sigs)
 
-        seed = b"\x07" * 32
-        pub = ref.pubkey_from_seed(seed)
-        msgs = [bytes([i]) for i in range(70)]
-        sigs = [ref.sign(seed, m) for m in msgs]
+    monkeypatch.setattr(jax_cache, "backend_platform", lambda: platform)
+    monkeypatch.setattr(ops_ed25519, "verify_batch", counting_verify_batch)
+    width = K.kernel_width()
+    assert width == (TILE if platform == "tpu" else 0)
 
-        def flush():
-            bv = K.Ed25519BatchVerifier(batch_size=256)
-            for m, s in zip(msgs, sigs):
-                bv.add(K.Ed25519PubKey(pub), m, s)
-            return bv.verify()
+    pub, msgs, sigs, want = _one_bad_lane(n)
+    bv = K.Ed25519BatchVerifier()
+    for m, s in zip(msgs, sigs):
+        bv.add(K.Ed25519PubKey(pub), m, s)
+    assert bv.verify() == (False, want)  # one bad lane, or none at all
+    if platform == "cpu" or n == 0:
+        assert calls == [] and chunks == []
+    else:
+        assert calls == [(n, TILE)]
+        assert chunks == [TILE] * -(-n // TILE)
 
-        ok, oks = flush()             # cold: clamped to native per-sig
-        assert ok and len(oks) == 70 and calls["kernel"] == 0
 
-        # an entry written by ANOTHER process: still clamped
-        other = jax_cache.CompileLedger(path)
-        other.record("ed25519-rlc", 256, 123.0)
-        jax_cache.reset_ledger(path)
-        assert jax_cache.ledger().seen("ed25519-rlc", 256)
-        ok, _ = flush()
-        assert calls["kernel"] == 0
-
-        # process-local warm (the prewarm/compile_guard path): lifted
-        with jax_cache.ledger().compile_guard("ed25519-rlc", 256):
-            pass
-        ok, oks = flush()
-        assert ok and len(oks) == 70 and calls["kernel"] == 1
-        # ...and a DIFFERENT bucket stays clamped
-        bv = K.Ed25519BatchVerifier(batch_size=512)
-        for m, s in zip(msgs, sigs):
-            bv.add(K.Ed25519PubKey(pub), m, s)
-        bv.verify()
-        assert calls["kernel"] == 1
-    finally:
-        jax_cache.reset_ledger()
+def test_device_layers_import_nothing_above_them():
+    """`ops/`, `crypto/`, `parallel/` and `mesh/` sit under the engine,
+    the pipeline and the node: no import in them, at any depth of any
+    function, names one of those packages."""
+    import ast
+    import pathlib
+    root = pathlib.Path(ops_ed25519.__file__).resolve().parents[1]
+    above = {"engine", "pipeline", "node"}
+    found = []
+    for layer in ("ops", "crypto", "parallel", "mesh"):
+        for path in sorted((root / layer).rglob("*.py")):
+            depth = len(path.relative_to(root).parts) - 1
+            for node in ast.walk(ast.parse(path.read_text())):
+                if isinstance(node, ast.ImportFrom):
+                    parts = (node.module or "").split(".")
+                    if node.level == 0 and parts[0] == "cometbft_tpu":
+                        parts = parts[1:]
+                    elif node.level != depth + 1:
+                        continue  # another distribution, or the layer's own
+                    names = parts[:1] if parts and parts[0] else \
+                        [a.name for a in node.names]
+                elif isinstance(node, ast.Import):
+                    names = [a.name.split(".")[1] for a in node.names
+                             if a.name.startswith("cometbft_tpu.")]
+                else:
+                    continue
+                found += [f"{path.relative_to(root)}:{node.lineno}"
+                          for name in names if name in above]
+    assert not found, found
 
 
 # --- the device sniff and the compile-cache decision (libs/jax_cache) ---------
@@ -232,7 +269,6 @@ def test_device_sniff_answers_from_the_backend(monkeypatch):
     from cometbft_tpu.ops.pallas_verify import TILE
 
     monkeypatch.delenv(jax_cache.DEVICE_SERVER_ENV, raising=False)
-    monkeypatch.delenv("COMETBFT_TPU_PALLAS", raising=False)
     assert jax_cache.backend_platform() == "cpu"
     assert not jax_cache.is_device_platform()
     assert Node._device_batch_size() == 0

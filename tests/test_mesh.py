@@ -451,42 +451,40 @@ def test_device_server_mesh_flush_attributes_shards():
 
 # --- farm kernel residual -----------------------------------------------------
 
-def test_farm_fallback_routes_warm_bucket_through_kernel(monkeypatch,
-                                                         tmp_path):
-    """ROADMAP item-4 residual: a wide farm batch routes through the
-    batch kernel when the CompileLedger proves the bucket warm in this
-    process, and stays per-sig native when cold — with the backend
-    label ('kernel' vs 'cpu') that FarmMetrics.lanes_verified records."""
+def test_farm_fallback_routes_wide_batches_through_the_warmed_bucket(
+        monkeypatch):
+    """A wide farm batch with no device server rides the batch kernel
+    at the one warmed bucket (`crypto/keys.kernel_width`, the Pallas
+    tile) on a device platform and verifies natively on a CPU one;
+    a narrow batch stays native on either — with the backend label
+    ('kernel' vs 'cpu') that FarmMetrics.lanes_verified records."""
     from cometbft_tpu.farm.batcher import _fallback_verify
     from cometbft_tpu.farm.planner import Lane
     from cometbft_tpu.libs import jax_cache
     from cometbft_tpu.ops import ed25519 as e5
+    from cometbft_tpu.ops.pallas_verify import TILE
 
-    jax_cache.reset_ledger(str(tmp_path / "ledger.json"))
-    try:
-        pubs, msgs, sigs = _batch(128, seed=5)
-        lanes = [Lane(p, m, s, Ed25519PubKey(p), i)
-                 for i, (p, m, s) in enumerate(zip(pubs, msgs, sigs))]
-        calls = []
+    pubs, msgs, sigs = _batch(128, seed=5)
+    lanes = [Lane(p, m, s, Ed25519PubKey(p), i)
+             for i, (p, m, s) in enumerate(zip(pubs, msgs, sigs))]
+    calls = []
 
-        def fake_verify_batch(p, m, s, batch_size=None, **kw):
-            calls.append((len(p), batch_size))
-            return np.array(_native_rows(p, m, s))
-        monkeypatch.setattr(e5, "verify_batch", fake_verify_batch)
+    def fake_verify_batch(p, m, s, batch_size=None, **kw):
+        calls.append((len(p), batch_size))
+        return np.array(_native_rows(p, m, s))
+    monkeypatch.setattr(e5, "verify_batch", fake_verify_batch)
 
-        # cold bucket: the per-sig native clamp holds
-        oks, backend = _fallback_verify(lanes)
-        assert backend == "cpu" and not calls
-        assert oks == [True] * 128
-        # warm the bucket (process-local, the keys.py lift rule)
-        with jax_cache.ledger().compile_guard("ed25519-rlc", 128):
-            pass
-        oks, backend = _fallback_verify(lanes)
-        assert backend == "kernel"
-        assert calls == [(128, 128)]
-        assert oks == [True] * 128
-        # narrow batches stay native even when warm
-        oks, backend = _fallback_verify(lanes[:16])
-        assert backend == "cpu" and len(calls) == 1
-    finally:
-        jax_cache.reset_ledger()
+    # a CPU backend jits nothing: native, however wide
+    assert jax_cache.backend_platform() == "cpu"
+    oks, backend = _fallback_verify(lanes)
+    assert backend == "cpu" and not calls
+    assert oks == [True] * 128
+    # a device: the kernel, at the tile and not at the batch's own width
+    monkeypatch.setattr(jax_cache, "backend_platform", lambda: "tpu")
+    oks, backend = _fallback_verify(lanes)
+    assert backend == "kernel"
+    assert calls == [(128, TILE)]
+    assert oks == [True] * 128
+    # narrow batches stay native even there
+    oks, backend = _fallback_verify(lanes[:16])
+    assert backend == "cpu" and len(calls) == 1

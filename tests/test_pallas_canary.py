@@ -30,7 +30,7 @@ def pallas_env(monkeypatch):
     """Route _rlc_dispatch to the 'pallas' kernel on the CPU platform:
     force the platform gate on, shrink TILE so BATCH is aligned, and
     reset the sticky latch + counters around each test."""
-    monkeypatch.setenv("COMETBFT_TPU_PALLAS", "1")
+    monkeypatch.setattr(e5, "use_pallas_rlc", lambda: True)
     monkeypatch.setattr(pv, "TILE", BATCH)
     monkeypatch.setattr(e5, "_pallas_broken", False)
     monkeypatch.setattr(e5, "_dispatches", 0)

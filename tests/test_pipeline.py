@@ -17,12 +17,13 @@ The slow-marked depth sweep (run_suite.sh) soaks K in {1,2,4,8}.
 
 import numpy as np
 import pytest
-from _kernel_shape import CLAMPED_LANES
+from _kernel_shape import LOCAL_LANES
 
 from cometbft_tpu.abci.kvstore import KVStoreApplication
 from cometbft_tpu.db.kv import MemDB
 from cometbft_tpu.engine.blocksync import (BlocksyncReactor, SyncStalled,
-                                           TiledCommitVerifier)
+                                           TiledCommitVerifier,
+                                           marshal_commit)
 from cometbft_tpu.engine.chain_gen import (LocalChainSource,
                                            generate_chain)
 from cometbft_tpu.libs.metrics import Registry
@@ -244,13 +245,13 @@ def test_remote_batch_verifier_retries_once_then_local():
         # process-wide shared instance from a test fixture client
         return DeviceSupervisor(backoff_base_s=0.01, backoff_cap_s=0.1)
 
-    # CLAMPED_LANES signatures a flush: going local is the point, not the
-    # kernel, and over 64 lanes a CPU backend verifies natively
+    # LOCAL_LANES signatures a flush: going local is the point, not the
+    # kernel: a CPU backend verifies natively at any width
     # (_kernel_shape.py)
     seed = b"\x05" * 32
     pk = Ed25519PubKey(ref.pubkey_from_seed(seed))
     signed = [(m, ref.sign(seed, m)) for m in
-              (b"hello %d" % i for i in range(CLAMPED_LANES))]
+              (b"hello %d" % i for i in range(LOCAL_LANES))]
 
     def fill(rbv):
         for msg, sig in signed:
@@ -262,7 +263,7 @@ def test_remote_batch_verifier_retries_once_then_local():
     flaky = FlakyClient(ConnectionError("link down"))
     s1 = sup()
     ok, oks = fill(RemoteBatchVerifier(flaky, supervisor=s1)).verify()
-    assert ok and oks == [True] * CLAMPED_LANES
+    assert ok and oks == [True] * LOCAL_LANES
     assert flaky.calls == 2
     assert s1.state == SUSPECT and s1.trips == 2
 
@@ -271,7 +272,7 @@ def test_remote_batch_verifier_retries_once_then_local():
     wedged = FlakyClient(TimeoutError("wedged"))
     s2 = sup()
     ok, oks = fill(RemoteBatchVerifier(wedged, supervisor=s2)).verify()
-    assert ok and oks == [True] * CLAMPED_LANES
+    assert ok and oks == [True] * LOCAL_LANES
     assert wedged.calls == 1
     assert s2.state == SUSPECT
 
@@ -280,7 +281,7 @@ def test_remote_batch_verifier_retries_once_then_local():
     unproc = FlakyClient(DeviceUnprocessable("too big"))
     s3 = sup()
     ok, oks = fill(RemoteBatchVerifier(unproc, supervisor=s3)).verify()
-    assert ok and oks == [True] * CLAMPED_LANES
+    assert ok and oks == [True] * LOCAL_LANES
     assert unproc.calls == 1
     assert s3.state == HEALTHY
 
@@ -376,7 +377,8 @@ def test_tile_cache_skips_device_lanes_same_verdicts():
 
     second = entries()
     pubs, msgs, sigs = [], [], []
-    metas = [v._add_commit(e, pubs, msgs, sigs) for e in second]
+    metas = [marshal_commit(v.chain_id, e, pubs, msgs, sigs, v.cache)
+             for e in second]
     assert pubs == [] and all(rows for _e, rows, _n in metas)
     v.verify_tile(entries())  # end-to-end warm pass
     assert cache.hits.get("blocksync") >= 2 * n_sigs

@@ -114,8 +114,7 @@ def test_chip_smoke_fails_without_a_tpu():
 
 
 @pytest.mark.parametrize("script", [
-    "bench.py", "tools/bench_blocksync.py", "tools/bench_light.py",
-    "tools/bench_vote_ingest.py"])
+    "bench.py", "tools/bench_light.py", "tools/bench_vote_ingest.py"])
 def test_device_benches_exit_nonzero_without_a_tpu(script):
     """A device metric is measured on the chip or not at all: no CPU
     fallback number, no JSON line on stdout."""
