@@ -80,7 +80,19 @@ class Ed25519PubKey:
             raise ValueError(f"ed25519 pubkey must be 32B, got {len(self.raw)}")
 
     def address(self) -> bytes:
-        return address_from_pubkey_bytes(self.raw)
+        """Memoized per instance, as CommitSig.encode is: the key is
+        frozen, and proposer rotation asks a 200-member set for two to
+        four addresses a comparison, every height. The memo is not a
+        field and does not travel through pickle/copy
+        (`__getstate__`): whoever holds the key pays its one hash."""
+        memo = self.__dict__.get("_address_memo")
+        if memo is None:
+            memo = address_from_pubkey_bytes(self.raw)
+            object.__setattr__(self, "_address_memo", memo)
+        return memo
+
+    def __getstate__(self):
+        return {"raw": self.raw}
 
     def bytes_(self) -> bytes:
         return self.raw

@@ -57,7 +57,7 @@ from ..engine.blocksync import (BlocksyncReactor, SyncStalled,
                                 settle_tile, verify_lanes)
 from ..libs.fail import fail_point
 from ..state.execution import BlockValidationError
-from ..state.state import State
+from ..state.state import VALSET_ENCODINGS, State
 from ..trace import ctx_of, shared_tracer
 from ..types.block import SIG_ENCODINGS, SIGN_BYTES_TEMPLATES
 
@@ -309,16 +309,23 @@ def _host_stage_span(tracer, name: str, parent):
     """A main-thread stage's span, carrying the CommitSig wire
     encodings the stage computed and the ones it reused (fetch pays a
     commit's one encoding in make_part_set, apply meets it three times
-    more: last_commit_hash and the `C:`/`SC:` store keys). The counters
-    are process-wide; the two stages never overlap and nothing else on
-    the catch-up path encodes a CommitSig."""
+    more: last_commit_hash and the `C:`/`SC:` store keys), and the
+    validator-set encodings likewise (StateStore.save asks four a block
+    applied, fetch none). The counters are process-wide; the two stages
+    never overlap and nothing else on the catch-up path encodes a
+    CommitSig or a validator set."""
     computed, reused = SIG_ENCODINGS
+    vs_computed, vs_reused = VALSET_ENCODINGS
     with tracer.start(name, parent=parent) as span:
         try:
             yield span
         finally:
             span.set_attr("sig_enc_computed", SIG_ENCODINGS[0] - computed)
             span.set_attr("sig_enc_reused", SIG_ENCODINGS[1] - reused)
+            span.set_attr("valset_enc_computed",
+                          VALSET_ENCODINGS[0] - vs_computed)
+            span.set_attr("valset_enc_reused",
+                          VALSET_ENCODINGS[1] - vs_reused)
 
 
 @dataclass

@@ -263,13 +263,31 @@ class StateStore:
         return len(deletes)
 
 
+# `_valset_to_json` calls [that encoded the set, that were served from
+# the set's memo], one count a call, kept as types/block.SIG_ENCODINGS
+# is (process-wide, unlocked, exact as one thread's delta: the catch-up
+# pipeline's apply span reads it so)
+VALSET_ENCODINGS = [0, 0]
+
+
 def _valset_to_json(vs: ValidatorSet) -> bytes:
+    """Memoized on the set (`ValidatorSet._json_memo`, carried by
+    copy(), dropped by every mutator): a State holds the same set value
+    in up to three places (validators(H+1) is next_validators(H),
+    last_validators(H+1) is validators(H)) and StateStore.save writes
+    `validators` twice, so of a height's four encodings only
+    next_validators', whose priorities rotated, is new."""
+    memo = vs._json_memo
+    if memo is not None:
+        VALSET_ENCODINGS[1] += 1
+        return memo
+    VALSET_ENCODINGS[0] += 1
     # key type stored per validator (absent == ed25519, so every state
     # written before BLS valsets existed still loads): a BLS valset
     # round-tripped through the store must come back as BLS keys, not
     # be silently re-typed
     prop = vs.get_proposer()
-    return json.dumps({
+    vs._json_memo = memo = json.dumps({
         "validators": [
             {"pub_key": v.pub_key.bytes_().hex(),
              "type": v.pub_key.type_(),
@@ -279,6 +297,7 @@ def _valset_to_json(vs: ValidatorSet) -> bytes:
         "proposer": prop.pub_key.bytes_().hex() if prop else None,
         "proposer_type": prop.pub_key.type_() if prop else None,
     }).encode()
+    return memo
 
 
 def _valset_from_json(raw: bytes) -> ValidatorSet:

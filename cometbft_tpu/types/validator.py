@@ -71,6 +71,11 @@ class ValidatorSet:
     # class-level default so raw __new__ constructions (e.g. state
     # deserialization) inherit an empty memo instead of AttributeError
     _hash: Optional[bytes] = None
+    # the set's stored encoding (state/state.py `_valset_to_json`),
+    # memoized beside _hash and _total. It covers proposer priorities
+    # and the proposer, so every writer of those drops it: the four
+    # mutators below, which are the only code that writes them
+    _json_memo: Optional[bytes] = None
 
     def __init__(self, validators: List[Validator],
                  proposer: Optional[Validator] = None):
@@ -141,11 +146,17 @@ class ValidatorSet:
         return self.proposer
 
     def copy(self) -> "ValidatorSet":
+        """An independent set (its own Validators: they carry the
+        mutable proposer_priority) that shares what a copy cannot
+        change: the address index, which update_with_change_set
+        replaces and never mutates, and the memos, which each set's
+        own mutators drop."""
         cp = ValidatorSet.__new__(ValidatorSet)
         cp.validators = [v.copy() for v in self.validators]
-        cp._by_address = {v.address: i for i, v in enumerate(cp.validators)}
+        cp._by_address = self._by_address
         cp._total = self._total
         cp._hash = self._hash
+        cp._json_memo = self._json_memo
         cp.proposer = None
         if self.proposer is not None:
             idx = cp._by_address.get(self.proposer.address)
@@ -161,6 +172,7 @@ class ValidatorSet:
         prios = [v.proposer_priority for v in self.validators]
         diff = max(prios) - min(prios)
         if diff > diff_max:
+            self._json_memo = None
             ratio = (diff + diff_max - 1) // diff_max
             for v in self.validators:
                 # Go integer division truncates toward zero
@@ -170,6 +182,7 @@ class ValidatorSet:
     def _shift_by_avg_proposer_priority(self) -> None:
         n = len(self.validators)
         avg = sum(v.proposer_priority for v in self.validators) // n
+        self._json_memo = None
         for v in self.validators:
             v.proposer_priority = _clip(v.proposer_priority - avg)
 
@@ -189,6 +202,7 @@ class ValidatorSet:
         if times <= 0:
             raise ValueError("times must be positive")
         diff_max = PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power()
+        self._json_memo = None
         self.rescale_priorities(diff_max)
         self._shift_by_avg_proposer_priority()
         proposer = None
@@ -261,6 +275,7 @@ class ValidatorSet:
                             for i, v in enumerate(self.validators)}
         self._total = None
         self._hash = None
+        self._json_memo = None
         self.total_voting_power()
 
         self.rescale_priorities(

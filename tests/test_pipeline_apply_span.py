@@ -1,8 +1,9 @@
-"""The pipelined catch-up's `pipeline.apply` span and the CommitSig
-encodings its `pipeline.fetch` and `pipeline.apply` spans carry
-(pipeline/scheduler.py `_host_stage_span`): one apply span a tile,
-parented on the tile's span, and over a whole sync every signature
-encoded once and met three times more. 8 validators in 4-block tiles on
+"""The pipelined catch-up's `pipeline.apply` span and the CommitSig and
+validator-set encodings its `pipeline.fetch` and `pipeline.apply` spans
+carry (pipeline/scheduler.py `_host_stage_span`): one apply span a
+tile, parented on the tile's span, over a whole sync every signature
+encoded once and met three times more, and of a block's four
+validator-set encodings one computed. 8 validators in 4-block tiles on
 the in-process backend: 32 lanes a tile take the native route on a CPU,
 so nothing is jitted."""
 
@@ -104,6 +105,27 @@ def test_every_signature_is_encoded_once_and_met_three_times_more(chain):
                for s in _named(spans, "pipeline.apply")) == VALIDATORS
 
 
+def test_apply_asks_four_valset_encodings_a_block_and_computes_one(chain):
+    _state, reactor, spans = _sync(chain, depth=4)
+    for s in spans:
+        if s["name"] in STAGES:
+            assert set(s["attrs"]) >= {"valset_enc_computed",
+                                       "valset_enc_reused"}
+    # fetch saves no state
+    assert all(s["attrs"]["valset_enc_computed"]
+               == s["attrs"]["valset_enc_reused"] == 0
+               for s in _named(spans, "pipeline.fetch"))
+    applies = [s["attrs"] for s in _named(spans, "pipeline.apply")]
+    # StateStore.save: the State's three sets and the `vals:` index
+    assert [a["valset_enc_computed"] + a["valset_enc_reused"]
+            for a in applies] == [4 * TILE] * (BLOCKS // TILE)
+    # only next_validators, whose priorities rotated, is new; the first
+    # save of a sync has nothing to reuse but `validators` itself
+    assert [a["valset_enc_computed"] for a in applies] == \
+        [TILE + 2] + [TILE] * (BLOCKS // TILE - 1)
+    assert reactor.stats.blocks_applied == BLOCKS
+
+
 def test_an_apply_that_fails_still_ends_its_span(chain):
     class Stubborn(LocalChainSource):
         def ban(self, height):
@@ -114,7 +136,8 @@ def test_an_apply_that_fails_still_ends_its_span(chain):
     assert state is None and src.banned
     applies = _named(spans, "pipeline.apply")
     assert applies and all(a["t1"] >= a["t0"] > 0 for a in applies)
-    assert all("sig_enc_reused" in a["attrs"] for a in applies)
+    assert all("sig_enc_reused" in a["attrs"]
+               and "valset_enc_reused" in a["attrs"] for a in applies)
 
 
 def test_the_synchronous_loop_keeps_its_own_spans(chain):
