@@ -3,7 +3,7 @@
 never seen, served by a local peer.
 
 The reactor is built as `Node._sync_then_consensus` builds it
-(node/node.py:643-690): tile size from the configuration's `tile_size`
+(node/node.py): tile size from the configuration's `tile_size`
 (the literal 16 there), lane bucket `Node._device_batch_size()`,
 `BlockSyncConfig().pipeline_depth`, a `DeviceWatchdog`, the in-process
 backend and `cache=shared_cache()`."""

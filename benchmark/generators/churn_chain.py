@@ -8,8 +8,9 @@ by `State.make_block`, executed by the real `BlockExecutor` with
 `verified=True` (nothing here reaches `types.validation.verify_commit`,
 so not one signature reaches the process-wide sigcache), precommits
 signed by the plain reference over sign-bytes from the benchmark's own
-CanonicalVote encoder, one block more than the chain's length. What is
-new is the schedule of updates, which is the configuration's:
+CanonicalVote encoder, one block more than the chain's length, no
+header's hash memo left on the blocks. What is new is the schedule of
+updates, which is the configuration's:
 
 - every `update_period` heights, first in block `first_update_block`, one
   update: turn 0, 2, 4, ... a power change of one validator, from
@@ -35,7 +36,9 @@ from __future__ import annotations
 import hashlib
 import random
 
-from benchmark.generators.fresh_chain import BASE_TIME, window_blocks
+from benchmark.generators.fresh_chain import (BASE_TIME,
+                                              forget_header_hashes,
+                                              window_blocks)
 from benchmark.reference import canonical_vote, ed25519_ref
 
 
@@ -117,8 +120,9 @@ def build_chain(chain_id: str, n_blocks: int, n_validators: int,
             last_commit = Commit(height=h, round=0, block_id=block_id,
                                  signatures=sigs)
     return {"chain_id": chain_id, "genesis": genesis, "n_blocks": n_blocks,
-            "blocks": blocks, "block_ids": block_ids, "tx_lists": tx_lists,
-            "app_hash": app_hash, "n_validators": n_validators,
+            "blocks": forget_header_hashes(blocks), "block_ids": block_ids,
+            "tx_lists": tx_lists, "app_hash": app_hash,
+            "n_validators": n_validators,
             "genesis_members": [(s.pub, power) for s in signers],
             "update_blocks": update_blocks}
 

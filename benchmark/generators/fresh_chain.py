@@ -3,7 +3,7 @@
 Copied in outline from the program's `engine.chain_gen.generate_chain`
 (blocks are built by `State.make_block` and executed by the real
 `BlockExecutor`, because a peer serves a node the program's own block
-types), with three differences that matter to a measurement:
+types), with four differences that matter to a measurement:
 
 - the precommits are signed by the plain reference (`cryptography`
   wheel) over sign-bytes from the benchmark's own CanonicalVote encoder,
@@ -14,7 +14,13 @@ types), with three differences that matter to a measurement:
   process-wide `pipeline.cache.shared_cache()` (trap 1 of ISSUE 25) —
   whichever process runs this;
 - one more block than the chain's length is made: block N+1 carries the
-  commit that seals block N, as a peer at height N+1 would serve it.
+  commit that seals block N, as a peer at height N+1 would serve it;
+- no memo of the program rides the payload: the blocks come back
+  without the `Header._hash_memo` that this generator's own
+  `block.hash()` calls set (`forget_header_hashes`), so the node under
+  test pays its first header hashes inside the window, as it pays its
+  first `CommitSig` encodings, sign-bytes templates and addresses (those
+  memos the program itself keeps out of a pickle).
 
 Every seed gives the same sizes: the seed changes keys, hashes and
 signatures, never the number of blocks, validators or lanes.
@@ -38,6 +44,15 @@ def window_blocks(seconds: float, per_second: float, tile: int) -> int:
     """Chain length for a window: whole tiles, so that every tile of
     the window has the same lanes."""
     return max(tile, math.ceil(seconds * per_second / tile) * tile)
+
+
+def forget_header_hashes(blocks: list) -> list:
+    """`blocks`, each header without the hash that `Header.hash`
+    memoised on it: a frozen instance's `__dict__` entry, which pickle
+    would carry to the node under test."""
+    for block in blocks:
+        block.header.__dict__.pop("_hash_memo", None)
+    return blocks
 
 
 def build_chain(chain_id: str, n_blocks: int, n_validators: int,
@@ -93,8 +108,9 @@ def build_chain(chain_id: str, n_blocks: int, n_validators: int,
             last_commit = Commit(height=h, round=0, block_id=block_id,
                                  signatures=sigs)
     return {"chain_id": chain_id, "genesis": genesis, "n_blocks": n_blocks,
-            "blocks": blocks, "block_ids": block_ids, "tx_lists": tx_lists,
-            "app_hash": app_hash, "n_validators": n_validators}
+            "blocks": forget_header_hashes(blocks), "block_ids": block_ids,
+            "tx_lists": tx_lists, "app_hash": app_hash,
+            "n_validators": n_validators}
 
 
 def make(params: dict) -> dict:

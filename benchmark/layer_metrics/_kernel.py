@@ -1,7 +1,8 @@
 """The verify program's device seconds in the traced window."""
 
 # the jitted RLC program as the profiler names it (`jit_<function>`); the
-# per-lane attribution program is `verify_kernel` and runs in no window
+# per-lane attribution program is `jit_verify_core`, which this does not
+# match: `_attribution.py` reads it, in the cell where it runs
 PROGRAM = r"verify_rlc_core_pallas"
 
 

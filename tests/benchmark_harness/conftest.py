@@ -7,7 +7,14 @@ The tiny sizes are data, found by name: `tiny/configs/<config>.json` and
 `tiny/traffic/<mix>.json` beside this file, each a dict merged over the
 real file. A PR that lists a cell in `BENCHMARK.json` adds the two files
 for it (where they are not there yet) and edits nothing here; the tests
-that walk the cells (`CELLS`) then run it."""
+that walk the cells (`CELLS`) then run it.
+
+A test that asserts anything of `BENCHMARK.json` takes it from the
+fixtures `tree` (the checkout whose manifest is judged) or `doc` (that
+manifest), never from `REPO` itself: `test_benchmark_additions.py` runs
+every such test once more on a tree to which a later PR's entries have
+been appended, so a test that pins today's lists fails in the PR that
+writes it."""
 
 import json
 import os
@@ -20,12 +27,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
+from benchmark.harness.manifest import validate  # noqa: E402
+
 TINY_REL = os.path.join("tests", "benchmark_harness", "tiny")
 
 
 def load_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def real_json(*rel) -> dict:
+    return load_json(os.path.join(REPO, *rel))
 
 
 def _tiny_file(src: str, kind: str, name: str) -> str:
@@ -102,12 +115,55 @@ def make_tiny_root(dst: str, src: str = REPO) -> str:
     return dst
 
 
+def tree_copy(dst: str) -> str:
+    """What the benchmark's tests read of the real tree, copied to
+    `dst`, for a test to add to as a PR would: files and BENCHMARK.json
+    entries."""
+    shutil.copytree(os.path.join(REPO, "benchmark"),
+                    os.path.join(dst, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copytree(os.path.join(REPO, TINY_REL),
+                    os.path.join(dst, TINY_REL))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), dst)
+    return dst
+
+
+def add_to_tree(tree: str, files: dict, entries) -> None:
+    """New files (`files`: path -> text or JSON value; a path that is
+    there is refused) and the new entries that `entries(doc)` makes."""
+    for rel, content in files.items():
+        path = os.path.join(tree, rel)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "x") as f:
+            f.write(content if isinstance(content, str)
+                    else json.dumps(content))
+    path = os.path.join(tree, "BENCHMARK.json")
+    doc = load_json(path)
+    entries(doc)
+    assert validate(doc) == []
+    with open(path, "w") as f:
+        json.dump(doc, f)
+
+
 CELLS = tiny_cells()
 
 
 @pytest.fixture
-def tiny_root(tmp_path):
-    return make_tiny_root(str(tmp_path / "checkout"))
+def tree():
+    """The checkout whose BENCHMARK.json a test judges: the real one
+    here, the one with a later PR's additions where
+    `test_benchmark_additions.py` runs the same test."""
+    return REPO
+
+
+@pytest.fixture
+def doc(tree):
+    return load_json(os.path.join(tree, "BENCHMARK.json"))
+
+
+@pytest.fixture
+def tiny_root(tmp_path, tree):
+    return make_tiny_root(str(tmp_path / "checkout"), tree)
 
 
 @pytest.fixture

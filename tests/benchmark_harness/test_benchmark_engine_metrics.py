@@ -90,7 +90,8 @@ def test_a_traced_cpu_run_of_the_cell_leaves_both_out(tiny_root,
                           time.perf_counter(), look_for_chip=False,
                           in_process_traffic=True)
     # the node's bucket is 0 on a CPU, so the sync is the synchronous loop
-    assert out["correct"] and out["metrics"] == {}
+    assert out["correct"] and not set(out["metrics"]) & {
+        "apply_ms_per_tile.catchup", "commit_encode_reuse_share.catchup"}
 
 
 def test_readers_on_a_pipelined_sync_through_the_cells_driver(

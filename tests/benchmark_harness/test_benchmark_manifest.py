@@ -1,5 +1,7 @@
 """BENCHMARK.json is valid, every file it names loads by name, and every
-cell it lists has the tiny sizes that the tests run it at."""
+cell it lists has the tiny sizes that the tests run it at. (`tree` and
+`doc`: conftest.py; `test_benchmark_additions.py` runs these once more
+on a tree with a later PR's additions.)"""
 
 import json
 import os
@@ -11,16 +13,10 @@ from benchmark.harness.manifest import (NAME_RE, UNIT_RE, Manifest,
                                         ManifestError, validate)
 
 
-@pytest.fixture(scope="module")
-def doc():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
-def test_manifest_is_valid(doc):
+def test_manifest_is_valid(tree, doc):
     assert validate(doc) == []
     assert 1 <= doc["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+    assert os.path.getsize(os.path.join(tree, "BENCHMARK.json")) < 64 * 1024
 
 
 def test_names_and_units_keep_to_the_allowed_characters(doc):
@@ -56,8 +52,8 @@ def test_validate_names_the_fault(doc, breakage, complaint):
     assert any(complaint in line for line in validate(broken))
 
 
-def test_every_named_file_loads(doc):
-    manifest = Manifest(REPO)
+def test_every_named_file_loads(tree, doc):
+    manifest = Manifest(tree)
     for w in doc["workloads"]:
         cell = manifest.cell(w["name"])
         driver = manifest.load_module("drivers", cell.config["driver"])
@@ -79,12 +75,12 @@ def test_every_named_file_loads(doc):
         manifest.cell("no-such.cell")
 
 
-def test_every_cell_has_its_tiny_sizes():
+def test_every_cell_has_its_tiny_sizes(tree):
     """A cell without them is left out of the tests' tiny checkout
     (conftest.py `make_tiny_root`), so no test would run it: it fails
     here, once, by name."""
-    missing = [f"cell {cell}: {os.path.relpath(path, REPO)} is missing"
-               for cell, path in missing_tiny()]
+    missing = [f"cell {cell}: {os.path.relpath(path, tree)} is missing"
+               for cell, path in missing_tiny(tree)]
     assert not missing, (
         "; ".join(missing) + " (its tiny sizes: a JSON dict merged over "
         "the real file of that name, benchmark/README.md says how)")
