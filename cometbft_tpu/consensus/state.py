@@ -28,9 +28,12 @@ from ..privval.file import DoubleSignError, PrivValidator
 from ..state.execution import BlockExecutor, BlockValidationError
 from ..state.state import State
 from ..types.block import Block, BlockID, Commit, Part, PartSet
+from ..types import validation
 from ..types.proto import Timestamp
 from ..types.vote import (Proposal, Vote, PREVOTE_TYPE, PRECOMMIT_TYPE)
-from ..types.vote_set import ErrVoteConflictingVotes, VoteError, VoteSet
+from ..trace import shared_tracer
+from ..types.vote_set import (ErrVoteConflictingVotes, VoteError, VoteSet,
+                              preverify_lanes)
 from .height_vote_set import HeightVoteSet
 from .ticker import TimeoutInfo, TimeoutTicker
 from .wal import (EndHeightMessage, NilWAL, WALBlockPart, WALProposal,
@@ -157,6 +160,29 @@ _thread_check_violations = 0
 _violation_lock = threading.Lock()
 
 
+# The batched vote intake's counters (`_intake`), process-wide like the
+# verified-signature cache it fills: peer votes the receive routine
+# handled, the runs it drained them in, the flushes through the
+# crypto.batch seam, and of the lanes it was to verify those the cache
+# answered, those a flush verified and those left to the per-vote check.
+_intake_counts = {"votes_handled": 0, "runs": 0, "flushes": 0,
+                  "device_lanes": 0, "native_lanes": 0, "cache_hits": 0}
+_intake_lock = threading.Lock()
+_NOTHING_HELD = object()
+
+
+def intake_stats() -> dict:
+    """Snapshot of the vote intake's counters. `votes_handled` rises as
+    each peer vote of a run has been handled, so a caller that filled
+    the inbox can tell when it has been consumed."""
+    with _intake_lock:
+        return dict(_intake_counts)
+
+
+def _is_peer_vote(entry) -> bool:
+    return isinstance(entry, tuple) and isinstance(entry[0], VoteMessage)
+
+
 @dataclass
 class RoundState:
     """reference internal/consensus/types/round_state.go:65-100."""
@@ -233,6 +259,7 @@ class ConsensusState:
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self._replaying = False
+        self._run_cap: Optional[int] = None     # lanes a run may hold
 
         # harness/reactor hooks
         self.broadcast: Callable[[Message], None] = lambda msg: None
@@ -289,18 +316,107 @@ class ConsensusState:
         # checking, see RoundState.claim — the race-detector analog)
         self._writer_tid = threading.get_ident()
         self.rs.claim(self._writer_tid)
+        held = _NOTHING_HELD    # taken from the inbox behind a run of votes
         while not self._stop.is_set():
-            msg = self.inbox.get()
-            if msg is None:
+            entry = self.inbox.get() if held is _NOTHING_HELD else held
+            held = _NOTHING_HELD
+            if entry is None:
                 break
-            try:
-                self.handle_msg(msg)
-            except DoubleSignError:
-                raise  # never continue past a refused signature
-            except Exception:  # noqa: BLE001 — a bad peer msg must not
-                # kill the loop (reference recovers/logs, state.go:784-800)
-                import traceback
-                traceback.print_exc()
+            if _is_peer_vote(entry):
+                held = self._intake(entry)
+            else:
+                self._handle_guarded(entry)
+
+    def _handle_guarded(self, entry) -> None:
+        try:
+            self.handle_msg(entry)
+        except DoubleSignError:
+            raise  # never continue past a refused signature
+        except Exception:  # noqa: BLE001 — a bad peer msg must not
+            # kill the loop (reference recovers/logs, state.go:784-800)
+            import traceback
+            traceback.print_exc()
+
+    def _intake(self, first):
+        """A peer's vote and the run of peer votes queued directly
+        behind it: their signatures that the cache does not hold are
+        verified in ONE flush through the crypto.batch seam where they
+        are worth one (types/vote_set.py `preverify_lanes`: the device,
+        on a TPU), then every message of the run is handled exactly as a
+        lone one is, in arrival order, through `handle_msg`: logged to
+        the WAL, then added, the state machine moving at the same vote.
+        The flush only fills the verified-signature cache, so the
+        per-vote path finds its signature there or, for a lane that
+        failed, stayed under the threshold or was never looked at,
+        verifies natively and raises what it raises.
+
+        Returns the entry that ended the run (taken from the inbox and
+        not a peer's vote), or `_NOTHING_HELD`."""
+        run, held = [first], _NOTHING_HELD
+        if not self.inbox.empty():
+            if self._run_cap is None:
+                from ..crypto.keys import kernel_width
+                self._run_cap = kernel_width() or 512
+            while len(run) < self._run_cap:
+                try:
+                    entry = self.inbox.get_nowait()
+                except queue.Empty:
+                    break
+                if not _is_peer_vote(entry):
+                    held = entry
+                    break
+                run.append(entry)
+        with shared_tracer().start("consensus.intake", votes=len(run),
+                                   height=first[0].vote.height) as span:
+            lanes = self._run_lanes(run)
+            if len(lanes) < validation.BATCH_VERIFY_THRESHOLD:
+                # cannot reach the threshold: not even encoded
+                hits, flushed, native = 0, 0, len(lanes)
+            else:
+                hits, flushed, native = preverify_lanes([
+                    (pub_key, vote.sign_bytes(self.chain_id),
+                     vote.signature) for pub_key, vote in lanes])
+            span.set_attr("cache_hits", hits)
+            span.set_attr("device_lanes", flushed)
+            span.set_attr("native_lanes", native)
+            span.set_attr("flushed", int(flushed > 0))
+            with _intake_lock:
+                _intake_counts["runs"] += 1
+                _intake_counts["flushes"] += int(flushed > 0)
+                _intake_counts["cache_hits"] += hits
+                _intake_counts["device_lanes"] += flushed
+                _intake_counts["native_lanes"] += native
+            for entry in run:
+                try:
+                    self._handle_guarded(entry)
+                finally:
+                    with _intake_lock:
+                        _intake_counts["votes_handled"] += 1
+        return held
+
+    def _run_lanes(self, run) -> list:
+        """(public key, vote) of the run's votes whose signature
+        `add_vote` would look up in the cache, as the round state stands
+        now: the set `_add_vote` routes each vote to, the validator
+        `_precheck` finds. A vote for another height, a late precommit
+        outside STEP_NEW_HEIGHT, a catch-up round, an exact duplicate, a
+        set with vote extensions: no lane, the per-vote path deals with
+        it as ever."""
+        rs, lanes = self.rs, []
+        for msg, _peer_id in run:
+            vote = msg.vote
+            if vote.height == rs.height:
+                val = rs.votes.lane_validator(vote)
+            elif vote.height + 1 == rs.height and \
+                    vote.type_ == PRECOMMIT_TYPE and \
+                    rs.step == STEP_NEW_HEIGHT and \
+                    rs.last_commit is not None:
+                val = rs.last_commit.lane_validator(vote)
+            else:
+                val = None
+            if val is not None:
+                lanes.append((val.pub_key, vote))
+        return lanes
 
     def send(self, msg: Message, peer_id: str = "") -> None:
         """Enqueue a message from a peer or self (thread-safe)."""
@@ -906,7 +1022,8 @@ class ConsensusState:
         if rs.proposal_block is None or \
                 rs.proposal_block.hash() != bid.hash:
             return
-        self._finalize_commit(height)
+        with shared_tracer().start("consensus.finalize", height=height):
+            self._finalize_commit(height)
 
     def _finalize_commit(self, height: int) -> None:
         """reference state.go:1673-1770 finalizeCommit."""

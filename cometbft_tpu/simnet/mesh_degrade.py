@@ -178,6 +178,12 @@ class _MeshSim:
 
     def _run_traced(self, t0: float, recorder) -> SimResult:
         from ..engine.chain_gen import generate_chain
+        from ..pipeline.cache import reset_shared_cache
+        # the trace records what the process-wide verified-signature
+        # cache answered (`commit.verify`: cache_hits, native_lanes), so
+        # a run starts with an empty one: its trace stays a function of
+        # (scenario, seed), not of what the process verified before
+        reset_shared_cache()
         self.build()
         self.log("start", scenario=self.name, seed=self.seed,
                  blocks=self.n_blocks, vals=self.n_vals,

@@ -18,6 +18,7 @@ from dataclasses import dataclass
 from typing import Callable, Optional
 
 from ..crypto import batch as crypto_batch
+from ..trace import shared_tracer
 from .block import Commit, CommitSig, BlockID
 from .validator import ValidatorSet
 
@@ -80,7 +81,12 @@ def _verify_basic(vals: ValidatorSet, commit: Commit, height: int,
         raise CommitVerificationError("invalid commit -- wrong block ID")
 
 
-def _should_batch_verify(vals: ValidatorSet, commit: Commit) -> bool:
+def _should_batch_verify(vals: ValidatorSet, missing: int) -> bool:
+    """Whether `missing` lanes, the ones the verified-signature cache
+    did not answer, are worth one flush through the batch seam. Asked of
+    the lanes that MISS, not of the commit's size: a validator's commits
+    are mostly hits, and three misses of 150 are three native checks,
+    not a 512-lane dispatch."""
     prop = vals.get_proposer()
     if prop is None:
         return False
@@ -92,7 +98,7 @@ def _should_batch_verify(vals: ValidatorSet, commit: Commit) -> bool:
         # pays for itself at the reference's own threshold of 2
         # (types/validation.go:13) — no device dispatch involved.
         threshold = 2
-    return (len(commit.signatures) >= threshold
+    return (missing >= threshold
             and crypto_batch.supports_batch_verifier(prop.pub_key))
 
 
@@ -116,84 +122,119 @@ def _verify_commit_core(chain_id: str, vals: ValidatorSet, commit: Commit,
             ignore=ignore, count=count, count_all=count_all,
             lookup_by_index=lookup_by_index, cache=_shared_cache())
         return
-    use_batch = _should_batch_verify(vals, commit)
+    with shared_tracer().start("commit.verify") as span:
+        _verify_commit_lanes(chain_id, vals, commit, voting_power_needed,
+                             ignore, count, count_all, lookup_by_index,
+                             span)
+
+
+def _verify_commit_lanes(chain_id, vals, commit, voting_power_needed,
+                         ignore, count, count_all, lookup_by_index,
+                         span) -> None:
+    # verified-signature cache (pipeline/cache): commits re-checked by
+    # the light client or blocksync's respeculation path, and a
+    # validator's `last_commit`s, whose signatures it took in as votes,
+    # skip signatures a previous pass already verified TRUE; cached
+    # lanes never reach a verifier and failed lanes are never cached,
+    # so verdicts are byte-identical with the uncached path
+    from ..pipeline.cache import shared_cache
+    cache = shared_cache()
+
+    # first the walk: the structural checks, the tally and the cache
+    # lookups. The route is chosen afterwards, from the lanes that
+    # missed; what the walk refuses is held back until then, because the
+    # two routes have always raised in different orders (below)
+    tallied = 0
+    seen = {}
+    hits = 0
+    missing = []    # (idx, pub key, its bytes, sign-bytes, signature)
+    refused = None
+    try:
+        for idx, cs in enumerate(commit.signatures):
+            if ignore(cs):
+                continue
+            try:
+                cs.validate_basic()
+            except ValueError as e:
+                raise CommitVerificationError(
+                    f"invalid signature at index {idx}: {e}") from e
+
+            if lookup_by_index:
+                val = vals.get_by_index(idx)
+            else:
+                val_idx, val = vals.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen:
+                    raise CommitVerificationError(
+                        f"double vote from validator {val_idx} "
+                        f"({seen[val_idx]} and {idx})")
+                seen[val_idx] = idx
+
+            msg = commit.vote_sign_bytes(chain_id, idx)
+            pkb = val.pub_key.bytes_()
+            if cache.seen(pkb, msg, cs.signature, path="commit"):
+                hits += 1   # previously verified TRUE: no work either route
+            else:
+                missing.append((idx, val.pub_key, pkb, msg, cs.signature))
+
+            if count(cs):
+                tallied += val.voting_power
+            if not count_all and tallied > voting_power_needed:
+                break
+        if tallied <= voting_power_needed:
+            raise ErrNotEnoughVotingPowerSigned(tallied,
+                                                voting_power_needed)
+    except CommitVerificationError as e:
+        refused = e
+
     bv = None
-    if use_batch:
+    if _should_batch_verify(vals, len(missing)):
         if len({v.pub_key.type_() for v in vals.validators}) > 1:
             # heterogeneous valset: a proposer-keyed single-curve
             # verifier would TypeError on the first foreign-curve
             # lane; the mixed dispatcher buckets per curve (batched
             # where supported, per-sig singles otherwise) with exact
             # per-lane attribution
-            bv, ok = crypto_batch.MixedBatchVerifier(), True
+            bv = crypto_batch.MixedBatchVerifier()
         else:
-            bv, ok = crypto_batch.create_batch_verifier(
+            bv, _ok = crypto_batch.create_batch_verifier(
                 vals.get_proposer().pub_key)
-        use_batch = ok
+    span.set_attr("lanes", hits + len(missing))
+    span.set_attr("cache_hits", hits)
+    span.set_attr("device_lanes", len(missing) if bv is not None else 0)
+    span.set_attr("native_lanes", 0 if bv is not None else len(missing))
 
-    # verified-signature cache (pipeline/cache): commits re-checked by
-    # the light client or blocksync's respeculation path skip signatures
-    # a previous pass already verified TRUE; cached lanes never reach
-    # the device and failed lanes are never cached, so verdicts are
-    # byte-identical with the uncached path
-    from ..pipeline.cache import shared_cache
-    cache = shared_cache()
-
-    tallied = 0
-    seen = {}
-    batch_idxs = []
-    batch_items = []  # (pub_bytes, msg, sig) per device lane, for cache
-    for idx, cs in enumerate(commit.signatures):
-        if ignore(cs):
-            continue
-        try:
-            cs.validate_basic()
-        except ValueError as e:
-            raise CommitVerificationError(
-                f"invalid signature at index {idx}: {e}") from e
-
-        if lookup_by_index:
-            val = vals.get_by_index(idx)
-        else:
-            val_idx, val = vals.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen:
-                raise CommitVerificationError(
-                    f"double vote from validator {val_idx} "
-                    f"({seen[val_idx]} and {idx})")
-            seen[val_idx] = idx
-
-        msg = commit.vote_sign_bytes(chain_id, idx)
-        pkb = val.pub_key.bytes_()
-        if cache.seen(pkb, msg, cs.signature, path="commit"):
-            pass  # previously verified TRUE: no work either path
-        elif use_batch:
-            bv.add(val.pub_key, msg, cs.signature)
-            batch_idxs.append(idx)
-            batch_items.append((pkb, msg, cs.signature))
-        else:
-            if not val.pub_key.verify_signature(msg, cs.signature):
-                raise ErrWrongSignature(idx, cs.signature)
-            cache.add(pkb, msg, cs.signature)
-
-        if count(cs):
-            tallied += val.voting_power
-        if not count_all and tallied > voting_power_needed:
-            break
-
-    if tallied <= voting_power_needed:
-        raise ErrNotEnoughVotingPowerSigned(tallied, voting_power_needed)
-
-    if use_batch and len(bv):
-        all_ok, oks = bv.verify()
-        for (pkb, msg, sig), ok in zip(batch_items, oks):
-            if ok:
-                cache.add(pkb, msg, sig)
-        if not all_ok:
-            first_bad = next(i for i, o in zip(batch_idxs, oks) if not o)
-            raise ErrWrongSignature(
-                first_bad, commit.signatures[first_bad].signature)
+    if bv is None:
+        # the native route verifies in index order and names the first
+        # signature that fails, before anything the walk refused at or
+        # after it (reference verifyCommitSingle)
+        for idx, pub_key, pkb, msg, sig in missing:
+            if not pub_key.verify_signature(msg, sig):
+                raise ErrWrongSignature(idx, sig)
+            cache.add(pkb, msg, sig)
+        if refused is not None:
+            raise refused
+        return
+    # the batch route flushes only what passed the walk (reference
+    # verifyCommitBatch: the tally is checked before the batch verifies)
+    if refused is not None:
+        raise refused
+    for _idx, pub_key, _pkb, msg, sig in missing:
+        bv.add(pub_key, msg, sig)
+    _all_ok, oks = bv.verify()
+    # fail-closed: a lane counts as verified only on its own verdict; a
+    # verifier that answers for fewer lanes than it was given has
+    # refused the rest
+    oks = [bool(ok) for ok in oks] + [False] * (len(missing) - len(oks))
+    first_bad = None
+    for (idx, _pk, pkb, msg, sig), ok in zip(missing, oks):
+        if ok:
+            cache.add(pkb, msg, sig)
+        elif first_bad is None:
+            first_bad = ErrWrongSignature(idx, sig)
+    if first_bad is not None:
+        raise first_bad
 
 
 def verify_commit(chain_id: str, vals: ValidatorSet, block_id: BlockID,

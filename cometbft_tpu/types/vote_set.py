@@ -121,7 +121,8 @@ class VoteSet:
 
     def _check_signature(self, vote: Vote, val) -> None:
         """The per-vote hot path (types/vote.go:235); raises on failure.
-        Shared by add_vote and add_votes' non-batched fallback."""
+        A batched intake ahead of it (`preverify_lanes`) shows here as a
+        cache hit, and as nothing else."""
         addr = vote.validator_address
         if self.extensions_enabled:
             if vote.block_id.is_nil() and \
@@ -154,107 +155,49 @@ class VoteSet:
             if vote.extension or vote.extension_signature:
                 raise VoteError("unexpected vote extension data")
 
+    def lane_validator(self, vote: Optional[Vote]):
+        """The validator whose key `add_vote(vote)` would look this
+        vote's signature up under in the verified-signature cache, or
+        None where it never gets that far or the lookup is not all of
+        the check: a vote `_precheck` refuses, an exact duplicate, a set
+        with vote extensions (their second signature is not batched), a
+        vote carrying extension data it should not. What a batched
+        intake may verify ahead of `add_vote`, and nothing else."""
+        if self.extensions_enabled:
+            return None
+        try:
+            val = self._precheck(vote)
+        except VoteError:
+            return None
+        if val is None or vote.extension or vote.extension_signature:
+            return None
+        return val
+
     def add_votes(self, votes: List[Vote]) -> List:
-        """Batched ingest: marshal every pending signature into ONE
-        device batch (the crypto/batch seam → ops/ed25519 kernel), then
-        add with per-lane verdicts — the TPU-native form of the addVote
-        hot path for gossip bursts and catch-up, where per-signature
-        host verification (~400µs on a small host core) would dominate
-        (reference crypto/ed25519/ed25519.go:208-241 batches the same
-        way for commits; here it is applied to live vote ingest).
+        """Batched ingest: the signatures of the whole list that the
+        cache does not hold are verified in ONE flush through the
+        crypto/batch seam where they are worth one (`preverify_lanes`),
+        then every vote is added as `add_vote` adds it, which finds its
+        signature verified or verifies it natively (reference
+        crypto/ed25519/ed25519.go:208-241 batches the same way for
+        commits; here it is applied to vote ingest). Consensus does the
+        same over the run of votes queued in its inbox
+        (consensus/state.py `_intake`).
 
         Returns one entry per vote: True (added), False (exact
         duplicate), or the VoteError instance that add_vote would have
         raised (conflicts carry both votes).
         """
-        out: List = [None] * len(votes)
-        pend = []
-        for i, v in enumerate(votes):
+        preverify_lanes([
+            (val.pub_key, v.sign_bytes(self.chain_id), v.signature)
+            for v in votes
+            if (val := self.lane_validator(v)) is not None])
+        out: List = []
+        for v in votes:
             try:
-                val = self._precheck(v)
+                out.append(self.add_vote(v))
             except VoteError as e:
-                out[i] = e
-                continue
-            if val is None:
-                out[i] = False
-                continue
-            if not self.extensions_enabled and \
-                    (v.extension or v.extension_signature):
-                out[i] = VoteError("unexpected vote extension data")
-                continue
-            pend.append((i, v, val))
-
-        if not pend:
-            return out
-        from ..crypto import batch as crypto_batch
-        from .validation import BATCH_VERIFY_THRESHOLD
-        bv = None
-        # same threshold rationale as commit verification: below it the
-        # native single-sig path beats a device dispatch
-        if not self.extensions_enabled and \
-                len(pend) >= BATCH_VERIFY_THRESHOLD:
-            bv, ok = crypto_batch.create_batch_verifier(pend[0][2].pub_key)
-            if ok and all(val.pub_key.type_() == pend[0][2].pub_key.type_()
-                          for _i, _v, val in pend):
-                # verified-signature cache: a re-gossiped burst costs
-                # zero device lanes; only misses are marshaled, and
-                # verified-true lanes are written back
-                from ..pipeline.cache import shared_cache
-                cache = shared_cache()
-                marshal = [(val.pub_key.bytes_(),
-                            v.sign_bytes(self.chain_id), v.signature,
-                            val.pub_key)
-                           for _i, v, val in pend]
-                # fail-closed: every lane starts UNVERIFIED (None is
-                # falsy below); only a cache hit or an explicit verifier
-                # verdict marks it — a short lane_oks from a buggy
-                # backend must never admit an unchecked vote
-                oks = [None] * len(pend)
-                lanes = []                # positions needing the device
-                for pos, (pkb, sb, sig, pk) in enumerate(marshal):
-                    if cache.seen(pkb, sb, sig, path="vote"):
-                        oks[pos] = True
-                        continue
-                    bv.add(pk, sb, sig)
-                    lanes.append(pos)
-                if lanes:
-                    _, lane_oks = bv.verify()
-                    for pos, lane_ok in zip(lanes, lane_oks):
-                        oks[pos] = lane_ok
-                        if lane_ok:
-                            pkb, sb, sig, _pk = marshal[pos]
-                            cache.add(pkb, sb, sig)
-            else:
-                bv = None
-        if bv is None:
-            oks = []
-            for i, v, val in pend:
-                try:
-                    self._check_signature(v, val)
-                    oks.append(True)
-                except VoteError as e:
-                    out[i] = e
-                    oks.append(False)
-
-        for (i, v, _val), sig_ok in zip(pend, oks):
-            if not sig_ok:
-                if out[i] is None:  # batched path: generic attribution
-                    out[i] = ErrVoteInvalidSignature(
-                        f"failed to verify vote from "
-                        f"{v.validator_address.hex()}")
-                continue
-            try:
-                # re-precheck: an earlier vote in THIS batch may have
-                # landed for the same validator (duplicate in one gossip
-                # burst) — without this the duplicate would hit
-                # _add_verified_vote's assertion
-                val = self._precheck(v)
-                if val is None:
-                    out[i] = False
-                    continue
-                out[i] = self._finish_add(v, val)
-            except VoteError as e:
-                out[i] = e
+                out.append(e)
         return out
 
     def _precheck(self, vote: Optional[Vote]):
@@ -468,3 +411,53 @@ class VoteSet:
         return (f"VoteSet{{H:{self.height} R:{self.round} "
                 f"T:{self.signed_msg_type} {voted}/{len(self.val_set)} "
                 f"maj23:{self.maj23 is not None}}}")
+
+
+def preverify_lanes(lanes) -> tuple:
+    """The batched half of vote intake. `lanes` are (public key,
+    sign-bytes, signature) of votes about to go through `add_vote`, one
+    after the other. Each is looked up in the verified-signature cache;
+    where the lanes that MISS reach `BATCH_VERIFY_THRESHOLD` and are all
+    of one key type the seam batches, they are verified in ONE flush
+    through `crypto.batch` (the device, on a TPU) and those that
+    verified true are added to the cache, where `_check_signature` finds
+    them. Below the threshold nothing is verified here: the native
+    single check beats a dispatch (same rule as commit verification,
+    types/validation.py).
+
+    Fail-closed by construction: this only ever ADDS verified-true
+    signatures to the cache, each on its own lane's verdict. A lane that
+    failed, a lane a short verdict list left out, a lane never handed in:
+    `add_vote` verifies it natively and raises what it raises.
+
+    Returns (cache hits, lanes flushed, lanes left to the native check).
+    """
+    from ..crypto import batch as crypto_batch
+    from ..pipeline.cache import shared_cache
+    from .validation import BATCH_VERIFY_THRESHOLD
+    if len(lanes) < BATCH_VERIFY_THRESHOLD:
+        return 0, 0, len(lanes)     # cannot reach it: not even looked up
+    cache = shared_cache()
+    hits, missing, asked = 0, [], set()
+    for pub_key, sign_bytes, sig in lanes:
+        pkb = pub_key.bytes_()
+        if (pkb, sign_bytes, sig) in asked:
+            continue                # the same vote twice in one list
+        asked.add((pkb, sign_bytes, sig))
+        if cache.seen(pkb, sign_bytes, sig, path="vote"):
+            hits += 1
+        else:
+            missing.append((pub_key, pkb, sign_bytes, sig))
+    if len(missing) < BATCH_VERIFY_THRESHOLD or \
+            len({lane[0].type_() for lane in missing}) != 1:
+        return hits, 0, len(missing)
+    bv, ok = crypto_batch.create_batch_verifier(missing[0][0])
+    if not ok:
+        return hits, 0, len(missing)
+    for pub_key, _pkb, sign_bytes, sig in missing:
+        bv.add(pub_key, sign_bytes, sig)
+    _all_ok, lane_oks = bv.verify()
+    for (_pk, pkb, sign_bytes, sig), lane_ok in zip(missing, lane_oks):
+        if lane_ok:
+            cache.add(pkb, sign_bytes, sig)
+    return hits, len(missing), 0
