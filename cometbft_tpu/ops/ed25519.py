@@ -375,6 +375,12 @@ def verify_batch(pubs: Sequence[bytes], msgs: Sequence[bytes],
                               dispatch, fallback)
 
 
+# chunks of one call that may be on the device with their verdicts not
+# yet read: a flush of tens of thousands of lanes cannot pile its
+# buffers there (a tile holds 7)
+_MAX_UNREAD_CHUNKS = 16
+
+
 def _verify_batch_loop(pubs, msgs, sigs, batch_size, dispatch, fallback
                        ) -> np.ndarray:
     """The shared host-side chunking protocol behind every batch-verify
@@ -383,43 +389,76 @@ def _verify_batch_loop(pubs, msgs, sigs, batch_size, dispatch, fallback
     `batch_size` bucket with power-of-two message capacity, try ONE RLC
     equation per chunk via `dispatch(pub, sig, hb, hn, z)`, and
     attribute failed chunks (or serve strict mode, dispatch=None) via
-    the per-lane `fallback(pub, sig, hb, hn)`."""
+    the per-lane `fallback(pub, sig, hb, hn)`.
+
+    Dispatch all, read back once: the chunks of a call (at most
+    `_MAX_UNREAD_CHUNKS` at a time) are prepared and dispatched one
+    after the other and what `dispatch` returned is kept UNREAD, so the
+    device runs chunk k while the host prepares chunk k+1 (JAX dispatch
+    is asynchronous); only then are the verdicts read, in order, and a
+    chunk whose equation failed sent to `fallback` with the arrays it
+    was dispatched with. Their coefficients come from ONE draw of OS
+    entropy, a row a lane, padding lanes included, none used twice. A
+    call of one chunk is "dispatch, then read" as ever."""
     n = len(pubs)
     if n == 0:
         return np.zeros((0,), dtype=bool)
     if batch_size is None:
         batch_size = 1 << (n - 1).bit_length()
+    tracer = shared_tracer()
+    starts = range(0, n, batch_size)
     outs = []
-    for lo in range(0, n, batch_size):
-        hi = min(lo + batch_size, n)
-        chunk_msgs = msgs[lo:hi]
-        max_msg_len = max((len(m) for m in chunk_msgs), default=0)
-        # bucket message capacity to limit kernel variants
-        cap = 64
-        while cap < max_msg_len:
-            cap *= 2
-        # tiles flush on the dispatch thread, single commits on the
-        # caller's: there the host's share of a chunk can be read
-        with shared_tracer().start("ed25519.prepare", lanes=hi - lo,
-                                   batch_size=batch_size):
-            pub_a, sig_a, hb, hn, ok_mask = prepare_batch(
-                pubs[lo:hi], chunk_msgs, sigs[lo:hi], batch_size, cap)
-        out = None
+    for first in range(0, len(starts), _MAX_UNREAD_CHUNKS):
+        window = starts[first:first + _MAX_UNREAD_CHUNKS]
         if dispatch is not None:
-            z = make_rlc_coefficients(batch_size)
-            batch_ok, struct_ok = dispatch(pub_a, sig_a, hb, hn, z)
-            if bool(batch_ok):
-                out = np.asarray(struct_ok)
-        # either thread may be here: the counters are shared
-        with _batch_lock:
-            _batch["chunks"] += 1
-            _batch["lanes"] += hi - lo
-            if dispatch is not None and out is None:
-                _batch["attributed_chunks"] += 1
-                _batch["attributed_lanes"] += hi - lo
-        if out is None:  # attribution fallback / strict mode
-            out = np.asarray(fallback(pub_a, sig_a, hb, hn))
-        outs.append(out[:hi - lo] & ok_mask[:hi - lo])
+            z = make_rlc_coefficients(batch_size * len(window))
+        unread = []
+        for i, lo in enumerate(window):
+            hi = min(lo + batch_size, n)
+            chunk_msgs = msgs[lo:hi]
+            max_msg_len = max((len(m) for m in chunk_msgs), default=0)
+            # bucket message capacity to limit kernel variants
+            cap = 64
+            while cap < max_msg_len:
+                cap *= 2
+            # tiles flush on the dispatch thread, single commits on the
+            # caller's: there the host's share of a chunk can be read
+            with tracer.start("ed25519.prepare", lanes=hi - lo,
+                              batch_size=batch_size):
+                pub_a, sig_a, hb, hn, ok_mask = prepare_batch(
+                    pubs[lo:hi], chunk_msgs, sigs[lo:hi], batch_size, cap)
+            verdict = None
+            if dispatch is not None:
+                verdict = dispatch(
+                    pub_a, sig_a, hb, hn,
+                    z[i * batch_size:(i + 1) * batch_size])
+            unread.append((hi - lo, (pub_a, sig_a, hb, hn), ok_mask,
+                           verdict))
+        # from the last chunk's dispatch to the last verdict read
+        # (strict mode dispatched nothing: it has 0 chunks to read back)
+        with tracer.start("ed25519.readback",
+                          chunks=len(unread) if dispatch is not None else 0,
+                          lanes=sum(u[0] for u in unread)) as rspan:
+            attributed = 0
+            for lanes, arrays, ok_mask, verdict in unread:
+                out = None
+                if verdict is not None:
+                    batch_ok, struct_ok = verdict
+                    if bool(batch_ok):
+                        out = np.asarray(struct_ok)
+                failed = verdict is not None and out is None
+                attributed += failed
+                # either thread may be here: the counters are shared
+                with _batch_lock:
+                    _batch["chunks"] += 1
+                    _batch["lanes"] += lanes
+                    if failed:
+                        _batch["attributed_chunks"] += 1
+                        _batch["attributed_lanes"] += lanes
+                if out is None:  # attribution fallback / strict mode
+                    out = np.asarray(fallback(*arrays))
+                outs.append(out[:lanes] & ok_mask[:lanes])
+            rspan.set_attr("attributed_chunks", attributed)
     return np.concatenate(outs)
 
 

@@ -44,6 +44,7 @@ from __future__ import annotations
 import contextlib
 import inspect
 import queue
+import sys
 import threading
 from collections import deque
 from dataclasses import dataclass
@@ -98,7 +99,17 @@ class LocalAsyncBackend:
     function (ops/ed25519 via engine/blocksync.verify_lanes) so
     submit() returns immediately — JAX device dispatch of tile N
     overlaps host marshal of tile N+1. A verify crash lands in the
-    future as an exception; the watchdog turns it into a CPU fallback."""
+    future as an exception; the watchdog turns it into a CPU fallback.
+
+    Who waits for whom: the thread sends a tile's chunks to the device
+    one after the other and reads their verdicts back once, after the
+    last (`ops.ed25519._verify_batch_loop`), then sets the future; the
+    main thread asks for the future `depth - 1` tiles later and should
+    find it set. The two share the interpreter lock: every step of the
+    thread that lets go of it (entropy, a transfer, an execute, a
+    read-back) has to get it back from a main thread that is running
+    Python, which is why `PipelinedBlocksync.run` shortens the switch
+    interval while it owns such a backend."""
 
     def __init__(self, verify_fn, name: str = "pipeline-verify"):
         self._verify = verify_fn
@@ -303,6 +314,14 @@ class CorruptBackend:
 
 
 # --- the scheduler ------------------------------------------------------------
+
+# CPython hands the interpreter lock to a thread that asks for it only
+# after this long (5 ms by default) when the holder runs Python without
+# a pause, as the main thread's marshal and apply do: every step of the
+# dispatch thread that let go of the lock then waits that long to go on.
+# PERF.md section 6 (PR 35) has the runs on the chip that chose the value.
+_SWITCH_INTERVAL_S = 0.0002
+
 
 @contextlib.contextmanager
 def _host_stage_span(tracer, name: str, parent):
@@ -560,28 +579,30 @@ class PipelinedBlocksync:
                 cancel()
 
     def _settle(self, tile: _Tile) -> None:
-        """Resolve the tile's verdicts (waiting on the dispatch under
-        the watchdog deadline; CPU fallback on wedge) and map them onto
-        entry.commit_ok."""
+        """Resolve the tile's verdicts and map them onto
+        entry.commit_ok. The tile was handed to the backend `depth - 1`
+        tiles ago, so its verdicts should be on the host already:
+        `pipeline.settle.wait` is what the main thread still sleeps for
+        them (under the watchdog deadline; CPU fallback on wedge), the
+        rest of `pipeline.settle` is `settle_tile`'s host work."""
         tracer = shared_tracer()
         sspan = tracer.start("pipeline.settle", parent=tile.span)
         try:
             if tile.out is None:
                 total = tile.n_lanes + tile.n_canaries
-                if self.watchdog is not None:
-                    out = self.watchdog.result(tile.future, total)
-                    if out is None:  # wedged: drain tile to the CPU
-                        self._cancel(tile)
-                        with tracer.start("pipeline.cpu_drain",
-                                          parent=sspan,
-                                          reason="watchdog-wedge"):
-                            out = self._cpu_verify(
-                                tile.pubs, tile.msgs, tile.sigs)
+                with tracer.start("pipeline.settle.wait", parent=sspan):
+                    if self.watchdog is not None:
+                        out = self.watchdog.result(tile.future, total)
                     else:
-                        out = self._canary_check(tile, out, sspan)
+                        out = tile.future.result()
+                if out is None:  # wedged: drain tile to the CPU
+                    self._cancel(tile)
+                    with tracer.start("pipeline.cpu_drain", parent=sspan,
+                                      reason="watchdog-wedge"):
+                        out = self._cpu_verify(
+                            tile.pubs, tile.msgs, tile.sigs)
                 else:
-                    out = self._canary_check(tile, tile.future.result(),
-                                             sspan)
+                    out = self._canary_check(tile, out, sspan)
                 tile.out = np.asarray(out, dtype=bool)
             settle_tile(tile.metas, tile.out, tile.pubs, tile.msgs,
                         tile.sigs, self.r.cache)
@@ -636,7 +657,26 @@ class PipelinedBlocksync:
         Mirrors _sync_tile's contract: on a bad block the peer is
         banned and either the partially-advanced state returns (caller
         retries the remainder) or BlockValidationError raises when
-        nothing was applied this pass."""
+        nothing was applied this pass.
+
+        While it owns an in-process `LocalAsyncBackend`, the pass runs
+        under `_SWITCH_INTERVAL_S`: the dispatch thread gets the
+        interpreter lock when it asks, so a tile's verdicts are on the
+        host before `_settle` asks for them. The interval is the
+        process's; the former value is back however the pass ends. A
+        backend that is not in-process leaves it alone."""
+        former = sys.getswitchinterval()
+        if not self._own_backend or former <= _SWITCH_INTERVAL_S:
+            return self._run_tiles(state, target)
+        sys.setswitchinterval(_SWITCH_INTERVAL_S)
+        try:
+            return self._run_tiles(state, target)
+        finally:
+            # CPython keeps whole microseconds and truncates: half of one
+            # more brings back the very value that was read
+            sys.setswitchinterval(former + 5e-7)
+
+    def _run_tiles(self, state: State, target: int) -> State:
         r = self.r
         tracer = shared_tracer()
         inflight: "deque[_Tile]" = deque()

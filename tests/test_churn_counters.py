@@ -148,7 +148,8 @@ def test_batch_stats_count_chunks_lanes_and_attribution():
     out = e5._verify_batch_loop([pub] * 8, [msg] * 8, [sig] * 8, 3,
                                 dispatch, fallback)
     after = e5.batch_stats()
-    assert calls == ["rlc", "rlc", "per-lane", "rlc"]
+    # dispatch all, then read back: attribution comes after the last
+    assert calls == ["rlc", "rlc", "rlc", "per-lane"]
     assert list(out) == [True] * 4 + [False] + [True] * 3
     assert {k: after[k] - before[k] for k in after} == {
         "chunks": 3, "lanes": 8, "attributed_chunks": 1,
