@@ -285,7 +285,7 @@ def cmd_light(args) -> int:
             h.rpartition(":")[0] or "127.0.0.1",
             int(h.rpartition(":")[2])))
          for h in args.witnesses.split(",") if h],
-        LightStore(MemDB()))
+        LightStore(MemDB()), sequential=args.sequential)
     lhost, _, lport = args.laddr.rpartition(":")
     proxy = LightProxy(VerifyingClient(light, primary),
                        lhost or "127.0.0.1", int(lport or 0))
@@ -445,6 +445,10 @@ def build_parser() -> argparse.ArgumentParser:
     lt.add_argument("--trusted-hash", dest="trusted_hash", default="")
     lt.add_argument("--trust-period", dest="trust_period", type=int,
                     default=168 * 3600)
+    lt.add_argument("--sequential", action="store_true",
+                    help="verify every header between the trusted height "
+                         "and the target, not by bisection (reference "
+                         "light.SequentialVerification())")
     lt.set_defaults(fn=cmd_light)
     ac = sub.add_parser("abci-cli")
     ac.add_argument("abci_command")

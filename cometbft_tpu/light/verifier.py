@@ -17,10 +17,13 @@ from __future__ import annotations
 
 from ..types import validation
 from ..types.proto import Timestamp
+from . import planner
 from .types import LightBlock, LightBlockError
 
 # reference light/verifier.go defaultMaxClockDrift
 MAX_CLOCK_DRIFT_SECONDS = 10
+# SigCache attribution label of the light client's planned lanes
+CACHE_PATH = "light"
 
 
 class VerificationError(Exception):
@@ -64,11 +67,16 @@ def _validate_untrusted(chain_id: str, trusted: LightBlock,
         raise ErrInvalidHeader("untrusted header is from the future")
 
 
-def verify_adjacent(chain_id: str, trusted: LightBlock,
-                    untrusted: LightBlock, trusting_period_s: int,
-                    now: Timestamp,
-                    max_drift_s: int = MAX_CLOCK_DRIFT_SECONDS) -> None:
-    """reference light/verifier.go:91-143 VerifyAdjacent."""
+def check_adjacent(chain_id: str, trusted: LightBlock,
+                   untrusted: LightBlock, trusting_period_s: int,
+                   now: Timestamp,
+                   max_drift_s: int = MAX_CLOCK_DRIFT_SECONDS) -> None:
+    """The half of `verify_adjacent` that needs no signature: adjacency,
+    expiry of the trusted header, the untrusted header's structure and
+    times, and the binding of its set to the trusted header's
+    `next_validators_hash`. The sequential client runs it against a
+    header that is itself only planned (light/client.py): nothing here
+    makes `untrusted` trusted."""
     if untrusted.height != trusted.height + 1:
         raise ErrInvalidHeader("headers must be adjacent in height")
     if _expired(trusted, trusting_period_s, now):
@@ -78,6 +86,10 @@ def verify_adjacent(chain_id: str, trusted: LightBlock,
             trusted.header.next_validators_hash:
         raise ErrInvalidHeader(
             "untrusted validators_hash != trusted next_validators_hash")
+
+
+def verify_own_commit(chain_id: str, untrusted: LightBlock) -> None:
+    """+2/3 of the header's own set signed its commit."""
     try:
         validation.verify_commit_light(
             chain_id, untrusted.validator_set,
@@ -85,6 +97,39 @@ def verify_adjacent(chain_id: str, trusted: LightBlock,
             untrusted.height, untrusted.signed_header.commit)
     except validation.CommitVerificationError as e:
         raise ErrInvalidHeader(f"invalid commit: {e}") from e
+
+
+def plan_own_commit(chain_id: str, untrusted: LightBlock,
+                    cache) -> planner.PlannedCheck:
+    """`verify_own_commit` with the verification deferred: the lanes
+    the rule takes, none verified; raises what it raises for whatever
+    no signature decides."""
+    try:
+        return planner.plan_commit_light(
+            chain_id, untrusted.validator_set,
+            untrusted.signed_header.commit.block_id,
+            untrusted.height, untrusted.signed_header.commit, cache,
+            path=CACHE_PATH)
+    except validation.CommitVerificationError as e:
+        raise ErrInvalidHeader(f"invalid commit: {e}") from e
+
+
+def wrong_signature(lane: planner.Lane) -> ErrInvalidHeader:
+    """What `verify_own_commit` raises for a planned lane that failed."""
+    cause = validation.ErrWrongSignature(lane.sig_index, lane.sig)
+    err = ErrInvalidHeader(f"invalid commit: {cause}")
+    err.__cause__ = cause
+    return err
+
+
+def verify_adjacent(chain_id: str, trusted: LightBlock,
+                    untrusted: LightBlock, trusting_period_s: int,
+                    now: Timestamp,
+                    max_drift_s: int = MAX_CLOCK_DRIFT_SECONDS) -> None:
+    """reference light/verifier.go:91-143 VerifyAdjacent."""
+    check_adjacent(chain_id, trusted, untrusted, trusting_period_s, now,
+                   max_drift_s)
+    verify_own_commit(chain_id, untrusted)
 
 
 def verify_non_adjacent(chain_id: str, trusted: LightBlock,
@@ -107,13 +152,7 @@ def verify_non_adjacent(chain_id: str, trusted: LightBlock,
         raise ErrNewValSetCantBeTrusted(str(e)) from e
     except validation.CommitVerificationError as e:
         raise ErrInvalidHeader(f"trusting verify failed: {e}") from e
-    try:
-        validation.verify_commit_light(
-            chain_id, untrusted.validator_set,
-            untrusted.signed_header.commit.block_id,
-            untrusted.height, untrusted.signed_header.commit)
-    except validation.CommitVerificationError as e:
-        raise ErrInvalidHeader(f"invalid commit: {e}") from e
+    verify_own_commit(chain_id, untrusted)
 
 
 def verify(chain_id: str, trusted: LightBlock, untrusted: LightBlock,

@@ -9,6 +9,10 @@ client catching up to the tip BOTH ways:
   bisection  — skipping verification with the 1/3-trust rule (static
                valset: one jump).
 Reports headers/s for the sequential pass and total wall for each.
+Since PR 36 the sequential pass is measured by the benchmark's cell
+`light-seq-150.tip-catch-up` (`benchmark/drivers/light_catchup.py`, a
+skewed set, a chain no process has verified, judged against a plain
+reference); this script's numbers are nobody's record.
 
 --farm A/B (docs/FARM.md): N already-subscribed clients at staggered
 trusted heights all verify the tip —
