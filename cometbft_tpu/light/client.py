@@ -44,9 +44,12 @@ TILE_CHUNKS = 16
 # cache it fills: headers trusted, tiles settled, flushes through the
 # crypto.batch seam, and of the lanes the rule took those a flush
 # verified, those verified natively and (beside them) those the cache
-# answered. One thread's delta is exact.
+# answered; and of the headers trusted, those whose set took the hash
+# of the header before's (`ValidatorSet.adopt_hash_of`). One thread's
+# delta is exact.
 _tile_counts = {"headers": 0, "tiles": 0, "flushes": 0, "lanes": 0,
-                "device_lanes": 0, "native_lanes": 0, "cache_hits": 0}
+                "device_lanes": 0, "native_lanes": 0, "cache_hits": 0,
+                "set_hashes_reused": 0}
 _tile_lock = threading.Lock()
 
 
@@ -257,18 +260,25 @@ class LightClient:
 
     def _plan_header(self, cur: LightBlock, height: int,
                      target: LightBlock, now: Timestamp, cache):
-        """(light block, planned commit) of the next header: everything
-        `verify_adjacent` checks of it short of its signatures. The
-        plan is None for a commit whose lanes cannot be planned (an
-        aggregate seal is one pairing check, not lanes)."""
+        """(light block, planned commit, whether its set's hash was
+        reused) of the next header: everything `verify_adjacent` checks
+        of it short of its signatures. The plan is None for a commit
+        whose lanes cannot be planned (an aggregate seal is one pairing
+        check, not lanes). A set equal, member for member, to the header
+        before's takes that set's hash instead of its own merkle;
+        `validate_basic` still binds it to this header's
+        `validators_hash`."""
         nxt = (target if height == target.height
                else self.primary.light_block(height))
+        reused = (nxt.validator_set is not None and
+                  nxt.validator_set.adopt_hash_of(cur.validator_set))
         nxt.validate_basic(self.chain_id)
         verifier.check_adjacent(self.chain_id, cur, nxt,
                                 self.trusting_period, now)
         if isinstance(nxt.signed_header.commit, AggregatedCommit):
-            return nxt, None
-        return nxt, verifier.plan_own_commit(self.chain_id, nxt, cache)
+            return nxt, None, reused
+        return (nxt, verifier.plan_own_commit(self.chain_id, nxt, cache),
+                reused)
 
     def _settle_tile(self, tile, span) -> None:
         """Verify the planned lanes of a tile's headers in one flush,
@@ -276,7 +286,7 @@ class LightClient:
         not all verify true, and raise for that one what the walk one
         header at a time raises."""
         tracer = shared_tracer()
-        plans = [planned for _lb, planned in tile if planned is not None]
+        plans = [planned for _lb, planned, _r in tile if planned is not None]
         lanes = [lane for planned in plans for lane in planned.lanes]
         hits = sum(planned.cache_hits for planned in plans)
         oks, flushed = [], False
@@ -293,7 +303,7 @@ class LightClient:
         cache = shared_cache()
         failed, saved, at = None, 0, 0
         with tracer.start("light.save", parent=span):
-            for lb, planned in tile:
+            for lb, planned, _r in tile:
                 mine = planned.lanes if planned is not None else ()
                 for lane, ok in zip(mine, oks[at:at + len(mine)]):
                     # each lane on its own verdict, as everywhere
@@ -316,7 +326,9 @@ class LightClient:
                            ("lanes", len(lanes)),
                            ("device_lanes", device),
                            ("native_lanes", len(lanes) - device),
-                           ("cache_hits", hits)):
+                           ("cache_hits", hits),
+                           ("set_hashes_reused",
+                            sum(r for _lb, _p, r in tile[:saved]))):
                 _tile_counts[key] += n
         if failed is not None:
             raise verifier.wrong_signature(failed)

@@ -63,9 +63,13 @@ def _valset(members) -> ValidatorSet:
 class Chain:
     """Headers 1..n with their commits, every member signing; `sets[h]`
     the members in force at h (`change_at`: from that height on another
-    key holds rank 2 and the powers of ranks 3 and 4 are swapped)."""
+    key holds rank 2 and the powers of ranks 3 and 4 are swapped; from
+    `back_at` the first set again). `forged_at`: that header's
+    `validators_hash`, and the one before's `next_validators_hash`, name
+    no set at all, and both are signed as they stand."""
 
-    def __init__(self, seed: int, n: int, change_at: int = 0):
+    def __init__(self, seed: int, n: int, change_at: int = 0,
+                 back_at: int = 0, forged_at: int = 0):
         keys = _keys(seed, 8)
         first = _members(keys)
         after = list(first)
@@ -73,16 +77,18 @@ class Chain:
         after[2], after[3] = (first[2][0], first[3][1]), \
             (first[3][0], first[2][1])
         self.n = n
-        self.sets = {h: after if change_at and h >= change_at else first
-                     for h in range(1, n + 2)}
+        self.sets = {h: after if change_at <= h < (back_at or n + 2)
+                     and change_at else first for h in range(1, n + 2)}
+        forged = {forged_at: _digest(seed, "forged")} if forged_at else {}
         self.headers, self.commits = {}, {}
         last = BlockID()
         for h in range(1, n + 1):
             vals, nxt = _valset(self.sets[h]), _valset(self.sets[h + 1])
             header = Header(
                 chain_id=CHAIN, height=h, time=Timestamp(BASE + h, 0),
-                last_block_id=last, validators_hash=vals.hash(),
-                next_validators_hash=nxt.hash(),
+                last_block_id=last,
+                validators_hash=forged.get(h, vals.hash()),
+                next_validators_hash=forged.get(h + 1, nxt.hash()),
                 app_hash=_digest(seed, "app", h),
                 proposer_address=vals.validators[0].address)
             last = BlockID(header.hash(),
@@ -264,7 +270,10 @@ def test_sound_chain_equal_store_and_return(monkeypatch, chain, headers):
     assert delta == {"headers": headers, "tiles": len(flushes),
                      "flushes": len(flushes), "lanes": headers * TAKEN,
                      "device_lanes": headers * TAKEN, "native_lanes": 0,
-                     "cache_hits": 0}
+                     "cache_hits": 0,
+                     # all but the target, whose set was hashed when it
+                     # was fetched
+                     "set_hashes_reused": headers - 1}
 
 
 def test_without_a_lane_width_a_tile_is_one_header(monkeypatch, chain):
@@ -311,6 +320,9 @@ def test_an_altered_signature_the_rule_takes(monkeypatch, chain, height,
     assert outcome[0] == "ErrInvalidHeader" and outcome[2] == \
         "ErrWrongSignature" and f"(#{lane})" in outcome[1]
     assert len(rows) == height - 1 == 1 + delta["headers"]
+    # the tile's headers planned after it took a hash and were not
+    # trusted: a reuse counts with its header's trust
+    assert delta["set_hashes_reused"] == delta["headers"]
 
 
 def test_two_altered_signatures_name_the_first_in_header_order(monkeypatch,
@@ -363,6 +375,58 @@ def test_refused_without_a_signature(monkeypatch, chain, fault, kind,
     assert (outcome, rows) == want and outcome[0] == kind
     assert len(rows) == height - 1
     assert sum(flushes) == (height - 2) * TAKEN == delta["lanes"]
+
+
+def _one_power_off(lb):
+    """The honest header under a set whose one power differs."""
+    return LightBlock(lb.signed_header, ValidatorSet([
+        Validator(v.pub_key, v.voting_power + (i == TAKEN - 1))
+        for i, v in enumerate(lb.validator_set.validators)]))
+
+
+@pytest.mark.parametrize("forged", [True, False],
+                         ids=["forged-hash-equal-set", "one-power-off"])
+@pytest.mark.parametrize("height", [2, 2 + TILE // 2, 1 + TILE])
+def test_a_set_bound_to_no_header_is_refused_at_its_own(monkeypatch, chain,
+                                                        height, forged):
+    """A set equal, member for member, to the header before's, under a
+    header (and a header before it) that name another root: the hash is
+    adopted, and the binding still refuses it. A set one power off under
+    an honest header: no adoption, its own root refuses it. Both at that
+    header, before any of its lanes is flushed, as the walk a header at
+    a time refuses them; the refused header's reuse is not counted."""
+    if forged:
+        chain = Chain(seed=13, n=chain.n, forged_at=height)
+    provider = Provider(chain, {} if forged else {height: _one_power_off})
+    now = _now(chain)
+    want = reference(provider, chain.n, now)
+    adopted, adopt = [], ValidatorSet.adopt_hash_of
+    monkeypatch.setattr(ValidatorSet, "adopt_hash_of", lambda vs, other: (
+        adopted.append(adopt(vs, other)) or adopted[-1]))
+    outcome, rows, flushes, delta = tiled(monkeypatch, provider, chain.n,
+                                          now)
+    assert (outcome, rows) == want
+    assert outcome[0] == "LightBlockError" and \
+        "validators_hash" in outcome[1]
+    assert adopted[-1] is forged and all(adopted[:-1])
+    assert len(rows) == height - 1
+    assert sum(flushes) == (height - 2) * TAKEN == delta["lanes"]
+    assert delta["set_hashes_reused"] == height - 2 == delta["headers"]
+
+
+def test_a_hash_is_reused_wherever_the_set_did_not_change(monkeypatch):
+    """Two set changes: every header planned takes the hash of the one
+    before but the two whose set changed and the target, whose set was
+    hashed when it was fetched."""
+    chain = Chain(seed=17, n=2 * TILE, change_at=5, back_at=TILE + 3)
+    provider, now = Provider(chain), _now(chain)
+    want = reference(provider, chain.n, now)
+    outcome, rows, _f, delta = tiled(monkeypatch, provider, chain.n, now)
+    assert (outcome, rows) == want and outcome[0] == "ok"
+    changed = [h for h in range(2, chain.n + 1)
+               if chain.sets[h] != chain.sets[h - 1]]
+    assert changed == [5, TILE + 3]
+    assert delta["set_hashes_reused"] == chain.n - 2 - len(changed)
 
 
 def test_a_header_from_the_future_mid_tile(monkeypatch, chain):
