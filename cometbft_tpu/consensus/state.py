@@ -31,7 +31,8 @@ from ..types.block import Block, BlockID, Commit, Part, PartSet
 from ..types import validation
 from ..types.proto import Timestamp
 from ..types.vote import (Proposal, Vote, PREVOTE_TYPE, PRECOMMIT_TYPE)
-from ..trace import shared_tracer
+from ..libs import timesource
+from ..trace import NOOP_SPAN, shared_tracer
 from ..types.vote_set import (ErrVoteConflictingVotes, VoteError, VoteSet,
                               preverify_lanes)
 from .height_vote_set import HeightVoteSet
@@ -169,6 +170,9 @@ _intake_counts = {"votes_handled": 0, "runs": 0, "flushes": 0,
                   "device_lanes": 0, "native_lanes": 0, "cache_hits": 0}
 _intake_lock = threading.Lock()
 _NOTHING_HELD = object()
+# the process's tracer, held once: the per-message sites below read its
+# `enabled` flag before they build anything
+_TRACER = shared_tracer()
 
 
 def intake_stats() -> dict:
@@ -260,6 +264,11 @@ class ConsensusState:
         self._stop = threading.Event()
         self._replaying = False
         self._run_cap: Optional[int] = None     # lanes a run may hold
+        # the open span the consensus spans opened now nest under: the
+        # run's `consensus.intake` while `_intake` handles a run, its
+        # `consensus.finalize` inside that; None outside a run or with
+        # tracing off. Single-writer state, never a thread-local.
+        self._trace_parent = None
 
         # harness/reactor hooks
         self.broadcast: Callable[[Message], None] = lambda msg: None
@@ -366,8 +375,29 @@ class ConsensusState:
                     held = entry
                     break
                 run.append(entry)
-        with shared_tracer().start("consensus.intake", votes=len(run),
-                                   height=first[0].vote.height) as span:
+        height = first[0].vote.height
+        # the one read of the tracing flag a run: with it off nothing
+        # below opens a span, builds its attributes or reads a clock
+        if not _TRACER.enabled:
+            self._intake_run(run, NOOP_SPAN, height)
+            return held
+        with _TRACER.start("consensus.intake", votes=len(run),
+                           height=height) as span:
+            self._trace_parent = span
+            cpu0 = timesource.thread_time_ns()
+            try:
+                self._intake_run(run, span, height)
+            finally:
+                self._trace_parent = None
+                span.set_attr("cpu_ns", timesource.thread_time_ns() - cpu0)
+        return held
+
+    def _intake_run(self, run, span, height: int) -> None:
+        """`_intake`'s work on a run, under `span` (NOOP_SPAN untraced):
+        the lookups and the flush, the counters, then every message."""
+        flush = NOOP_SPAN if span is NOOP_SPAN else _TRACER.start(
+            "consensus.intake.flush", parent=span, height=height)
+        with flush:
             lanes = self._run_lanes(run)
             if len(lanes) < validation.BATCH_VERIFY_THRESHOLD:
                 # cannot reach the threshold: not even encoded
@@ -376,23 +406,25 @@ class ConsensusState:
                 hits, flushed, native = preverify_lanes([
                     (pub_key, vote.sign_bytes(self.chain_id),
                      vote.signature) for pub_key, vote in lanes])
-            span.set_attr("cache_hits", hits)
-            span.set_attr("device_lanes", flushed)
-            span.set_attr("native_lanes", native)
-            span.set_attr("flushed", int(flushed > 0))
-            with _intake_lock:
-                _intake_counts["runs"] += 1
-                _intake_counts["flushes"] += int(flushed > 0)
-                _intake_counts["cache_hits"] += hits
-                _intake_counts["device_lanes"] += flushed
-                _intake_counts["native_lanes"] += native
-            for entry in run:
-                try:
-                    self._handle_guarded(entry)
-                finally:
-                    with _intake_lock:
-                        _intake_counts["votes_handled"] += 1
-        return held
+            flush.set_attr("lanes", len(lanes))
+            flush.set_attr("cache_hits", hits)
+            flush.set_attr("flushed", int(flushed > 0))
+        span.set_attr("cache_hits", hits)
+        span.set_attr("device_lanes", flushed)
+        span.set_attr("native_lanes", native)
+        span.set_attr("flushed", int(flushed > 0))
+        with _intake_lock:
+            _intake_counts["runs"] += 1
+            _intake_counts["flushes"] += int(flushed > 0)
+            _intake_counts["cache_hits"] += hits
+            _intake_counts["device_lanes"] += flushed
+            _intake_counts["native_lanes"] += native
+        for entry in run:
+            try:
+                self._handle_guarded(entry)
+            finally:
+                with _intake_lock:
+                    _intake_counts["votes_handled"] += 1
 
     def _run_lanes(self, run) -> list:
         """(public key, vote) of the run's votes whose signature
@@ -488,21 +520,36 @@ class ConsensusState:
             return
         if isinstance(msg, ProposalMessage):
             if not self._replaying:
-                self.wal.write(WALProposal(msg.proposal, peer_id))
+                self._wal_write(WALProposal(msg.proposal, peer_id),
+                                msg.proposal.height)
         elif isinstance(msg, BlockPartMessage):
             if not self._replaying:
-                self.wal.write(WALBlockPart(
+                self._wal_write(WALBlockPart(
                     msg.height, msg.round, msg.part.index,
-                    msg.part.encode(), peer_id))
+                    msg.part.encode(), peer_id), msg.height)
         elif isinstance(msg, VoteMessage):
             if not self._replaying:
                 if peer_id == "":  # own vote: fsync (state.go:825)
-                    self.wal.write_sync(WALVote(msg.vote))
+                    self._wal_write(WALVote(msg.vote), msg.vote.height,
+                                    sync=True)
                 else:
-                    self.wal.write(WALVote(msg.vote, peer_id))
+                    self._wal_write(WALVote(msg.vote, peer_id),
+                                    msg.vote.height)
         else:
             raise TypeError(f"unknown consensus message {type(msg)}")
         self._dispatch(msg, peer_id)
+
+    def _wal_write(self, record, height: int, sync: bool = False) -> None:
+        """Append `record` to the WAL, flushed (and fsynced where `sync`).
+        With tracing on, inside a `consensus.wal` span (`height`, `sync`)
+        under `_trace_parent`: a root outside a run."""
+        write = self.wal.write_sync if sync else self.wal.write
+        if not _TRACER.enabled:
+            write(record)
+            return
+        with _TRACER.start("consensus.wal", parent=self._trace_parent,
+                           height=height, sync=int(sync)):
+            write(record)
 
     def _dispatch(self, msg, peer_id: str) -> None:
         """Route to a handler, parking future-(height,round) messages
@@ -548,8 +595,8 @@ class ConsensusState:
                 (ti.round == rs.round and ti.step < rs.step):
             return  # stale
         if not self._replaying:
-            self.wal.write(WALTimeout(ti.height, ti.round, ti.step,
-                                      ti.duration_ms))
+            self._wal_write(WALTimeout(ti.height, ti.round, ti.step,
+                                       ti.duration_ms), ti.height)
         if ti.step == STEP_NEW_HEIGHT:
             self._enter_new_round(ti.height, 0)
         elif ti.step == STEP_NEW_ROUND:
@@ -1022,8 +1069,17 @@ class ConsensusState:
         if rs.proposal_block is None or \
                 rs.proposal_block.hash() != bid.hash:
             return
-        with shared_tracer().start("consensus.finalize", height=height):
+        if not _TRACER.enabled:
             self._finalize_commit(height)
+            return
+        outer = self._trace_parent
+        with _TRACER.start("consensus.finalize", parent=outer,
+                           height=height) as span:
+            self._trace_parent = span   # the end-of-height record's parent
+            try:
+                self._finalize_commit(height)
+            finally:
+                self._trace_parent = outer
 
     def _finalize_commit(self, height: int) -> None:
         """reference state.go:1673-1770 finalizeCommit."""
@@ -1058,7 +1114,7 @@ class ConsensusState:
         # the WAL must know the height is decided before the app mutates
         # (reference state.go:1890 WriteSync EndHeightMessage)
         if not self._replaying:
-            self.wal.write_sync(EndHeightMessage(height))
+            self._wal_write(EndHeightMessage(height), height, sync=True)
         fail_point("finalize:post-endheight")        # state.go:1897
 
         # deliberately wall clock: measures REAL apply_block compute
@@ -1110,8 +1166,13 @@ class ConsensusState:
                 traceback.print_exc()
                 return
         try:
-            self.priv_validator.sign_vote(
-                self.chain_id, vote, sign_extension=extensions)
+            # the signature and the signer's state fsync; a root where
+            # no run is being handled (a prevote the proposal triggers)
+            with (_TRACER.start("privval.sign", parent=self._trace_parent,
+                                height=rs.height, type=type_)
+                  if _TRACER.enabled else NOOP_SPAN):
+                self.priv_validator.sign_vote(
+                    self.chain_id, vote, sign_extension=extensions)
         except DoubleSignError:
             return  # never sign conflicting votes; stay silent
         self.handle_msg(VoteMessage(vote))

@@ -16,8 +16,9 @@ makes two runs with the same seed produce byte-identical event logs
 The seam is deliberately tiny:
 
   install(now_ns_fn)  — now_ns_fn() -> int nanoseconds since the Unix
-                        epoch (virtual). monotonic() is derived from it,
-                        so one function drives both clock families.
+                        epoch (virtual). monotonic() and thread_time_ns()
+                        are derived from it, so one function drives every
+                        clock family.
   reset()             — back to wall clocks.
 
 Code holding a long-lived reference to `time.monotonic` (thread loops
@@ -55,6 +56,17 @@ def time_ns() -> int:
     if _virtual_now_ns is not None:
         return _virtual_now_ns()
     return _time.time_ns()
+
+
+def thread_time_ns() -> int:
+    """CPU nanoseconds the calling thread has run — a span's `cpu_ns`
+    (the span's wall time minus it is time the thread held the work but
+    did not run). Under a virtual source this is the virtual clock, like
+    time_ns(), so a traced simulation stays a pure function of its
+    seed."""
+    if _virtual_now_ns is not None:
+        return _virtual_now_ns()
+    return _time.thread_time_ns()
 
 
 def monotonic() -> float:

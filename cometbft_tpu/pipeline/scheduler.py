@@ -578,6 +578,17 @@ class PipelinedBlocksync:
             if cancel is not None:
                 cancel()
 
+    @classmethod
+    def _abandon(cls, tile: "_Tile") -> None:
+        """Throw away a speculated tile that will never settle (a ban, an
+        escape): its future cancelled, its `pipeline.tile` span ended
+        with `outcome` = abandoned, so that it reaches the ring."""
+        cls._cancel(tile)
+        if tile.span is not None:
+            tile.span.set_attr("outcome", "abandoned")
+            tile.span.end()
+            tile.span = None
+
     def _settle(self, tile: _Tile) -> None:
         """Resolve the tile's verdicts and map them onto
         entry.commit_ok. The tile was handed to the backend `depth - 1`
@@ -752,7 +763,7 @@ class PipelinedBlocksync:
                                 # abandoned dispatches so the device
                                 # client doesn't retain their answers
                                 for t in inflight:
-                                    self._cancel(t)
+                                    self._abandon(t)
                                 inflight.clear()
                                 if applied_any:
                                     return state
@@ -776,7 +787,7 @@ class PipelinedBlocksync:
             # dispatches — cancel so the device client drops the
             # answers instead of retaining them for nobody
             for t in inflight:
-                self._cancel(t)
+                self._abandon(t)
             inflight.clear()
             raise
         finally:

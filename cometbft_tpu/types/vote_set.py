@@ -19,10 +19,12 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 from ..libs.bits import BitArray
+from ..trace import shared_tracer
 from .block import BlockID, Commit, CommitSig
 from .vote import Vote, PRECOMMIT_TYPE
 
 MAX_VOTES_COUNT = 10000  # DoS bound, reference types/vote_set.go:14-17
+_TRACER = shared_tracer()
 
 
 class VoteError(Exception):
@@ -147,8 +149,16 @@ class VoteSet:
             if not cache.seen(pkb, sb, vote.signature, path="vote"):
                 # _precheck pinned addr == val.address, so Vote.verify's
                 # address check is redundant here — verify against the
-                # already-encoded sign bytes (one encode, not two)
-                if not val.pub_key.verify_signature(sb, vote.signature):
+                # already-encoded sign bytes (one encode, not two). With
+                # tracing on, in a `vote.verify` span: a root, which the
+                # readers place by its `height`
+                if not _TRACER.enabled:
+                    ok = val.pub_key.verify_signature(sb, vote.signature)
+                else:
+                    with _TRACER.start("vote.verify", height=vote.height):
+                        ok = val.pub_key.verify_signature(sb,
+                                                          vote.signature)
+                if not ok:
                     raise ErrVoteInvalidSignature(
                         f"failed to verify vote from {addr.hex()}")
                 cache.add(pkb, sb, vote.signature)

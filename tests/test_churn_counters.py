@@ -122,6 +122,26 @@ def test_a_sync_that_gives_up_closes_its_ban_span():
     assert src.banned == [2] * 4
 
 
+def test_a_ban_ends_the_spans_of_the_tiles_it_abandons():
+    """The bad tile 1-4 is applied up to its bad height while 5-8 is in
+    flight behind it: the ban throws 5-8 away, and its `pipeline.tile`
+    span ends with `outcome` = abandoned; every tile span built ends."""
+    src = LocalChainSource(CHAIN, corrupt_heights={3: "sig"})
+    (reactor, state), spans = _traced(lambda: _sync(4, src))
+    assert state.last_block_height == 12 and reactor.stats.bans == 1
+    (ban,) = _named(spans, "pipeline.ban")
+    tiles = _named(spans, "pipeline.tile")
+    thrown = [t for t in tiles if t["attrs"].get("outcome") == "abandoned"]
+    assert [t["attrs"]["start"] for t in thrown] == [5]
+    assert len(thrown) == ban["attrs"]["tiles_cancelled"] == 1
+    assert len({t["sid"] for t in tiles}) == len(tiles)
+    # the tiles built (every one ends, settled or thrown away) are the
+    # tile spans, which allocate their ids in that order
+    starts = [t["attrs"]["start"] for t in sorted(tiles,
+                                                  key=lambda t: t["sid"])]
+    assert starts[:2] == [1, 5] and starts[-1] <= 12
+
+
 def test_tracing_off_opens_nothing():
     program_trace.disable()
     reactor, state = _sync(4)

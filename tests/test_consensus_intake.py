@@ -356,7 +356,7 @@ def test_tracing_off_opens_no_span_and_on_names_the_runs(chain, stub):
     assert [r["height"] for r in runs] == [1, 1, 1, 1]
     for r in runs:
         assert set(r) == {"height", "votes", "cache_hits", "device_lanes",
-                          "native_lanes", "flushed"}
+                          "native_lanes", "flushed", "cpu_ns"}
     (fin,) = [s for s in spans if s["name"] == "consensus.finalize"]
     assert fin["attrs"] == {"height": 1}
     # the node validated no block with a last commit: height 2's
