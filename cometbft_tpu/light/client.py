@@ -18,6 +18,7 @@ from ..crypto.keys import kernel_width
 from ..pipeline.cache import shared_cache
 from ..trace import shared_tracer
 from ..types.agg_commit import AggregatedCommit
+from ..types.block import SIG_TS_PREFIX
 from ..types.proto import Timestamp
 from ..types import validation
 from . import verifier
@@ -45,11 +46,14 @@ TILE_CHUNKS = 16
 # crypto.batch seam, and of the lanes the rule took those a flush
 # verified, those verified natively and (beside them) those the cache
 # answered; and of the headers trusted, those whose set took the hash
-# of the header before's (`ValidatorSet.adopt_hash_of`). One thread's
-# delta is exact.
+# of the header before's (`ValidatorSet.adopt_hash_of`); and of the
+# CommitSigs the saves encoded in a commit's one pass, those whose
+# timestamp's seconds field came from an earlier lane of that pass
+# (`types/block.SIG_TS_PREFIX`). One thread's delta is exact.
 _tile_counts = {"headers": 0, "tiles": 0, "flushes": 0, "lanes": 0,
                 "device_lanes": 0, "native_lanes": 0, "cache_hits": 0,
-                "set_hashes_reused": 0}
+                "set_hashes_reused": 0, "sig_encodings": 0,
+                "sig_ts_prefix_reused": 0}
 _tile_lock = threading.Lock()
 
 
@@ -302,7 +306,8 @@ class LightClient:
             vspan.set_attr("native_lanes", len(lanes) - device)
         cache = shared_cache()
         failed, saved, at = None, 0, 0
-        with tracer.start("light.save", parent=span):
+        encoded, ts_reused = SIG_TS_PREFIX
+        with tracer.start("light.save", parent=span) as sspan:
             for lb, planned, _r in tile:
                 mine = planned.lanes if planned is not None else ()
                 for lane, ok in zip(mine, oks[at:at + len(mine)]):
@@ -316,6 +321,10 @@ class LightClient:
                     break
                 self.store.save_light_block(lb)
                 saved += 1
+            encoded = SIG_TS_PREFIX[0] - encoded
+            ts_reused = SIG_TS_PREFIX[1] - ts_reused
+            sspan.set_attr("sig_encodings", encoded)
+            sspan.set_attr("sig_ts_prefix_reused", ts_reused)
         span.set_attr("first_height", tile[0][0].height)
         span.set_attr("headers", saved)
         span.set_attr("lanes", len(lanes))
@@ -328,7 +337,9 @@ class LightClient:
                            ("native_lanes", len(lanes) - device),
                            ("cache_hits", hits),
                            ("set_hashes_reused",
-                            sum(r for _lb, _p, r in tile[:saved]))):
+                            sum(r for _lb, _p, r in tile[:saved])),
+                           ("sig_encodings", encoded),
+                           ("sig_ts_prefix_reused", ts_reused)):
                 _tile_counts[key] += n
         if failed is not None:
             raise verifier.wrong_signature(failed)

@@ -102,8 +102,7 @@ class AggregatedCommit(Commit):
         last_commit_hash binds bitmap and aggregate signature exactly
         like it binds per-lane signatures."""
         return merkle.hash_from_byte_slices(
-            [cs.encode() for cs in self.signatures]
-            + [self._seal_encode()])
+            self._sig_wires() + [self._seal_encode()])
 
     def seal_digest(self, chain_id: str, valset_hash: bytes) -> bytes:
         """Digest keying the WHOLE aggregate verdict in the SigCache:

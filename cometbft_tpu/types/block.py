@@ -45,6 +45,69 @@ SIG_ENCODINGS = [0, 0]
 # catch-up pipeline's marshal span reads the deltas)
 SIGN_BYTES_TEMPLATES = [0, 0]
 
+# CommitSig encodings built in a commit's one pass (`Commit._sig_wires`)
+# [in all, of those whose timestamp's seconds field an earlier lane of
+# the same pass had built], one count a signature, as SIG_ENCODINGS is
+# kept (the light client's save span reads the deltas)
+SIG_TS_PREFIX = [0, 0]
+
+
+class _Frames(dict):
+    """Wire frames of one kind by key, built by `make` from `proto`'s
+    helpers at import for every key of the domain `CommitSig.validate_basic`
+    allows. A key outside it, a flag or length of a peer's lane that
+    validation will refuse, is built on each call and never kept: the
+    table never grows."""
+
+    __slots__ = ("make",)
+
+    def __init__(self, make, keys):
+        super().__init__((key, make(key)) for key in keys)
+        self.make = make
+
+    def __missing__(self, key):
+        return self.make(key)
+
+
+# the longest timestamp (two int64 varint fields) and the longest CommitSig
+# of a lane that validates (flag, 20-byte address, timestamp, signature)
+_MAX_TS_WIRE = 2 * (1 + 10)
+_MAX_SIG_WIRE = 2 + (2 + 20) + (2 + _MAX_TS_WIRE) + (2 + MAX_SIGNATURE_SIZE)
+
+# A CommitSig's bytes before its address, by (flag, address length): flag
+# (field 1), then field 2's tag and length, each left out where proto3
+# leaves it out (flag 0, an empty address)
+_SIG_HEADS = _Frames(
+    lambda key: proto.f_varint(1, key[0]) + (
+        proto.embed_header(2, key[1]) if key[1] else b""),
+    [(flag, size) for flag in (BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_COMMIT,
+                               BLOCK_ID_FLAG_NIL) for size in (0, 20)])
+# the tag and length of the timestamp (field 3), by its length: always
+# there, gogoproto non-nullable
+_TS_HEADS = _Frames(lambda size: proto.embed_header(3, size),
+                    range(_MAX_TS_WIRE + 1))
+# field 4's tag and length, by length: a CommitSig's signature (left out
+# where empty) and a Commit's CommitSig (never empty: it always holds a
+# timestamp) share them
+_FIELD4_HEADS = _Frames(
+    lambda size: proto.embed_header(4, size) if size else b"",
+    range(_MAX_SIG_WIRE + 1))
+_NANOS_TAG = proto.tag(2, 0)
+
+
+def _commit_sig_wire(cs: "CommitSig", seconds_field: bytes) -> bytes:
+    """The one encoding of a CommitSig (types.proto: flag=1,
+    validator_address=2, timestamp=3 nonnull, signature=4), given its
+    timestamp's seconds field as `proto.f_varint(1, seconds)` builds it:
+    the timestamp is that field and the nanos field (left out where 0, as
+    `Timestamp.encode` leaves it), and three frames go around the
+    address, the timestamp and the signature."""
+    addr, sig, nanos = cs.validator_address, cs.signature, cs.timestamp.nanos
+    ts = (seconds_field + _NANOS_TAG + proto.varint(nanos) if nanos
+          else seconds_field)
+    return b"".join((_SIG_HEADS[cs.block_id_flag, len(addr)], addr,
+                     _TS_HEADS[len(ts)], ts, _FIELD4_HEADS[len(sig)], sig))
+
 
 @dataclass(frozen=True)
 class PartSetHeader:
@@ -137,15 +200,13 @@ class CommitSig:
         never seeded from decoded bytes and does not travel through
         pickle/copy (`__reduce__`), so whoever holds the instance pays
         its first encoding."""
-        memo = self.__dict__.get("_wire_memo")
+        memo = getattr(self, "_wire_memo", None)
         if memo is not None:
             SIG_ENCODINGS[1] += 1
             return memo
         SIG_ENCODINGS[0] += 1
-        wire = (proto.f_varint(1, self.block_id_flag)
-                + proto.f_bytes(2, self.validator_address)
-                + proto.f_embed(3, self.timestamp.encode())
-                + proto.f_bytes(4, self.signature))
+        wire = _commit_sig_wire(self,
+                                proto.f_varint(1, self.timestamp.seconds))
         object.__setattr__(self, "_wire_memo", wire)
         return wire
 
@@ -228,8 +289,34 @@ class Commit:
 
     def hash(self) -> bytes:
         """merkle over CommitSig encodings (types/block.go:949-967)."""
-        return merkle.hash_from_byte_slices(
-            [cs.encode() for cs in self.signatures])
+        return merkle.hash_from_byte_slices(self._sig_wires())
+
+    def _sig_wires(self) -> List[bytes]:
+        """Every CommitSig's encoding, in order, in one pass: a memoised
+        one from its memo, the rest built by `_commit_sig_wire` and
+        memoised, each timestamp's seconds field (tag and varint) built
+        once for all the lanes of the pass whose seconds are equal. Nothing
+        is kept on the commit."""
+        wires, seconds_fields, built = [], {}, 0
+        for cs in self.signatures:
+            # getattr, not the instance's __dict__: asking for that builds
+            # a dict a fresh instance, which the collector then walks
+            wire = getattr(cs, "_wire_memo", None)
+            if wire is None:
+                seconds = cs.timestamp.seconds
+                field = seconds_fields.get(seconds)
+                if field is None:
+                    field = seconds_fields[seconds] = proto.f_varint(
+                        1, seconds)
+                wire = _commit_sig_wire(cs, field)
+                object.__setattr__(cs, "_wire_memo", wire)
+                built += 1
+            wires.append(wire)
+        SIG_ENCODINGS[0] += built
+        SIG_ENCODINGS[1] += len(wires) - built
+        SIG_TS_PREFIX[0] += built
+        SIG_TS_PREFIX[1] += built - len(seconds_fields)
+        return wires
 
     def median_time(self, val_set) -> Optional[Timestamp]:
         """Voting-power-weighted median of the commit timestamps — BFT
@@ -299,10 +386,13 @@ class Commit:
     def encode(self) -> bytes:
         """proto Commit (types.proto: height=1, round=2, block_id=3 nonnull,
         signatures=4 repeated)."""
-        return b"".join(
-            [proto.f_varint(1, self.height), proto.f_varint(2, self.round),
-             proto.f_embed(3, self.block_id.encode())]
-            + [proto.f_embed(4, cs.encode()) for cs in self.signatures])
+        parts = [proto.f_varint(1, self.height),
+                 proto.f_varint(2, self.round),
+                 proto.f_embed(3, self.block_id.encode())]
+        for wire in self._sig_wires():
+            parts.append(_FIELD4_HEADS[len(wire)])
+            parts.append(wire)
+        return b"".join(parts)
 
     @classmethod
     def decode(cls, buf: bytes) -> "Commit":

@@ -149,16 +149,21 @@ def test_decode_does_not_keep_foreign_bytes(foreign_form):
     assert decoded.encode() == canonical
 
 
-@pytest.mark.parametrize("n", [0, 1, 5, 200])
-def test_commit_encode_and_hash_by_the_old_formulas(n):
-    commit = _commit(n)
+def _commit_by_hand(commit: Commit) -> tuple:
+    """(wire, hash) by the formulas `Commit` had before the one pass."""
     want = (proto.f_varint(1, commit.height)
             + proto.f_varint(2, commit.round)
             + proto.f_embed(3, commit.block_id.encode()))
     for cs in commit.signatures:
         want += proto.f_embed(4, _encode_by_hand(cs))
-    want_hash = merkle.hash_from_byte_slices(
+    return want, merkle.hash_from_byte_slices(
         [_encode_by_hand(cs) for cs in commit.signatures])
+
+
+@pytest.mark.parametrize("n", [0, 1, 5, 200])
+def test_commit_encode_and_hash_by_the_old_formulas(n):
+    commit = _commit(n)
+    want, want_hash = _commit_by_hand(commit)
     for _ in range(2):      # the first pass computes, the second reuses
         assert commit.encode() == want
         assert commit.hash() == want_hash
@@ -201,3 +206,139 @@ def test_the_counters_tell_computed_from_reused():
     commit.hash()
     commit.encode()
     assert block_mod.SIG_ENCODINGS == [computed + 6, reused + 12]
+
+
+# --- the one encoding rule, in a commit's one pass and alone ---------------
+
+GO_ZERO = Timestamp()
+TIMESTAMPS = {
+    # negative seconds: a ten-byte varint
+    "go-zero": GO_ZERO,
+    "seconds-0": Timestamp(0, 0),
+    "seconds-0-nanos-1": Timestamp(0, 1),
+    **{f"seconds-2^{k}{edge}": Timestamp(2 ** k + d, 5)
+       for k in (7, 14, 21, 28, 35) for edge, d in (("-1", -1), ("", 0))},
+    "nanos-0": Timestamp(1_700_000_000, 0),
+    "nanos-1": Timestamp(1_700_000_000, 1),
+    "nanos-max": Timestamp(1_700_000_000, 999_999_999),
+}
+
+
+def _fresh(sigs) -> list:
+    """The same CommitSigs as new instances: no memo travels."""
+    return [copy.copy(cs) for cs in sigs]
+
+
+def _assert_encodes_as_by_hand(sigs) -> None:
+    """Alone, and in a commit's one pass (encode and hash): the bytes of
+    `_encode_by_hand` and `_commit_by_hand`, and the decoded round trip."""
+    for cs in _fresh(sigs):
+        assert cs.encode() == _encode_by_hand(cs)
+        assert CommitSig.decode(cs.encode()) == cs
+    for first in ("encode", "hash"):
+        commit = Commit(height=9, round=1, block_id=BID,
+                        signatures=_fresh(sigs))
+        want, want_hash = _commit_by_hand(commit)
+        if first == "hash":
+            assert commit.hash() == want_hash
+        assert commit.encode() == want and commit.hash() == want_hash
+        assert [cs.__dict__[MEMO] for cs in commit.signatures] == [
+            _encode_by_hand(cs) for cs in commit.signatures]
+        decoded = Commit.decode(want)
+        assert decoded == commit and decoded.encode() == want
+
+
+@pytest.mark.parametrize("sig_len", [0, 64, 96])
+@pytest.mark.parametrize("addr_len", [0, 20])
+@FLAGS
+def test_every_frame_encodes_as_by_hand(flag, addr_len, sig_len):
+    """Each (flag, address length) head and signature head, under every
+    timestamp of `TIMESTAMPS`, one lane each."""
+    _assert_encodes_as_by_hand([
+        CommitSig(flag, bytes([0x30 + i]) * addr_len, ts,
+                  bytes([0x60 + i]) * sig_len)
+        for i, ts in enumerate(TIMESTAMPS.values())])
+
+
+@pytest.mark.parametrize("ts", TIMESTAMPS.values(), ids=TIMESTAMPS.keys())
+def test_every_timestamp_encodes_as_by_hand(ts):
+    """A whole commit of lanes under one timestamp: the seconds field of
+    the first lane serves all the others."""
+    _assert_encodes_as_by_hand([
+        CommitSig(BLOCK_ID_FLAG_COMMIT, bytes([i + 1]) * 20, ts,
+                  bytes([0xA0 + i]) * 64) for i in range(4)])
+
+
+def _mixed() -> list:
+    """Every flag, address and signature length and timestamp of the
+    cases above in one commit, seconds repeating and alternating."""
+    stamps = list(TIMESTAMPS.values())
+    sizes = [(a, s) for a in (0, 20) for s in (0, 64, 96)]
+    flags = [BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_NIL, 0]
+    sigs = []
+    for i in range(3 * len(stamps)):
+        # the timestamps in order, then alternating pairs, then repeats
+        ts = stamps[i if i < len(stamps) else
+                    (i % 2 if i < 2 * len(stamps) else i % 3)]
+        addr_len, sig_len = sizes[i % len(sizes)]
+        sigs.append(CommitSig(flags[i % len(flags)],
+                              bytes([i % 256]) * addr_len, ts,
+                              bytes([(7 * i) % 256]) * sig_len))
+    return sigs
+
+
+def test_a_commit_mixing_every_case():
+    _assert_encodes_as_by_hand(_mixed())
+
+
+@pytest.mark.parametrize("memoised", [(), (0, 3, 4), "all"],
+                         ids=["none-memoised", "some-memoised",
+                              "all-memoised"])
+def test_ts_prefix_counts_the_lanes_whose_seconds_repeat(memoised):
+    """Of the lanes a pass builds, those whose seconds an earlier lane of
+    the same pass built; a memoised lane is served, not built, and
+    SIG_ENCODINGS counts a signature as it always has."""
+    sigs = _mixed()
+    memoised = range(len(sigs)) if memoised == "all" else memoised
+    for i in memoised:
+        sigs[i].encode()
+    built = [cs for i, cs in enumerate(sigs) if i not in memoised]
+    distinct = len({cs.timestamp.seconds for cs in built})
+    enc, prefix = list(block_mod.SIG_ENCODINGS), list(block_mod.SIG_TS_PREFIX)
+    commit = Commit(height=9, round=1, block_id=BID, signatures=sigs)
+    commit.encode()
+    assert block_mod.SIG_TS_PREFIX == [prefix[0] + len(built),
+                                       prefix[1] + len(built) - distinct]
+    assert block_mod.SIG_ENCODINGS == [enc[0] + len(built),
+                                       enc[1] + len(sigs) - len(built)]
+    commit.hash()       # every lane from its memo now: nothing built
+    assert block_mod.SIG_TS_PREFIX == [prefix[0] + len(built),
+                                       prefix[1] + len(built) - distinct]
+    assert block_mod.SIG_ENCODINGS[1] == enc[1] + 2 * len(sigs) - len(built)
+
+
+def test_foreign_flags_and_lengths_leave_the_frame_tables_as_they_are():
+    """A peer's commit whose lanes carry a thousand flags, address and
+    signature lengths that `validate_basic` refuses encodes by the
+    formulas, and no frame of them is kept."""
+    tables = (block_mod._SIG_HEADS, block_mod._TS_HEADS,
+              block_mod._FIELD4_HEADS)
+    before = [dict(table) for table in tables]
+    _assert_encodes_as_by_hand([
+        CommitSig(4 + i, bytes([i % 256]) * (21 + i), Timestamp(i, i),
+                  bytes([(3 * i) % 256]) * (97 + i)) for i in range(1000)])
+    assert [dict(table) for table in tables] == before
+
+
+def test_one_rule_alone_and_in_the_pass(monkeypatch):
+    """`CommitSig.encode` and the commit's pass build through the same
+    rule, once a lane built."""
+    calls = []
+    rule = block_mod._commit_sig_wire
+    monkeypatch.setattr(block_mod, "_commit_sig_wire",
+                        lambda cs, ts: calls.append(cs) or rule(cs, ts))
+    sigs = _mixed()
+    sigs[0].encode()
+    assert calls == [sigs[0]]
+    Commit(height=9, round=1, block_id=BID, signatures=sigs).encode()
+    assert calls == sigs

@@ -273,7 +273,10 @@ def test_sound_chain_equal_store_and_return(monkeypatch, chain, headers):
                      "cache_hits": 0,
                      # all but the target, whose set was hashed when it
                      # was fetched
-                     "set_hashes_reused": headers - 1}
+                     "set_hashes_reused": headers - 1,
+                     # the walk a header at a time encoded these commits
+                     # first: every lane is served from its memo
+                     "sig_encodings": 0, "sig_ts_prefix_reused": 0}
 
 
 def test_without_a_lane_width_a_tile_is_one_header(monkeypatch, chain):
@@ -512,6 +515,31 @@ def test_spans_of_a_tile(monkeypatch, chain):
         v = kids[1]["attrs"]
         assert v["lanes"] == v["device_lanes"] + v["native_lanes"] == \
             t["attrs"]["lanes"]
+
+
+def test_save_counts_the_commit_lanes_it_encodes(monkeypatch):
+    """A chain never encoded before, one second a commit: each header's
+    save builds all its 8 lanes in one pass, and all but the first take
+    the seconds field the first built. The tile's `light.save` span
+    carries the same counts."""
+    chain = Chain(seed=19, n=TILE + 3)
+    trace.enable(seed=5)
+    try:
+        outcome, rows, _f, delta = tiled(monkeypatch, Provider(chain),
+                                         chain.n, _now(chain))
+        spans = trace.shared_recorder().snapshot()
+    finally:
+        trace.disable()
+    lanes, saved = len(chain.sets[1]), chain.n - 1
+    assert outcome[0] == "ok" and len(rows) == chain.n
+    assert delta["headers"] == saved
+    assert delta["sig_encodings"] == lanes * saved
+    assert delta["sig_ts_prefix_reused"] == (lanes - 1) * saved
+    saves = [s["attrs"] for s in spans if s["name"] == "light.save"]
+    tiles = [s["attrs"] for s in spans if s["name"] == "light.tile"]
+    assert [(a["sig_encodings"], a["sig_ts_prefix_reused"]) for a in saves] \
+        == [(lanes * t["headers"], (lanes - 1) * t["headers"])
+            for t in tiles]
 
 
 def test_the_farm_plans_as_before_the_move(chain):
