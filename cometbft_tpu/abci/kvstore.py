@@ -353,3 +353,56 @@ class KVStoreApplication(BaseApplication):
         self._prev = None  # pre-restore snapshot no longer provable
         self._restore = None
         return "COMPLETE"
+
+
+def vote_extension_bytes(height: int, address: bytes, size: int) -> bytes:
+    """The `size` bytes a validator of `ExtendingKVStoreApplication`
+    extends its precommit of `height` with: a SHAKE-256 stream keyed by
+    the height and the validator's address."""
+    return hashlib.shake_256(b"vote-extension|%d|" % height
+                             + address).digest(size)
+
+
+class ExtendingKVStoreApplication(KVStoreApplication):
+    """The kvstore with vote extensions as the reference's e2e app makes
+    them (test/e2e/app `ExtendVote` / `VerifyVoteExtension`, sized by the
+    manifest's `vote_extension_size`): a precommit's extension is
+    `vote_extension_size` bytes derived from its height and validator
+    (`vote_extension_bytes`; the e2e app draws them at random, which no
+    judge could check), and VerifyVoteExtension accepts exactly those.
+    PrepareProposal checks every extension of the extended commit it is
+    handed the same way, as the e2e app's `verifyAndSum` does, and counts
+    them in `extensions_prepared`; the block's transactions stay the
+    kvstore's. `extension_checks` counts VerifyVoteExtension's verdicts
+    (`accepted`, `refused`). `validator_address` is the node's own, what
+    its ExtendVote derives from."""
+
+    def __init__(self, vote_extension_size: int,
+                 validator_address: bytes = b""):
+        super().__init__()
+        self.vote_extension_size = vote_extension_size
+        self.validator_address = validator_address
+        self.extensions_prepared = 0
+        self.extension_checks = {"accepted": 0, "refused": 0}
+
+    def _made_here(self, height: int, addr: bytes, ext: bytes) -> bool:
+        return ext == vote_extension_bytes(height, addr,
+                                           self.vote_extension_size)
+
+    def extend_vote(self, height, round_) -> bytes:
+        return vote_extension_bytes(height, self.validator_address,
+                                    self.vote_extension_size)
+
+    def verify_vote_extension(self, height, addr, ext) -> bool:
+        ok = self._made_here(height, addr, ext)
+        self.extension_checks["accepted" if ok else "refused"] += 1
+        return ok
+
+    def prepare_proposal(self, txs, max_tx_bytes, local_last_commit=None):
+        for _index, addr, ext in local_last_commit or ():
+            if not self._made_here(self.last_height, addr, ext):
+                raise ValueError(
+                    f"extended commit of height {self.last_height} holds "
+                    f"an extension of {addr.hex()} this app never made")
+            self.extensions_prepared += 1
+        return super().prepare_proposal(txs, max_tx_bytes)

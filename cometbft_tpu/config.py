@@ -66,6 +66,10 @@ class BaseConfig:
     # "host:port" = external ABCI app over the socket protocol
     # (reference config.go BaseConfig.ProxyApp)
     proxy_app: str = "kvstore"
+    # bytes the built-in kvstore extends each precommit with on a chain
+    # whose consensus params enable vote extensions, and checks in its
+    # peers' (the reference e2e manifest's vote_extension_size); 0 = none
+    vote_extension_size: int = 0
 
 
 @dataclass

@@ -70,15 +70,20 @@ class HeightVoteSet:
         vs = self._get(vote.round, vote.type_)
         return vs.add_vote(vote)
 
-    def lane_validator(self, vote: Vote):
-        """`VoteSet.lane_validator` of the set `add_vote` would route
-        this vote to, without `add_vote`'s effects: a catch-up round
-        (beyond round + 1) is charged to no peer and gets no set made
-        for it here, so its votes are None, left to `add_vote`."""
+    def lane_set(self, vote: Vote) -> Optional[VoteSet]:
+        """The set `add_vote` would route this vote to, without
+        `add_vote`'s effects: a catch-up round (beyond round + 1) is
+        charged to no peer and gets no set made for it here, so its votes
+        have None, left to `add_vote`."""
         if vote.type_ not in (PREVOTE_TYPE, PRECOMMIT_TYPE) or \
                 not 0 <= vote.round <= self.round + 1:
             return None
-        return self._get(vote.round, vote.type_).lane_validator(vote)
+        return self._get(vote.round, vote.type_)
+
+    def lane_validator(self, vote: Vote):
+        """`VoteSet.lane_validator` of the set `lane_set` names."""
+        vs = self.lane_set(vote)
+        return None if vs is None else vs.lane_validator(vote)
 
     def pol_info(self) -> Tuple[Optional[BlockID], int]:
         """Highest round with a prevote 2/3 majority (reference

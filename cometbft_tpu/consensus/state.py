@@ -34,7 +34,7 @@ from ..types.vote import (Proposal, Vote, PREVOTE_TYPE, PRECOMMIT_TYPE)
 from ..libs import timesource
 from ..trace import NOOP_SPAN, shared_tracer
 from ..types.vote_set import (ErrVoteConflictingVotes, VoteError, VoteSet,
-                              preverify_lanes)
+                              preverify_lanes, verify_cached)
 from .height_vote_set import HeightVoteSet
 from .ticker import TimeoutInfo, TimeoutTicker
 from .wal import (EndHeightMessage, NilWAL, WALBlockPart, WALProposal,
@@ -164,10 +164,14 @@ _violation_lock = threading.Lock()
 # The batched vote intake's counters (`_intake`), process-wide like the
 # verified-signature cache it fills: peer votes the receive routine
 # handled, the runs it drained them in, the flushes through the
-# crypto.batch seam, and of the lanes it was to verify those the cache
-# answered, those a flush verified and those left to the per-vote check.
+# crypto.batch seam, and of the lanes it was to verify (a vote's own and,
+# with vote extensions, a non-nil precommit's extension's) those the
+# cache answered, those a flush verified and those left to the per-vote
+# check; the `ext_` three count the extension lanes among them.
 _intake_counts = {"votes_handled": 0, "runs": 0, "flushes": 0,
-                  "device_lanes": 0, "native_lanes": 0, "cache_hits": 0}
+                  "device_lanes": 0, "native_lanes": 0, "cache_hits": 0,
+                  "ext_device_lanes": 0, "ext_native_lanes": 0,
+                  "ext_cache_hits": 0}
 _intake_lock = threading.Lock()
 _NOTHING_HELD = object()
 # the process's tracer, held once: the per-message sites below read its
@@ -398,15 +402,19 @@ class ConsensusState:
         flush = NOOP_SPAN if span is NOOP_SPAN else _TRACER.start(
             "consensus.intake.flush", parent=span, height=height)
         with flush:
-            lanes = self._run_lanes(run)
-            if len(lanes) < validation.BATCH_VERIFY_THRESHOLD:
+            voters = self._run_lanes(run)
+            n_ext = sum(vs.signs_extension(vote) for vs, _val, vote in voters)
+            if len(voters) + n_ext < validation.BATCH_VERIFY_THRESHOLD:
                 # cannot reach the threshold: not even encoded
-                hits, flushed, native = 0, 0, len(lanes)
+                counts = {"vote": (0, 0, len(voters)), "ext": (0, 0, n_ext)}
             else:
-                hits, flushed, native = preverify_lanes([
-                    (pub_key, vote.sign_bytes(self.chain_id),
-                     vote.signature) for pub_key, vote in lanes])
-            flush.set_attr("lanes", len(lanes))
+                counts = preverify_lanes([
+                    lane for vs, val, vote in voters
+                    for lane in vs.lanes(vote, val)])
+            hits, flushed, native = (sum(c[k] for c in counts.values())
+                                     for k in range(3))
+            ext = counts.get("ext", (0, 0, 0))
+            flush.set_attr("lanes", len(voters) + n_ext)
             flush.set_attr("cache_hits", hits)
             flush.set_attr("flushed", int(flushed > 0))
         span.set_attr("cache_hits", hits)
@@ -419,6 +427,9 @@ class ConsensusState:
             _intake_counts["cache_hits"] += hits
             _intake_counts["device_lanes"] += flushed
             _intake_counts["native_lanes"] += native
+            _intake_counts["ext_cache_hits"] += ext[0]
+            _intake_counts["ext_device_lanes"] += ext[1]
+            _intake_counts["ext_native_lanes"] += ext[2]
         for entry in run:
             try:
                 self._handle_guarded(entry)
@@ -427,28 +438,29 @@ class ConsensusState:
                     _intake_counts["votes_handled"] += 1
 
     def _run_lanes(self, run) -> list:
-        """(public key, vote) of the run's votes whose signature
-        `add_vote` would look up in the cache, as the round state stands
-        now: the set `_add_vote` routes each vote to, the validator
-        `_precheck` finds. A vote for another height, a late precommit
-        outside STEP_NEW_HEIGHT, a catch-up round, an exact duplicate, a
-        set with vote extensions: no lane, the per-vote path deals with
-        it as ever."""
-        rs, lanes = self.rs, []
+        """(vote set, validator, vote) of the run's votes whose
+        signatures `add_vote` would look up in the cache, as the round
+        state stands now: the set `_add_vote` routes each vote to, the
+        validator `_precheck` finds; `VoteSet.lanes` gives a vote's lanes
+        (two for a non-nil precommit with vote extensions). A vote for
+        another height, a late precommit outside STEP_NEW_HEIGHT, a
+        catch-up round, an exact duplicate: no lane, the per-vote path
+        deals with it as ever."""
+        rs, voters = self.rs, []
         for msg, _peer_id in run:
             vote = msg.vote
             if vote.height == rs.height:
-                val = rs.votes.lane_validator(vote)
+                vs = rs.votes.lane_set(vote)
             elif vote.height + 1 == rs.height and \
                     vote.type_ == PRECOMMIT_TYPE and \
-                    rs.step == STEP_NEW_HEIGHT and \
-                    rs.last_commit is not None:
-                val = rs.last_commit.lane_validator(vote)
+                    rs.step == STEP_NEW_HEIGHT:
+                vs = rs.last_commit
             else:
-                val = None
+                vs = None
+            val = None if vs is None else vs.lane_validator(vote)
             if val is not None:
-                lanes.append((val.pub_key, vote))
-        return lanes
+                voters.append((vs, val, vote))
+        return voters
 
     def send(self, msg: Message, peer_id: str = "") -> None:
         """Enqueue a message from a peer or self (thread-safe)."""
@@ -1233,33 +1245,22 @@ class ConsensusState:
             return
 
         # ABCI VerifyVoteExtension on peer precommits (reference
-        # state.go addVote → blockExec.VerifyVoteExtension). Order
-        # matters: authenticate the extension signature against the
-        # validator's key FIRST (the main vote signature does not cover
-        # the extension — unauthenticated bytes must never reach the
-        # app or suppress a valid vote), and skip duplicates so gossip
-        # re-deliveries don't cost an app round-trip each.
+        # state.go addVote → blockExec.VerifyVoteExtension), skipping
+        # duplicates so gossip re-deliveries don't cost an app
+        # round-trip each
         if peer_id and vote.type_ == PRECOMMIT_TYPE and \
                 not vote.block_id.is_nil() and \
                 self.state.consensus_params.extensions_enabled(rs.height):
             existing = rs.votes.precommits(vote.round).get_by_index(
                 vote.validator_index)
             if existing is None:
-                _idx, val = self.state.validators.get_by_address(
-                    vote.validator_address)
-                if val is None or not vote.extension_signature or \
-                        not val.pub_key.verify_signature(
-                            vote.extension_sign_bytes(self.chain_id),
-                            vote.extension_signature):
-                    raise VoteError("bad vote extension signature")
-                try:
-                    ok = self.executor.app.verify_vote_extension(
-                        vote.height, vote.validator_address,
-                        vote.extension)
-                except Exception:  # noqa: BLE001
-                    ok = False
-                if not ok:
-                    raise VoteError("app rejected vote extension")
+                if not _TRACER.enabled:
+                    self._check_extension(vote, NOOP_SPAN)
+                else:
+                    with _TRACER.start("consensus.ext_check",
+                                       parent=self._trace_parent,
+                                       height=vote.height) as span:
+                        self._check_extension(vote, span)
 
         try:
             rs.votes.add_vote(vote, peer_id)
@@ -1281,6 +1282,36 @@ class ConsensusState:
             self._on_prevote_added(vote)
         else:
             self._on_precommit_added(vote)
+
+    def _check_extension(self, vote: Vote, span) -> None:
+        """A peer precommit's extension, authenticated against the
+        validator's key FIRST (the vote's signature does not cover it:
+        unauthenticated bytes must never reach the app or suppress a
+        valid vote), then handed to the app; raises VoteError on either
+        refusal. The signature is looked up in the verified-signature
+        cache on path `ext`, where the run's flush put it, and verified
+        natively only on a miss (`verify_cached`), so `add_vote` finds it
+        there in turn. `span` (NOOP_SPAN untraced) gets `cache_hit` and,
+        where the app was asked, `app_ok`."""
+        _idx, val = self.state.validators.get_by_address(
+            vote.validator_address)
+        ok = val is not None and bool(vote.extension_signature)
+        if ok:
+            hit, ok = verify_cached(
+                val.pub_key, val.pub_key.bytes_(),
+                vote.extension_sign_bytes(self.chain_id),
+                vote.extension_signature, "ext", vote.height)
+            span.set_attr("cache_hit", int(hit))
+        if not ok:
+            raise VoteError("bad vote extension signature")
+        try:
+            ok = self.executor.app.verify_vote_extension(
+                vote.height, vote.validator_address, vote.extension)
+        except Exception:  # noqa: BLE001
+            ok = False
+        span.set_attr("app_ok", int(bool(ok)))
+        if not ok:
+            raise VoteError("app rejected vote extension")
 
     def _on_prevote_added(self, vote: Vote) -> None:
         rs = self.rs

@@ -159,8 +159,11 @@ def kernel_bucket() -> int:
     chunks and pads a narrower one up, so every chunk of every caller
     is the warmed executable, and a sub-tile or odd width never pays a
     compile of its own (minutes) or falls to the XLA kernel
-    (`ops/ed25519._rlc_dispatch`'s alignment check). Read through the
-    module at call time: the canary tests shrink the tile."""
+    (`ops/ed25519._rlc_dispatch`'s alignment check). Messages longer
+    than a vote's need a longer SHA-512 axis: the batch verifier sends
+    their lanes to the kernel only where that shape is warm
+    (`ops.ed25519.verify_batch_warm`). Read through the module at call
+    time: the canary tests shrink the tile."""
     from ..ops import pallas_verify
     return pallas_verify.TILE
 
@@ -223,9 +226,9 @@ class Ed25519BatchVerifier:
         if width == 0:
             out = verify_native(self._pubs, self._msgs, self._sigs)
         else:
-            from ..ops.ed25519 import verify_batch
-            out = verify_batch(self._pubs, self._msgs, self._sigs,
-                               batch_size=width)
+            from ..ops.ed25519 import verify_batch_warm
+            out = verify_batch_warm(self._pubs, self._msgs, self._sigs,
+                                    width)
         oks = [bool(v) for v in out]
         return all(oks), oks
 

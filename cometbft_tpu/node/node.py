@@ -158,6 +158,13 @@ class Node:
             from ..aggsig.aggregate import register_pops_batch
             register_pops_batch(self.genesis.bls_pops)
 
+        # --- privval (node.go:343; loaded before the app, whose vote
+        # extensions the validator's address keys) -------------------------
+        if priv_validator is None:
+            pv_path = config.path(config.base.priv_validator_file)
+            priv_validator = FilePV.load_or_generate(pv_path)
+        self.priv_validator = priv_validator
+
         # --- proxy app (node.go:319): in-process app, explicit client
         # creator, or [base] proxy_app = tcp://host:port (the socket
         # flavor — reference proxy.DefaultClientCreator) ----------------------
@@ -167,9 +174,8 @@ class Node:
             else:
                 target = config.base.proxy_app
                 if target == "kvstore":
-                    from ..abci.kvstore import KVStoreApplication
                     client_creator = local_client_creator(
-                        KVStoreApplication())
+                        self.builtin_app(config, priv_validator))
                 elif target.startswith("grpc://"):
                     from ..proxy.multi_app_conn import (
                         remote_grpc_client_creator)
@@ -199,12 +205,6 @@ class Node:
             self.block_indexer = BlockIndexer(self._indexer_db)
         self.indexer_service = IndexerService(
             self.tx_indexer, self.block_indexer, self.event_bus)
-
-        # --- privval (node.go:343) -------------------------------------------
-        if priv_validator is None:
-            pv_path = config.path(config.base.priv_validator_file)
-            priv_validator = FilePV.load_or_generate(pv_path)
-        self.priv_validator = priv_validator
 
         # --- mempool + evidence (node.go:385-409) ----------------------------
         mc = config.mempool
@@ -531,15 +531,74 @@ class Node:
             return kernel_bucket() if shared_client() is not None else 0
         return kernel_width()
 
+    @staticmethod
+    def builtin_app(config: Config, priv_validator=None) -> Application:
+        """`[base] proxy_app = "kvstore"`: the kvstore, or with `[base]
+        vote_extension_size` above 0 the kvstore that extends its
+        validator's precommits by that many bytes and verifies its
+        peers' (`ExtendingKVStoreApplication`, the reference e2e app's
+        `vote_extension_size`)."""
+        from ..abci.kvstore import (ExtendingKVStoreApplication,
+                                    KVStoreApplication)
+        size = config.base.vote_extension_size
+        if size <= 0:
+            return KVStoreApplication()
+        address = (priv_validator.get_pub_key().address()
+                   if priv_validator is not None else b"")
+        return ExtendingKVStoreApplication(size, address)
+
+    @classmethod
+    def boot_kernels(cls, vote_extension_size: int = 0) -> dict:
+        """What a node does at boot before its first verification: the
+        compile cache on, and the kernels of its bucket warm for every
+        shape `_warm_shapes` names. Returns the bucket (0 = the native
+        path, nothing warmed) and the seconds the warm took."""
+        import time
+        from ..libs.jax_cache import enable_compile_cache
+        enable_compile_cache()
+        batch = cls._device_batch_size()
+        prewarm_s = 0.0
+        if batch > 0:
+            # the compile's real seconds, whatever clock a simulation
+            # installs
+            t0 = time.perf_counter()  # staticcheck: allow(wallclock)
+            cls._warm_shapes(batch, vote_extension_size)
+            t1 = time.perf_counter()  # staticcheck: allow(wallclock)
+            prewarm_s = t1 - t0
+        return {"batch": batch, "prewarm_s": prewarm_s}
+
+    @staticmethod
+    def _warm_shapes(batch: int, vote_extension_size: int) -> None:
+        """Warm the kernels of every SHA-512 bucket
+        (`ops.ed25519.hash_block_bucket`) the node's flushes can dispatch:
+        a vote's or a commit's sign-bytes, and on a chain with vote
+        extensions of `vote_extension_size` bytes the sign-bytes of such
+        an extension at the shortest and the longest chain id and round
+        (`types.vote.extension_sign_bytes_span`)."""
+        from ..ops.ed25519 import (VOTE_MSG_CAP, hash_block_bucket,
+                                   prewarm_verify_kernels)
+        from ..types.vote import extension_sign_bytes_span
+        caps = [VOTE_MSG_CAP]
+        if vote_extension_size > 0:
+            caps += extension_sign_bytes_span(vote_extension_size)
+        warmed = set()
+        for cap in caps:
+            if hash_block_bucket(cap) not in warmed:
+                warmed.add(hash_block_bucket(cap))
+                prewarm_verify_kernels(batch_size=batch, msg_cap=cap)
+
     def _prewarm_kernels(self) -> None:
         """Compile the node bucket's kernels (and run the miscompile
         canary) BEFORE the first tile is dispatched: the pipeline
         watchdog arms a deadline per dispatch, and a first dispatch
         that is still compiling would trip it — sticky — and drain a
-        healthy chip's every later tile to native CPU verify. A
-        failure here is a broken device path and propagates."""
-        from ..ops.ed25519 import prewarm_verify_kernels
-        prewarm_verify_kernels(batch_size=self._device_batch_size())
+        healthy chip's every later tile to native CPU verify. A chain
+        with vote extensions warms their shapes too (`_warm_shapes`).
+        The one warm of the program (`boot_kernels`). A failure here is
+        a broken device path and propagates."""
+        params = self.consensus.state.consensus_params
+        self.boot_kernels(self.config.base.vote_extension_size
+                          if params.vote_extensions_enable_height > 0 else 0)
 
     def _run_statesync(self):
         """Snapshot-sync a fresh node (reference node.go:591-601

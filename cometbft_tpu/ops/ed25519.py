@@ -381,13 +381,59 @@ def verify_batch(pubs: Sequence[bytes], msgs: Sequence[bytes],
 _MAX_UNREAD_CHUNKS = 16
 
 
+def hash_blocks_needed(msg_len: int) -> int:
+    """SHA-512 blocks of H(R || A || M) for a message of `msg_len`
+    bytes: 64 bytes of R and A, the message, 0x80 and a 16-byte length."""
+    return (64 + msg_len + 17 + 127) // 128
+
+
+def hash_block_bucket(msg_len: int) -> int:
+    """The block axis of a chunk whose longest message has `msg_len`
+    bytes: 2 up to 175 bytes (every vote's and commit's sign-bytes, the
+    shape every node warms), above that the blocks the message needs
+    rounded up to four significant bits, so that a lane computes at most
+    an eighth more blocks than it needs and a few lengths share one
+    compiled program (a 2 KiB vote extension's ~2,090 sign-bytes: 17
+    blocks needed, 18 computed)."""
+    need = hash_blocks_needed(msg_len)
+    if need <= 2:
+        return 2
+    step = 1 << max(0, need.bit_length() - 4)
+    return -(-need // step) * step
+
+
+def msg_cap_of(n_blocks: int) -> int:
+    """The longest message that `n_blocks` SHA-512 blocks hold."""
+    return n_blocks * 128 - 64 - 17
+
+
+def _plan_chunks(msgs, batch_size: int) -> list:
+    """(first lane, end lane, hash blocks, the blocks its lanes' messages
+    need) of each chunk of a call: cut in order into `batch_size` chunks,
+    each at the block axis `hash_block_bucket` gives its longest message.
+    A chunk that mixes lengths (a flush's vote and vote-extension lanes)
+    runs every lane at the longest's axis: at a chunk's fixed lane count
+    the kernel's time goes by the axis, so one mixed chunk costs what the
+    long lanes alone would, where two chunks cut by length cost both."""
+    plan = []
+    for lo in range(0, len(msgs), batch_size):
+        lens = list(map(len, msgs[lo:lo + batch_size]))
+        shortest, longest = min(lens), max(lens)
+        need = hash_blocks_needed(shortest)
+        real = (need * len(lens) if need == hash_blocks_needed(longest)
+                else sum(map(hash_blocks_needed, lens)))
+        plan.append((lo, lo + len(lens), hash_block_bucket(longest), real))
+    return plan
+
+
 def _verify_batch_loop(pubs, msgs, sigs, batch_size, dispatch, fallback
                        ) -> np.ndarray:
     """The shared host-side chunking protocol behind every batch-verify
     entry point (single-device `verify_batch` here; the mesh-sharded
     `parallel.verify.verify_batch_mesh`): pad each chunk to the fixed
-    `batch_size` bucket with power-of-two message capacity, try ONE RLC
-    equation per chunk via `dispatch(pub, sig, hb, hn, z)`, and
+    `batch_size` bucket and its SHA-512 block axis to
+    `hash_block_bucket` of its longest message (`_plan_chunks`), try ONE
+    RLC equation per chunk via `dispatch(pub, sig, hb, hn, z)`, and
     attribute failed chunks (or serve strict mode, dispatch=None) via
     the per-lane `fallback(pub, sig, hb, hn)`.
 
@@ -406,41 +452,35 @@ def _verify_batch_loop(pubs, msgs, sigs, batch_size, dispatch, fallback
     if batch_size is None:
         batch_size = 1 << (n - 1).bit_length()
     tracer = shared_tracer()
-    starts = range(0, n, batch_size)
+    chunks = _plan_chunks(msgs, batch_size)
     outs = []
-    for first in range(0, len(starts), _MAX_UNREAD_CHUNKS):
-        window = starts[first:first + _MAX_UNREAD_CHUNKS]
+    for first in range(0, len(chunks), _MAX_UNREAD_CHUNKS):
+        window = chunks[first:first + _MAX_UNREAD_CHUNKS]
         if dispatch is not None:
             z = make_rlc_coefficients(batch_size * len(window))
         unread = []
-        for i, lo in enumerate(window):
-            hi = min(lo + batch_size, n)
-            chunk_msgs = msgs[lo:hi]
-            max_msg_len = max((len(m) for m in chunk_msgs), default=0)
-            # bucket message capacity to limit kernel variants
-            cap = 64
-            while cap < max_msg_len:
-                cap *= 2
+        for i, (lo, hi, blocks, real_blocks) in enumerate(window):
             # tiles flush on the dispatch thread, single commits on the
             # caller's: there the host's share of a chunk can be read
             with tracer.start("ed25519.prepare", lanes=hi - lo,
                               batch_size=batch_size):
                 pub_a, sig_a, hb, hn, ok_mask = prepare_batch(
-                    pubs[lo:hi], chunk_msgs, sigs[lo:hi], batch_size, cap)
+                    pubs[lo:hi], msgs[lo:hi], sigs[lo:hi], batch_size,
+                    msg_cap_of(blocks))
             verdict = None
             if dispatch is not None:
                 verdict = dispatch(
                     pub_a, sig_a, hb, hn,
                     z[i * batch_size:(i + 1) * batch_size])
             unread.append((hi - lo, (pub_a, sig_a, hb, hn), ok_mask,
-                           verdict))
+                           verdict, real_blocks))
         # from the last chunk's dispatch to the last verdict read
         # (strict mode dispatched nothing: it has 0 chunks to read back)
         with tracer.start("ed25519.readback",
                           chunks=len(unread) if dispatch is not None else 0,
                           lanes=sum(u[0] for u in unread)) as rspan:
             attributed = 0
-            for lanes, arrays, ok_mask, verdict in unread:
+            for lanes, arrays, ok_mask, verdict, real_blocks in unread:
                 out = None
                 if verdict is not None:
                     batch_ok, struct_ok = verdict
@@ -452,6 +492,9 @@ def _verify_batch_loop(pubs, msgs, sigs, batch_size, dispatch, fallback
                 with _batch_lock:
                     _batch["chunks"] += 1
                     _batch["lanes"] += lanes
+                    _batch["hash_blocks_real"] += real_blocks
+                    _batch["hash_blocks_dispatched"] += (
+                        arrays[2].shape[0] * arrays[2].shape[1])
                     if failed:
                         _batch["attributed_chunks"] += 1
                         _batch["attributed_lanes"] += lanes
@@ -478,12 +521,21 @@ _pallas_broken = False
 _CANARY_INTERVAL = 16
 _canary = {"runs": 0, "trips": 0}
 _dispatches = 0
+# aligned dispatches by (lanes, hash blocks): the canary's cadence is
+# counted per shape, so every compiled program is checked from its first
+# dispatch on, whichever shapes a node interleaves
+_shape_dispatches: dict = {}
 # bucket-wide chunks and real lanes through `_verify_batch_loop`, and of
 # those the ones whose RLC equation failed and went to the per-lane
 # fallback for attribution (strict mode, which has no RLC pass, counts
-# under the first pair only)
+# under the first pair only); the SHA-512 blocks the real lanes' messages
+# need, and those the kernel computed: every lane of every chunk, padding
+# in, at its chunk's block axis (`hash_block_bucket`). Beside the loop,
+# the lanes `verify_batch_warm` verified natively for want of a warm shape
 _batch = {"chunks": 0, "lanes": 0,
-          "attributed_chunks": 0, "attributed_lanes": 0}
+          "attributed_chunks": 0, "attributed_lanes": 0,
+          "hash_blocks_real": 0, "hash_blocks_dispatched": 0,
+          "cold_shape_lanes": 0}
 _batch_lock = threading.Lock()
 
 
@@ -496,7 +548,12 @@ def canary_stats() -> dict:
 def batch_stats() -> dict:
     """Snapshot of the batch loop's counters: {"chunks", "lanes"} for
     everything it verified, {"attributed_chunks", "attributed_lanes"}
-    for the chunks a failed RLC equation sent to the per-lane kernel."""
+    for the chunks a failed RLC equation sent to the per-lane kernel,
+    {"hash_blocks_real", "hash_blocks_dispatched"}: Σ over the real lanes
+    of the SHA-512 blocks each message needs, and Σ over the chunks of
+    lanes × block axis (padding lanes compute their chunk's blocks too),
+    {"cold_shape_lanes"}: lanes `verify_batch_warm` sent to the native
+    check because no kernel of their shape was warm."""
     with _batch_lock:
         return dict(_batch)
 
@@ -523,15 +580,8 @@ def _canary_batch(batch_size: int, n_blocks: int):
     pubs = [pub] * batch_size
     msgs = [msg] * batch_size
     sigs = [sig] * (batch_size - 1) + [bad]
-    cap = max(n_blocks * 128 - 64 - 17, 1)  # msg cap giving >= n_blocks
-    pub_a, sig_a, hb, hn, _ = prepare_batch(pubs, msgs, sigs,
-                                            batch_size, cap)
-    if hb.shape[1] < n_blocks:  # pad the block axis to the bucket shape
-        pad = np.zeros((batch_size, n_blocks - hb.shape[1], 128),
-                       dtype=hb.dtype)
-        hb = np.concatenate([hb, pad], axis=1)
-    else:
-        hb = hb[:, :n_blocks]
+    pub_a, sig_a, hb, hn, _ = prepare_batch(pubs, msgs, sigs, batch_size,
+                                            msg_cap_of(n_blocks))
     z = make_rlc_coefficients(batch_size)
     return pub_a, sig_a, hb, hn, z
 
@@ -570,20 +620,78 @@ def _rlc_dispatch(pub_a, sig_a, hb, hn, z):
     from .pallas_verify import TILE
     aligned = pub_a.shape[0] % TILE == 0
     if use_pallas_rlc() and aligned and not _pallas_broken:
-        if _dispatches % _CANARY_INTERVAL == 0:
-            _run_canary(pub_a.shape[0], hb.shape[1])
+        shape = (pub_a.shape[0], hb.shape[1])
+        seen = _shape_dispatches.get(shape, 0)
+        if seen % _CANARY_INTERVAL == 0:
+            _run_canary(*shape)
+        _shape_dispatches[shape] = seen + 1
         _dispatches += 1
         if not _pallas_broken:
             return verify_rlc_kernel_pallas(pub_a, sig_a, hb, hn, z)
     return verify_rlc_kernel(pub_a, sig_a, hb, hn, z)
 
 
+# the message capacity of a vote's or a commit's sign-bytes (~107 bytes),
+# and its SHA-512 block axis: the shape every node warms at boot
+VOTE_MSG_CAP = 128
+VOTE_BLOCKS = 2
+
+
+def rlc_kernel_name(n_blocks: int) -> str:
+    """The compile ledger's name of the RLC kernel at `n_blocks` SHA-512
+    blocks (its bucket is the lane count): "ed25519-rlc" at the vote
+    shape, as ever, the block axis beside it at any other."""
+    if n_blocks == VOTE_BLOCKS:
+        return "ed25519-rlc"
+    return f"ed25519-rlc@{n_blocks}b"
+
+
+def shape_warm(batch_size: int, n_blocks: int) -> bool:
+    """Whether a chunk of `batch_size` lanes at `n_blocks` SHA-512
+    blocks dispatches without a compile: the vote shape, which every
+    device process warms before its first flush (`crypto.keys
+    .kernel_bucket`), or a shape `prewarm_verify_kernels` warmed in this
+    process (a chain's vote extensions, `Node._warm_shapes`)."""
+    from ..libs.jax_cache import ledger
+    return n_blocks == VOTE_BLOCKS or ledger().warm_in_process(
+        rlc_kernel_name(n_blocks), batch_size)
+
+
+def verify_batch_warm(pubs: Sequence[bytes], msgs: Sequence[bytes],
+                      sigs: Sequence[bytes], batch_size: int) -> np.ndarray:
+    """`verify_batch` for a live path, which never compiles: a lane
+    whose SHA-512 bucket (`hash_block_bucket`) is not `shape_warm` is
+    verified natively, the rest through the kernel. So a vote extension
+    of a length the node did not warm (an external app's, or one longer
+    than `[base] vote_extension_size`) costs a native check, not minutes
+    of compile on the consensus receive path."""
+    cold = {ln for ln in set(map(len, msgs))
+            if not shape_warm(batch_size, hash_block_bucket(ln))}
+    if not cold:
+        return verify_batch(pubs, msgs, sigs, batch_size=batch_size)
+    from ..crypto.keys import verify_native
+    is_cold = np.fromiter((len(m) in cold for m in msgs), dtype=bool,
+                          count=len(msgs))
+    out = np.zeros((len(msgs),), dtype=bool)
+    for mask, verify in ((is_cold, verify_native), (~is_cold, functools
+                         .partial(verify_batch, batch_size=batch_size))):
+        idx = np.flatnonzero(mask)
+        if idx.size:
+            out[idx] = verify([pubs[i] for i in idx], [msgs[i] for i in idx],
+                              [sigs[i] for i in idx])
+    with _batch_lock:
+        _batch["cold_shape_lanes"] += int(is_cold.sum())
+    return out
+
+
 def prewarm_verify_kernels(batch_size: int = 4096,
-                           msg_cap: int = 128) -> None:
-    """Compile the (batch, msg-cap) bucket's RLC fast path AND the
-    per-lane attribution fallback before live traffic, so neither cold
-    jit lands mid-blocksync (the device server does the same at start,
-    device/server.py:_warm; this is the in-process caller's analog).
+                           msg_cap: int = VOTE_MSG_CAP) -> None:
+    """Compile the RLC fast path AND the per-lane attribution fallback
+    of the (batch, `hash_block_bucket(msg_cap)`) shape, the one
+    `_verify_batch_loop` dispatches messages of up to `msg_cap` bytes
+    at, before live traffic, so neither cold jit lands mid-blocksync
+    (the device server does the same at start, device/server.py:_warm;
+    this is the in-process caller's analog).
 
     The tampered lane corrupts a LOW byte of s: the signature stays
     structurally valid, the RLC batch EQUATION fails, and the fallback
@@ -593,15 +701,17 @@ def prewarm_verify_kernels(batch_size: int = 4096,
     from ..libs.jax_cache import ledger
     pub, sig, msg = _dummy()
     bad = sig[:40] + bytes([sig[40] ^ 1]) + sig[41:]
+    n_blocks = hash_block_bucket(msg_cap)
+    msg_cap = msg_cap_of(n_blocks)
     pub_a, sig_a, hb, hn, _ = prepare_batch([pub], [msg], [sig],
                                             batch_size, msg_cap)
     z = make_rlc_coefficients(batch_size)
     # warm the kernel the live path will actually dispatch to (pallas
     # on a TPU backend, behind its miscompile canary). The
     # compile guard attributes the warm in the ledger AND marks the
-    # bucket process-warm, which mesh/executor's single-shard view
-    # reads as "no cold compile on a live flush".
-    with ledger().compile_guard("ed25519-rlc", batch_size):
+    # shape process-warm, which `shape_warm` and mesh/executor's
+    # single-shard view read as "no cold compile on a live flush".
+    with ledger().compile_guard(rlc_kernel_name(n_blocks), batch_size):
         _rlc_dispatch(pub_a, sig_a, hb, hn, z)
         pub_a, sig_a, hb, hn, _ = prepare_batch([pub], [msg], [bad],
                                                 batch_size, msg_cap)

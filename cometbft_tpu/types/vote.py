@@ -17,6 +17,7 @@ PRECOMMIT_TYPE = 2
 PROPOSAL_TYPE = 32
 
 MAX_VOTE_BYTES = 209  # types/vote.go MaxVoteBytes (with 64-byte signature)
+MAX_CHAIN_ID_LEN = 50  # reference types/genesis.go MaxChainIDLen
 
 
 def is_vote_type_valid(t: int) -> bool:
@@ -73,18 +74,6 @@ class Vote:
         return pub_key.verify_signature(self.sign_bytes(chain_id),
                                         self.signature)
 
-    def verify_vote_and_extension(self, chain_id: str,
-                                  pub_key: PubKey) -> bool:
-        """reference types/vote.go VerifyVoteAndExtension."""
-        if not self.verify(chain_id, pub_key):
-            return False
-        if self.type_ == PRECOMMIT_TYPE and not self.block_id.is_nil():
-            if not self.extension_signature:
-                return False
-            return pub_key.verify_signature(
-                self.extension_sign_bytes(chain_id), self.extension_signature)
-        return True
-
     def validate_basic(self) -> None:
         if not is_vote_type_valid(self.type_):
             raise ValueError(f"invalid vote type {self.type_}")
@@ -132,6 +121,17 @@ class Vote:
             signature=proto.field_bytes(f, 8, b""),
             extension=proto.field_bytes(f, 9, b""),
             extension_signature=proto.field_bytes(f, 10, b""))
+
+
+def extension_sign_bytes_span(extension_size: int) -> list:
+    """[shortest, longest] sign-bytes of an extension of
+    `extension_size` bytes over every height, round and chain id a chain
+    may have: a round is 9 bytes where it is not 0, a chain id 1 to
+    `MAX_CHAIN_ID_LEN` characters, a height always 9."""
+    ext = bytes(extension_size)
+    return [len(Vote(height=1, round=r, extension=ext)
+                .extension_sign_bytes(chain_id))
+            for r, chain_id in ((0, "c"), (1, "c" * MAX_CHAIN_ID_LEN))]
 
 
 @dataclass

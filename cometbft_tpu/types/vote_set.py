@@ -122,10 +122,15 @@ class VoteSet:
         return self._finish_add(vote, val)
 
     def _check_signature(self, vote: Vote, val) -> None:
-        """The per-vote hot path (types/vote.go:235); raises on failure.
-        A batched intake ahead of it (`preverify_lanes`) shows here as a
-        cache hit, and as nothing else."""
+        """The per-vote hot path (types/vote.go:235, and with vote
+        extensions `VerifyVoteAndExtension`); raises on failure. A
+        batched intake ahead of it (`preverify_lanes`) shows here as a
+        cache hit, and as nothing else: the vote's signature is looked up
+        on path `vote`, an extension's on path `ext`, and only what
+        misses is verified natively, once."""
         addr = vote.validator_address
+        pub_key = val.pub_key
+        pkb = pub_key.bytes_()
         if self.extensions_enabled:
             if vote.block_id.is_nil() and \
                     (vote.extension or vote.extension_signature):
@@ -133,55 +138,69 @@ class VoteSet:
                 # non-nil precommits — unsigned bytes on a nil vote
                 # would be stored and re-gossiped otherwise
                 raise VoteError("extension data on nil precommit")
-            if not vote.verify_vote_and_extension(self.chain_id,
-                                                  val.pub_key):
+            extended = self.signs_extension(vote)
+            ok = (not extended or bool(vote.extension_signature)) and \
+                verify_cached(pub_key, pkb, vote.sign_bytes(self.chain_id),
+                              vote.signature, "vote", vote.height)[1]
+            if ok and extended:
+                ok = verify_cached(
+                    pub_key, pkb, vote.extension_sign_bytes(self.chain_id),
+                    vote.extension_signature, "ext", vote.height)[1]
+            if not ok:
                 raise ErrVoteInvalidSignature(
                     f"failed to verify extended vote from {addr.hex()}")
-        else:
-            # re-gossiped votes hit the verified-signature cache instead
-            # of re-running the ~400µs verify (or burning a device lane);
-            # only verified-TRUE signatures are ever cached, so a hit
-            # can't flip a verdict
-            from ..pipeline.cache import shared_cache
-            cache = shared_cache()
-            pkb = val.pub_key.bytes_()
-            sb = vote.sign_bytes(self.chain_id)
-            if not cache.seen(pkb, sb, vote.signature, path="vote"):
-                # _precheck pinned addr == val.address, so Vote.verify's
-                # address check is redundant here — verify against the
-                # already-encoded sign bytes (one encode, not two). With
-                # tracing on, in a `vote.verify` span: a root, which the
-                # readers place by its `height`
-                if not _TRACER.enabled:
-                    ok = val.pub_key.verify_signature(sb, vote.signature)
-                else:
-                    with _TRACER.start("vote.verify", height=vote.height):
-                        ok = val.pub_key.verify_signature(sb,
-                                                          vote.signature)
-                if not ok:
-                    raise ErrVoteInvalidSignature(
-                        f"failed to verify vote from {addr.hex()}")
-                cache.add(pkb, sb, vote.signature)
-            if vote.extension or vote.extension_signature:
-                raise VoteError("unexpected vote extension data")
+            return
+        # re-gossiped votes hit the verified-signature cache instead of
+        # re-running the ~400µs verify (or burning a device lane); only
+        # verified-TRUE signatures are ever cached, so a hit can't flip a
+        # verdict. _precheck pinned addr == val.address, so Vote.verify's
+        # address check is redundant here
+        if not verify_cached(pub_key, pkb, vote.sign_bytes(self.chain_id),
+                             vote.signature, "vote", vote.height)[1]:
+            raise ErrVoteInvalidSignature(
+                f"failed to verify vote from {addr.hex()}")
+        if vote.extension or vote.extension_signature:
+            raise VoteError("unexpected vote extension data")
+
+    def signs_extension(self, vote: Vote) -> bool:
+        """Whether `add_vote` checks a second signature of this vote, over
+        its extension: a non-nil precommit in a set with vote extensions
+        (reference types/vote.go VerifyVoteAndExtension)."""
+        return (self.extensions_enabled and vote.type_ == PRECOMMIT_TYPE
+                and not vote.block_id.is_nil())
 
     def lane_validator(self, vote: Optional[Vote]):
         """The validator whose key `add_vote(vote)` would look this
-        vote's signature up under in the verified-signature cache, or
-        None where it never gets that far or the lookup is not all of
-        the check: a vote `_precheck` refuses, an exact duplicate, a set
-        with vote extensions (their second signature is not batched), a
-        vote carrying extension data it should not. What a batched
-        intake may verify ahead of `add_vote`, and nothing else."""
-        if self.extensions_enabled:
-            return None
+        vote's signatures up under in the verified-signature cache, or
+        None where it never gets that far or the lookups are not all of
+        the check: a vote `_precheck` refuses, an exact duplicate, a vote
+        carrying extension data it should not, a non-nil precommit of a
+        set with vote extensions that lacks its extension signature.
+        What a batched intake may verify ahead of `add_vote`, and nothing
+        else: the vote's signature, and its extension's where
+        `signs_extension`."""
         try:
             val = self._precheck(vote)
         except VoteError:
             return None
-        if val is None or vote.extension or vote.extension_signature:
+        if val is None:
+            return None
+        if self.signs_extension(vote):
+            return val if vote.extension_signature else None
+        if vote.extension or vote.extension_signature:
             return None
         return val
+
+    def lanes(self, vote: Vote, val) -> list:
+        """The (public key, sign-bytes, signature, cache path) lanes of a
+        vote `lane_validator` gave `val` for: its own, and its
+        extension's where `signs_extension`."""
+        out = [(val.pub_key, vote.sign_bytes(self.chain_id), vote.signature,
+                "vote")]
+        if self.signs_extension(vote):
+            out.append((val.pub_key, vote.extension_sign_bytes(self.chain_id),
+                        vote.extension_signature, "ext"))
+        return out
 
     def add_votes(self, votes: List[Vote]) -> List:
         """Batched ingest: the signatures of the whole list that the
@@ -199,9 +218,9 @@ class VoteSet:
         raised (conflicts carry both votes).
         """
         preverify_lanes([
-            (val.pub_key, v.sign_bytes(self.chain_id), v.signature)
-            for v in votes
-            if (val := self.lane_validator(v)) is not None])
+            lane for v in votes
+            if (val := self.lane_validator(v)) is not None
+            for lane in self.lanes(v, val)])
         out: List = []
         for v in votes:
             try:
@@ -423,13 +442,36 @@ class VoteSet:
                 f"maj23:{self.maj23 is not None}}}")
 
 
-def preverify_lanes(lanes) -> tuple:
+def verify_cached(pub_key, pkb: bytes, sign_bytes: bytes, sig: bytes,
+                  path: str, height: int) -> tuple:
+    """(hit, ok) of one signature: looked up in the verified-signature
+    cache on `path`; on a miss verified natively and added where true.
+    With tracing on the native check is a `vote.verify` span: a root,
+    which the readers place by its `height`; `path` says whose
+    signature it is (a vote's, `vote`, or its extension's, `ext`)."""
+    from ..pipeline.cache import shared_cache
+    cache = shared_cache()
+    if cache.seen(pkb, sign_bytes, sig, path=path):
+        return True, True
+    if not _TRACER.enabled:
+        ok = pub_key.verify_signature(sign_bytes, sig)
+    else:
+        with _TRACER.start("vote.verify", height=height, path=path):
+            ok = pub_key.verify_signature(sign_bytes, sig)
+    if ok:
+        cache.add(pkb, sign_bytes, sig)
+    return False, ok
+
+
+def preverify_lanes(lanes) -> dict:
     """The batched half of vote intake. `lanes` are (public key,
-    sign-bytes, signature) of votes about to go through `add_vote`, one
-    after the other. Each is looked up in the verified-signature cache;
-    where the lanes that MISS reach `BATCH_VERIFY_THRESHOLD` and are all
-    of one key type the seam batches, they are verified in ONE flush
-    through `crypto.batch` (the device, on a TPU) and those that
+    sign-bytes, signature, cache path) of votes about to go through
+    `add_vote`, one after the other (`VoteSet.lanes`: a vote's own on
+    path `vote`, and an extension's on path `ext`). Each is looked up in
+    the verified-signature cache on its path; where the lanes that MISS
+    reach `BATCH_VERIFY_THRESHOLD` and are all of one key type the seam
+    batches, they are verified in ONE flush through `crypto.batch` (the
+    device, on a TPU; lanes of both lengths together) and those that
     verified true are added to the cache, where `_check_signature` finds
     them. Below the threshold nothing is verified here: the native
     single check beats a dispatch (same rule as commit verification,
@@ -440,34 +482,40 @@ def preverify_lanes(lanes) -> tuple:
     failed, a lane a short verdict list left out, a lane never handed in:
     `add_vote` verifies it natively and raises what it raises.
 
-    Returns (cache hits, lanes flushed, lanes left to the native check).
-    """
+    Returns {path: [cache hits, lanes flushed, lanes left to the native
+    check]} for each path the lanes name."""
     from ..crypto import batch as crypto_batch
     from ..pipeline.cache import shared_cache
     from .validation import BATCH_VERIFY_THRESHOLD
+    counts = {path: [0, 0, 0] for *_lane, path in lanes}
     if len(lanes) < BATCH_VERIFY_THRESHOLD:
-        return 0, 0, len(lanes)     # cannot reach it: not even looked up
+        for *_lane, path in lanes:  # cannot reach it: not even looked up
+            counts[path][2] += 1
+        return counts
     cache = shared_cache()
-    hits, missing, asked = 0, [], set()
-    for pub_key, sign_bytes, sig in lanes:
+    missing, asked = [], set()
+    for pub_key, sign_bytes, sig, path in lanes:
         pkb = pub_key.bytes_()
         if (pkb, sign_bytes, sig) in asked:
             continue                # the same vote twice in one list
         asked.add((pkb, sign_bytes, sig))
-        if cache.seen(pkb, sign_bytes, sig, path="vote"):
-            hits += 1
+        if cache.seen(pkb, sign_bytes, sig, path=path):
+            counts[path][0] += 1
         else:
-            missing.append((pub_key, pkb, sign_bytes, sig))
-    if len(missing) < BATCH_VERIFY_THRESHOLD or \
-            len({lane[0].type_() for lane in missing}) != 1:
-        return hits, 0, len(missing)
-    bv, ok = crypto_batch.create_batch_verifier(missing[0][0])
+            missing.append((pub_key, pkb, sign_bytes, sig, path))
+    bv, ok = None, False
+    if len(missing) >= BATCH_VERIFY_THRESHOLD and \
+            len({lane[0].type_() for lane in missing}) == 1:
+        bv, ok = crypto_batch.create_batch_verifier(missing[0][0])
     if not ok:
-        return hits, 0, len(missing)
-    for pub_key, _pkb, sign_bytes, sig in missing:
+        for *_lane, path in missing:
+            counts[path][2] += 1
+        return counts
+    for pub_key, _pkb, sign_bytes, sig, path in missing:
         bv.add(pub_key, sign_bytes, sig)
+        counts[path][1] += 1
     _all_ok, lane_oks = bv.verify()
-    for (_pk, pkb, sign_bytes, sig), lane_ok in zip(missing, lane_oks):
+    for (_pk, pkb, sign_bytes, sig, _path), lane_ok in zip(missing, lane_oks):
         if lane_ok:
             cache.add(pkb, sign_bytes, sig)
-    return hits, len(missing), 0
+    return counts

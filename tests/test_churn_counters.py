@@ -171,9 +171,13 @@ def test_batch_stats_count_chunks_lanes_and_attribution():
     # dispatch all, then read back: attribution comes after the last
     assert calls == ["rlc", "rlc", "rlc", "per-lane"]
     assert list(out) == [True] * 4 + [False] + [True] * 3
+    # the dummy's 21-byte message needs one SHA-512 block; its chunks'
+    # block axis is the 2 of every vote-sized message, for each of a
+    # chunk's 3 lanes, padding in
     assert {k: after[k] - before[k] for k in after} == {
         "chunks": 3, "lanes": 8, "attributed_chunks": 1,
-        "attributed_lanes": 3}
+        "attributed_lanes": 3, "hash_blocks_real": 8,
+        "hash_blocks_dispatched": 18, "cold_shape_lanes": 0}
     # strict mode has no RLC pass: its chunks are no attribution
     e5._verify_batch_loop([pub] * 2, [msg] * 2, [sig] * 2, 3, None,
                           lambda *a: np.ones(3, dtype=bool))

@@ -91,6 +91,9 @@ def _chunk_by_chunk(pubs, msgs, sigs, kernels, strict=False):
                 out = struct_ok
         counted["chunks"] += 1
         counted["lanes"] += hi - lo
+        counted["hash_blocks_real"] += sum(
+            e5.hash_blocks_needed(len(m)) for m in msgs[lo:hi])
+        counted["hash_blocks_dispatched"] += BUCKET * arrays[2].shape[1]
         if not strict and out is None:
             counted["attributed_chunks"] += 1
             counted["attributed_lanes"] += hi - lo

@@ -34,6 +34,7 @@ def pallas_env(monkeypatch):
     monkeypatch.setattr(pv, "TILE", BATCH)
     monkeypatch.setattr(e5, "_pallas_broken", False)
     monkeypatch.setattr(e5, "_dispatches", 0)
+    monkeypatch.setattr(e5, "_shape_dispatches", {})
     monkeypatch.setattr(e5, "_canary", {"runs": 0, "trips": 0})
     yield
 
