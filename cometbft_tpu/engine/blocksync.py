@@ -60,11 +60,15 @@ class TileEntry:
 
 
 def marshal_commit(chain_id: str, e: TileEntry, pubs: List[bytes],
-                   msgs: List[bytes], sigs: List[bytes], cache=None):
+                   msgs: List[bytes], sigs: List[bytes], cache=None,
+                   keys: Optional[List[bytes]] = None):
     """Marshal one commit's non-absent signatures into the lane lists;
     returns (entry, rows, needed) with rows=None on structural
     rejection. Each row is (lane, power, counted); lane=-1 marks a
-    verified-signature-cache hit that occupies no device lane.
+    verified-signature-cache hit that occupies no device lane. The
+    commit's lanes are looked up in the cache in one batch, and each
+    miss's cache key lands in `keys` beside `pubs`/`msgs`/`sigs`, for
+    `settle_tile` to insert it with.
 
     Standalone (not a verifier method) because this IS the pipeline's
     host marshal stage: the scheduler runs it for tile N+1 while the
@@ -101,26 +105,41 @@ def marshal_commit(chain_id: str, e: TileEntry, pubs: List[bytes],
             return e, AggSeal("ok", None), needed
         except validation.CommitVerificationError:
             return e, AggSeal("fail", None), needed
-    rows = []
+    # (lane triple, power, counted) of each signature up to the first
+    # that fails its structural check, which rejects the commit: the
+    # lanes before it are still marshaled and looked up
+    lanes, meta, malformed = [], [], False
     for idx, cs in enumerate(commit.signatures):
         if cs.absent_():
             continue
         try:
             cs.validate_basic()
         except ValueError:
-            return e, None, 0
+            malformed = True
+            break
         val = vals.get_by_index(idx)
-        msg = commit.vote_sign_bytes(chain_id, idx)
-        pkb = val.pub_key.bytes_()
-        if cache is not None and cache.seen(pkb, msg, cs.signature,
-                                            path="blocksync"):
-            rows.append((-1, val.voting_power, cs.for_block()))
+        lanes.append((val.pub_key.bytes_(),
+                      commit.vote_sign_bytes(chain_id, idx), cs.signature))
+        meta.append((val.voting_power, cs.for_block()))
+    if cache is not None:
+        lane_keys, hits = cache.lookup(lanes, path="blocksync")
+    else:
+        lane_keys, hits = [None] * len(lanes), [False] * len(lanes)
+    if keys is None:
+        keys = []
+    rows = []
+    for (pkb, msg, sig), key, hit, (power, counted) in zip(
+            lanes, lane_keys, hits, meta):
+        if hit:
+            rows.append((-1, power, counted))
             continue
-        row = len(pubs)
+        rows.append((len(pubs), power, counted))
         pubs.append(pkb)
         msgs.append(msg)
-        sigs.append(cs.signature)
-        rows.append((row, val.voting_power, cs.for_block()))
+        sigs.append(sig)
+        keys.append(key)
+    if malformed:
+        return e, None, 0
     return e, rows, needed
 
 
@@ -150,12 +169,15 @@ def verify_lanes(pubs: Sequence[bytes], msgs: Sequence[bytes],
     return verify_batch(pubs, msgs, sigs, batch_size=batch_size)
 
 
-def settle_tile(metas, out, pubs, msgs, sigs, cache=None) -> None:
+def settle_tile(metas, out, pubs, msgs, sigs, cache=None,
+                keys: Optional[Sequence[bytes]] = None) -> None:
     """Map per-lane verdicts back to per-commit results with FULL
     verify_commit semantics (every included signature valid AND for-block
-    power > 2/3); newly verified-true lanes feed the cache. Aggregated
-    commits arrive as marshaled AggSeals and settle in ONE batched
-    pairing call (Miller loops + final exp) for the whole tile."""
+    power > 2/3); newly verified-true lanes feed the cache, in one
+    insert of the keys `marshal_commit` looked them up with (`keys`,
+    required with a cache). Aggregated commits arrive as marshaled
+    AggSeals and settle in ONE batched pairing call (Miller loops +
+    final exp) for the whole tile."""
     from ..aggsig.verify import AggSeal, settle_seals
     agg = [(e, rows) for e, rows, _n in metas
            if isinstance(rows, AggSeal)]
@@ -172,10 +194,11 @@ def settle_tile(metas, out, pubs, msgs, sigs, cache=None) -> None:
         all_valid = all(r < 0 or out[r] for r, _p, _c in rows)
         tallied = sum(p for r, p, counted in rows if counted)
         e.commit_ok = all_valid and tallied > needed
-        if cache is not None:
-            for r, _p, _c in rows:
-                if r >= 0 and out[r]:
-                    cache.add(pubs[r], msgs[r], sigs[r])
+    if cache is not None:
+        # each lane on its own verdict, in the tile's row order
+        cache.insert([keys[r] for _e, rows, _n in metas
+                      if isinstance(rows, list)
+                      for r, _p, _c in rows if r >= 0 and out[r]])
 
 
 class TiledCommitVerifier:
@@ -197,10 +220,11 @@ class TiledCommitVerifier:
         pubs: List[bytes] = []
         msgs: List[bytes] = []
         sigs: List[bytes] = []
+        keys: List[bytes] = []
         metas = [marshal_commit(self.chain_id, e, pubs, msgs, sigs,
-                                self.cache) for e in entries]
+                                self.cache, keys) for e in entries]
         out = verify_lanes(pubs, msgs, sigs, self.batch_size)
-        settle_tile(metas, out, pubs, msgs, sigs, self.cache)
+        settle_tile(metas, out, pubs, msgs, sigs, self.cache, keys)
 
 
 @dataclass

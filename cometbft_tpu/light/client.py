@@ -15,7 +15,7 @@ from typing import List, Optional
 
 from ..crypto import batch as crypto_batch
 from ..crypto.keys import kernel_width
-from ..pipeline.cache import shared_cache
+from ..pipeline.cache import insert_span_attrs, shared_cache
 from ..trace import shared_tracer
 from ..types.agg_commit import AggregatedCommit
 from ..types.block import SIG_TS_PREFIX
@@ -307,20 +307,22 @@ class LightClient:
         cache = shared_cache()
         failed, saved, at = None, 0, 0
         encoded, ts_reused = SIG_TS_PREFIX
-        with tracer.start("light.save", parent=span) as sspan:
+        with tracer.start("light.save", parent=span) as sspan, \
+                insert_span_attrs(cache, sspan):
             for lb, planned, _r in tile:
                 mine = planned.lanes if planned is not None else ()
-                for lane, ok in zip(mine, oks[at:at + len(mine)]):
-                    # each lane on its own verdict, as everywhere
-                    if ok:
-                        cache.add(lane.pub, lane.msg, lane.sig)
-                    elif failed is None:
-                        failed = lane
+                failed = next((lane for lane, ok in zip(
+                    mine, oks[at:at + len(mine)]) if not ok), None)
                 at += len(mine)
                 if failed is not None:
                     break
                 self.store.save_light_block(lb)
                 saved += 1
+            # each lane on its own verdict, as everywhere: the true lanes
+            # of the headers saved and of the one that failed, in one
+            # insert with their lookups' keys
+            cache.insert([lane.key for lane, ok in zip(lanes[:at], oks)
+                          if ok])
             encoded = SIG_TS_PREFIX[0] - encoded
             ts_reused = SIG_TS_PREFIX[1] - ts_reused
             sspan.set_attr("sig_encodings", encoded)

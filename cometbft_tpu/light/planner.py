@@ -41,6 +41,7 @@ class Lane:
     sig: bytes
     pk: object          # crypto PubKey (CPU-fallback verify)
     sig_index: int      # index into the commit's signature list
+    key: bytes          # SigCache key, from the lane's lookup
 
 
 @dataclass
@@ -66,17 +67,21 @@ def plan_commit_light(chain_id: str, vals: ValidatorSet, block_id,
     total = vals.total_voting_power()
     needed = total * 2 // 3
     planned = PlannedCheck("light", commit, total=total, needed=needed)
-    for idx, cs in enumerate(commit.signatures):
-        if not cs.for_block():
-            continue
-        _validate_sig(cs, idx)
-        val = vals.get_by_index(idx)
-        _add_lane(planned, chain_id, commit, idx, val, cs, cache, path)
-        planned.tallied += val.voting_power
-        if planned.tallied > needed:
-            break
-    if planned.tallied <= needed:
-        raise ErrNotEnoughVotingPowerSigned(planned.tallied, needed)
+    taken = []
+    try:
+        for idx, cs in enumerate(commit.signatures):
+            if not cs.for_block():
+                continue
+            _validate_sig(cs, idx)
+            val = vals.get_by_index(idx)
+            taken.append((idx, val, cs))
+            planned.tallied += val.voting_power
+            if planned.tallied > needed:
+                break
+        if planned.tallied <= needed:
+            raise ErrNotEnoughVotingPowerSigned(planned.tallied, needed)
+    finally:
+        _add_lanes(planned, chain_id, commit, taken, cache, path)
     return planned
 
 
@@ -95,24 +100,29 @@ def plan_commit_trusting(chain_id: str, vals: ValidatorSet,
     needed = (total * trust_level.numerator) // trust_level.denominator
     planned = PlannedCheck("trusting", commit, total=total, needed=needed)
     seen: Dict[int, int] = {}
-    for idx, cs in enumerate(commit.signatures):
-        if not cs.for_block():
-            continue
-        _validate_sig(cs, idx)
-        val_idx, val = vals.get_by_address(cs.validator_address)
-        if val is None:
-            continue  # signer outside the trusted set: no vouching power
-        if val_idx in seen:
-            raise CommitVerificationError(
-                f"double vote from validator {val_idx} "
-                f"({seen[val_idx]} and {idx})")
-        seen[val_idx] = idx
-        _add_lane(planned, chain_id, commit, idx, val, cs, cache, path)
-        planned.tallied += val.voting_power
-        if planned.tallied > needed:
-            break
-    if planned.tallied <= needed:
-        raise ErrNotEnoughVotingPowerSigned(planned.tallied, needed)
+    taken = []
+    try:
+        for idx, cs in enumerate(commit.signatures):
+            if not cs.for_block():
+                continue
+            _validate_sig(cs, idx)
+            val_idx, val = vals.get_by_address(cs.validator_address)
+            if val is None:
+                # signer outside the trusted set: no vouching power
+                continue
+            if val_idx in seen:
+                raise CommitVerificationError(
+                    f"double vote from validator {val_idx} "
+                    f"({seen[val_idx]} and {idx})")
+            seen[val_idx] = idx
+            taken.append((idx, val, cs))
+            planned.tallied += val.voting_power
+            if planned.tallied > needed:
+                break
+        if planned.tallied <= needed:
+            raise ErrNotEnoughVotingPowerSigned(planned.tallied, needed)
+    finally:
+        _add_lanes(planned, chain_id, commit, taken, cache, path)
     return planned
 
 
@@ -142,11 +152,24 @@ def _validate_sig(cs, idx: int) -> None:
             f"invalid signature at index {idx}: {e}") from e
 
 
+def _add_lanes(planned: PlannedCheck, chain_id: str, commit: Commit,
+               taken, cache: SigCache, path: str) -> None:
+    """Look the (index, validator, CommitSig) the tally took up in the
+    cache in one batch; a miss becomes a lane that keeps its key. Run
+    for a refused plan too, so that the lanes it took count on `path`
+    as they always have."""
+    triples = [(val.pub_key.bytes_(), commit.vote_sign_bytes(chain_id, idx),
+                cs.signature) for idx, val, cs in taken]
+    keys, cached = cache.lookup(triples, path)
+    for (idx, val, _cs), (pkb, msg, sig), key, hit in zip(
+            taken, triples, keys, cached):
+        if hit:
+            planned.cache_hits += 1  # previously verified TRUE: no lane
+        else:
+            planned.lanes.append(Lane(pkb, msg, sig, val.pub_key, idx, key))
+
+
 def _add_lane(planned: PlannedCheck, chain_id: str, commit: Commit,
               idx: int, val, cs, cache: SigCache, path: str) -> None:
-    msg = commit.vote_sign_bytes(chain_id, idx)
-    pkb = val.pub_key.bytes_()
-    if cache.seen(pkb, msg, cs.signature, path=path):
-        planned.cache_hits += 1  # previously verified TRUE: no lane
-        return
-    planned.lanes.append(Lane(pkb, msg, cs.signature, val.pub_key, idx))
+    """`_add_lanes` of one signature."""
+    _add_lanes(planned, chain_id, commit, [(idx, val, cs)], cache, path)

@@ -10,6 +10,10 @@ already verified these bytes under this chain" — never a cross-context
 confusion. Failed signatures are never cached (attribution paths handle
 them), so a hit can only skip work, never flip a verdict.
 
+A batch is looked up under one lock (`SigCache.lookup`), which hands
+back each lane's key, and the lanes that then verify true are inserted
+by those keys under one lock (`SigCache.insert`): a lane is hashed once.
+
 Intake paths attribute hits/misses per label ("blocksync", "vote",
 "commit") — the raw material of the pipeline_sigcache_{hits,misses}
 Prometheus counters (libs/metrics_defs.PipelineMetrics). Capacity is
@@ -19,10 +23,11 @@ LRU-bounded; COMETBFT_TPU_SIGCACHE_CAPACITY overrides the default
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import threading
 from collections import OrderedDict
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 from ..libs.env import env_int
 
@@ -39,10 +44,29 @@ def _key(pub: bytes, sign_bytes: bytes, sig: bytes) -> bytes:
     return h.digest()
 
 
+def _tally(path, hits: List[bool]) -> List[Tuple[str, int, int]]:
+    """(path, hits, misses) of a lookup's answers, one a path."""
+    if isinstance(path, str):
+        n = hits.count(True)
+        return [(path, n, len(hits) - n)]
+    tally: Dict[str, List[int]] = {}
+    for p, hit in zip(path, hits):
+        tally.setdefault(p, [0, 0])[0 if hit else 1] += 1
+    return [(p, h, m) for p, (h, m) in tally.items()]
+
+
 class SigCache:
-    """Thread-safe LRU of verified-true signatures."""
+    """Thread-safe LRU of verified-true signatures.
+
+    A site that looks a batch of lanes up and later inserts those that
+    verified true goes through `lookup` and `insert`: each lane's key is
+    computed once, at its lookup, and each half takes the lock once for
+    the whole batch. `seen` and `add` are their one-lane forms, for the
+    callers that look a signature up alone (`seen` written out: such a
+    caller builds no lists)."""
 
     # guarded-by: _lock: _entries, hits, misses, evictions
+    # guarded-by: _lock: inserted, inserted_keyed
     # (enforced by tools/staticcheck's guarded-by rule: any access to
     # the attributes above outside `with self._lock` is a lint error)
 
@@ -54,6 +78,10 @@ class SigCache:
         self.evictions = 0
         self.hits: Dict[str, int] = {}
         self.misses: Dict[str, int] = {}
+        # lanes inserted, and of them those whose key came from their
+        # lookup (`insert`) rather than computed again (`add`)
+        self.inserted = 0
+        self.inserted_keyed = 0
 
     def __len__(self) -> int:
         with self._lock:
@@ -85,22 +113,73 @@ class SigCache:
             (m.cache_hits if hit else m.cache_misses).inc(path=path)
         return hit
 
+    def lookup(self, lanes: Sequence[Tuple[bytes, bytes, bytes]],
+               path: Union[str, Sequence[str]] = "unknown"
+               ) -> Tuple[List[bytes], List[bool]]:
+        """(keys, hits) of (pub, sign_bytes, sig) lanes: each lane's key,
+        to hand to `insert` once it verifies true, and whether it
+        previously verified TRUE — what `seen` answers of it, in order,
+        under one lock. `path` is the lanes' attribution label, or one
+        label a lane."""
+        keys = [_key(pub, sign_bytes, sig) for pub, sign_bytes, sig in lanes]
+        if self.capacity <= 0:
+            return keys, [False] * len(keys)
+        with self._lock:
+            entries = self._entries
+            hits = [k in entries for k in keys]
+            for k, hit in zip(keys, hits):
+                if hit:
+                    entries.move_to_end(k)
+            tally = _tally(path, hits)
+            for p, h, m in tally:
+                if h:
+                    self.hits[p] = self.hits.get(p, 0) + h
+                if m:
+                    self.misses[p] = self.misses.get(p, 0) + m
+        metrics = self.metrics
+        if metrics is not None:
+            for p, h, m in tally:
+                if h:
+                    metrics.cache_hits.inc(h, path=p)
+                if m:
+                    metrics.cache_misses.inc(m, path=p)
+        return keys, hits
+
     def add(self, pub: bytes, sign_bytes: bytes, sig: bytes) -> None:
         """Record a signature that verified TRUE. Never call for a
         failed verification."""
+        self._insert([_key(pub, sign_bytes, sig)], keyed=False)
+
+    def insert(self, keys: Sequence[bytes]) -> None:
+        """Record, in order, the signatures whose `lookup` keys these
+        are; each verified TRUE. Never pass the key of a failed
+        verification."""
+        self._insert(keys, keyed=True)
+
+    def _insert(self, keys: Sequence[bytes], keyed: bool) -> None:
         if self.capacity <= 0:
             return
         evicted = 0
-        k = _key(pub, sign_bytes, sig)
         with self._lock:
-            self._entries[k] = None
-            self._entries.move_to_end(k)
-            while len(self._entries) > self.capacity:
-                self._entries.popitem(last=False)
+            entries = self._entries
+            for k in keys:
+                entries[k] = None
+                entries.move_to_end(k)
+            while len(entries) > self.capacity:
+                entries.popitem(last=False)
                 evicted += 1
             self.evictions += evicted
+            self.inserted += len(keys)
+            if keyed:
+                self.inserted_keyed += len(keys)
         if evicted and self.metrics is not None:
             self.metrics.cache_evictions.inc(evicted)
+
+    def insert_counts(self) -> Tuple[int, int]:
+        """(lanes inserted, of them those inserted with their lookup's
+        key) since the cache was made or cleared."""
+        with self._lock:
+            return self.inserted, self.inserted_keyed
 
     def hit_rate(self, path: Optional[str] = None) -> float:
         """Hits / (hits + misses), overall or for one intake path."""
@@ -117,6 +196,26 @@ class SigCache:
             self.hits.clear()
             self.misses.clear()
             self.evictions = 0
+            self.inserted = self.inserted_keyed = 0
+
+
+@contextlib.contextmanager
+def insert_span_attrs(cache: Optional[SigCache], span):
+    """Sets on `span` the lanes `cache` inserted while the block ran
+    (`sigcache_inserted`) and, of them, those inserted with their
+    lookup's key (`sigcache_inserted_keyed`). The counts are the
+    cache's, so the block must be its only inserter meanwhile, as the
+    catch-up settle and the light client's save are."""
+    if cache is None:
+        yield
+        return
+    inserted, keyed = cache.insert_counts()
+    try:
+        yield
+    finally:
+        now_inserted, now_keyed = cache.insert_counts()
+        span.set_attr("sigcache_inserted", now_inserted - inserted)
+        span.set_attr("sigcache_inserted_keyed", now_keyed - keyed)
 
 
 _shared: Optional[SigCache] = None

@@ -61,6 +61,7 @@ from ..state.execution import BlockValidationError
 from ..state.state import VALSET_ENCODINGS, State
 from ..trace import ctx_of, shared_tracer
 from ..types.block import SIG_ENCODINGS, SIGN_BYTES_TEMPLATES
+from .cache import insert_span_attrs
 
 
 # --- futures + verify backends ------------------------------------------------
@@ -357,6 +358,7 @@ class _Tile:
     pubs: List[bytes]
     msgs: List[bytes]
     sigs: List[bytes]
+    keys: List[bytes]                # each lane's sigcache key
     future: object = None            # None => out already final
     out: Optional[np.ndarray] = None
     valset_break: int = 0            # height whose header announced a
@@ -469,8 +471,9 @@ class PipelinedBlocksync:
             pubs: List[bytes] = []
             msgs: List[bytes] = []
             sigs: List[bytes] = []
+            keys: List[bytes] = []
             metas = [marshal_commit(self.r.verifier.chain_id, e, pubs,
-                                    msgs, sigs, self.r.cache)
+                                    msgs, sigs, self.r.cache, keys)
                      for e in entries]
         finally:
             # as _host_stage_span's: process-wide counters, this
@@ -485,7 +488,7 @@ class PipelinedBlocksync:
 
         tile = _Tile(start=start, end=end, fetched=fetched,
                      entries=entries, metas=metas, pubs=pubs, msgs=msgs,
-                     sigs=sigs, valset_break=valset_break, span=tspan)
+                     sigs=sigs, keys=keys, valset_break=valset_break, span=tspan)
         tspan.set_attr("end", end)
         tspan.set_attr("lanes", len(pubs))
         if not pubs:
@@ -615,8 +618,9 @@ class PipelinedBlocksync:
                 else:
                     out = self._canary_check(tile, out, sspan)
                 tile.out = np.asarray(out, dtype=bool)
-            settle_tile(tile.metas, tile.out, tile.pubs, tile.msgs,
-                        tile.sigs, self.r.cache)
+            with insert_span_attrs(self.r.cache, sspan):
+                settle_tile(tile.metas, tile.out, tile.pubs, tile.msgs,
+                            tile.sigs, self.r.cache, tile.keys)
             if tile.entries:
                 self.r.stats.tiles_flushed += 1
                 self.r.stats.sigs_verified += sum(

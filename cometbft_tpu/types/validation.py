@@ -146,8 +146,7 @@ def _verify_commit_lanes(chain_id, vals, commit, voting_power_needed,
     # two routes have always raised in different orders (below)
     tallied = 0
     seen = {}
-    hits = 0
-    missing = []    # (idx, pub key, its bytes, sign-bytes, signature)
+    walked = []     # (idx, pub key, its bytes, sign-bytes, signature)
     refused = None
     try:
         for idx, cs in enumerate(commit.signatures):
@@ -171,12 +170,9 @@ def _verify_commit_lanes(chain_id, vals, commit, voting_power_needed,
                         f"({seen[val_idx]} and {idx})")
                 seen[val_idx] = idx
 
-            msg = commit.vote_sign_bytes(chain_id, idx)
-            pkb = val.pub_key.bytes_()
-            if cache.seen(pkb, msg, cs.signature, path="commit"):
-                hits += 1   # previously verified TRUE: no work either route
-            else:
-                missing.append((idx, val.pub_key, pkb, msg, cs.signature))
+            walked.append((idx, val.pub_key, val.pub_key.bytes_(),
+                           commit.vote_sign_bytes(chain_id, idx),
+                           cs.signature))
 
             if count(cs):
                 tallied += val.voting_power
@@ -187,6 +183,14 @@ def _verify_commit_lanes(chain_id, vals, commit, voting_power_needed,
                                                 voting_power_needed)
     except CommitVerificationError as e:
         refused = e
+    # one lookup of the lanes walked; a hit previously verified TRUE and
+    # is no work on either route. A miss keeps its key for the insert
+    keys, cached = cache.lookup([lane[2:] for lane in walked],
+                                path="commit")
+    missing = [(idx, pub_key, key, msg, sig)    # key: the lane's cache key
+               for (idx, pub_key, _pkb, msg, sig), key, hit
+               in zip(walked, keys, cached) if not hit]
+    hits = len(walked) - len(missing)
 
     bv = None
     if _should_batch_verify(vals, len(missing)):
@@ -209,32 +213,32 @@ def _verify_commit_lanes(chain_id, vals, commit, voting_power_needed,
         # the native route verifies in index order and names the first
         # signature that fails, before anything the walk refused at or
         # after it (reference verifyCommitSingle)
-        for idx, pub_key, pkb, msg, sig in missing:
-            if not pub_key.verify_signature(msg, sig):
-                raise ErrWrongSignature(idx, sig)
-            cache.add(pkb, msg, sig)
+        oks = []
+        for _idx, pub_key, _key, msg, sig in missing:
+            oks.append(bool(pub_key.verify_signature(msg, sig)))
+            if not oks[-1]:
+                break
+    else:
+        # the batch route flushes only what passed the walk (reference
+        # verifyCommitBatch: the tally is checked before the batch
+        # verifies)
         if refused is not None:
             raise refused
-        return
-    # the batch route flushes only what passed the walk (reference
-    # verifyCommitBatch: the tally is checked before the batch verifies)
+        for _idx, pub_key, _key, msg, sig in missing:
+            bv.add(pub_key, msg, sig)
+        _all_ok, oks = bv.verify()
+        # fail-closed: a lane counts as verified only on its own
+        # verdict; a verifier that answers for fewer lanes than it was
+        # given has refused the rest
+        oks = [bool(ok) for ok in oks] + \
+            [False] * (len(missing) - len(oks))
+    # the lanes that verified true, in one insert
+    cache.insert([lane[2] for lane, ok in zip(missing, oks) if ok])
+    for (idx, _pk, _key, _msg, sig), ok in zip(missing, oks):
+        if not ok:
+            raise ErrWrongSignature(idx, sig)
     if refused is not None:
         raise refused
-    for _idx, pub_key, _pkb, msg, sig in missing:
-        bv.add(pub_key, msg, sig)
-    _all_ok, oks = bv.verify()
-    # fail-closed: a lane counts as verified only on its own verdict; a
-    # verifier that answers for fewer lanes than it was given has
-    # refused the rest
-    oks = [bool(ok) for ok in oks] + [False] * (len(missing) - len(oks))
-    first_bad = None
-    for (idx, _pk, pkb, msg, sig), ok in zip(missing, oks):
-        if ok:
-            cache.add(pkb, msg, sig)
-        elif first_bad is None:
-            first_bad = ErrWrongSignature(idx, sig)
-    if first_bad is not None:
-        raise first_bad
 
 
 def verify_commit(chain_id: str, vals: ValidatorSet, block_id: BlockID,

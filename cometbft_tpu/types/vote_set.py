@@ -451,7 +451,8 @@ def verify_cached(pub_key, pkb: bytes, sign_bytes: bytes, sig: bytes,
     signature it is (a vote's, `vote`, or its extension's, `ext`)."""
     from ..pipeline.cache import shared_cache
     cache = shared_cache()
-    if cache.seen(pkb, sign_bytes, sig, path=path):
+    (key,), (hit,) = cache.lookup([(pkb, sign_bytes, sig)], path)
+    if hit:
         return True, True
     if not _TRACER.enabled:
         ok = pub_key.verify_signature(sign_bytes, sig)
@@ -459,7 +460,7 @@ def verify_cached(pub_key, pkb: bytes, sign_bytes: bytes, sig: bytes,
         with _TRACER.start("vote.verify", height=height, path=path):
             ok = pub_key.verify_signature(sign_bytes, sig)
     if ok:
-        cache.add(pkb, sign_bytes, sig)
+        cache.insert([key])
     return False, ok
 
 
@@ -493,16 +494,22 @@ def preverify_lanes(lanes) -> dict:
             counts[path][2] += 1
         return counts
     cache = shared_cache()
-    missing, asked = [], set()
+    unique, asked = [], set()
     for pub_key, sign_bytes, sig, path in lanes:
-        pkb = pub_key.bytes_()
-        if (pkb, sign_bytes, sig) in asked:
+        triple = (pub_key.bytes_(), sign_bytes, sig)
+        if triple in asked:
             continue                # the same vote twice in one list
-        asked.add((pkb, sign_bytes, sig))
-        if cache.seen(pkb, sign_bytes, sig, path=path):
+        asked.add(triple)
+        unique.append((pub_key, triple, path))
+    keys, cached = cache.lookup([triple for _pk, triple, _p in unique],
+                                [path for _pk, _t, path in unique])
+    missing = []    # (public key, cache key, sign-bytes, signature, path)
+    for (pub_key, (_pkb, sign_bytes, sig), path), key, hit in zip(
+            unique, keys, cached):
+        if hit:
             counts[path][0] += 1
         else:
-            missing.append((pub_key, pkb, sign_bytes, sig, path))
+            missing.append((pub_key, key, sign_bytes, sig, path))
     bv, ok = None, False
     if len(missing) >= BATCH_VERIFY_THRESHOLD and \
             len({lane[0].type_() for lane in missing}) == 1:
@@ -511,11 +518,10 @@ def preverify_lanes(lanes) -> dict:
         for *_lane, path in missing:
             counts[path][2] += 1
         return counts
-    for pub_key, _pkb, sign_bytes, sig, path in missing:
+    for pub_key, _key, sign_bytes, sig, path in missing:
         bv.add(pub_key, sign_bytes, sig)
         counts[path][1] += 1
     _all_ok, lane_oks = bv.verify()
-    for (_pk, pkb, sign_bytes, sig, _path), lane_ok in zip(missing, lane_oks):
-        if lane_ok:
-            cache.add(pkb, sign_bytes, sig)
+    cache.insert([lane[1] for lane, lane_ok in zip(missing, lane_oks)
+                  if lane_ok])
     return counts

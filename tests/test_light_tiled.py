@@ -495,6 +495,48 @@ def test_verified_lanes_reach_the_cache_under_their_own_label(monkeypatch,
         reset_shared_cache()
 
 
+def test_the_save_inserts_true_lanes_never_the_false_one(monkeypatch,
+                                                        chain):
+    """A false lane mid-tile: the save inserts the true lanes of the
+    headers before it and of its own header, each with the key its
+    lookup computed, and neither the false lane nor the lanes of the
+    headers planned after it."""
+    height, bad = 2 + TILE // 2, 1
+    provider, now = Provider(chain, {height: bad_lane(bad)}), _now(chain)
+    monkeypatch.setattr(light_client, "kernel_width", lambda: WIDTH)
+    monkeypatch.setattr(light_client, "TILE_CHUNKS", CHUNKS)
+    monkeypatch.setattr(validation, "BATCH_VERIFY_THRESHOLD", TAKEN)
+    monkeypatch.setattr(
+        crypto_batch, "create_batch_verifier",
+        lambda pk: (StubVerifier([], lambda oks: oks), True))
+    reset_shared_cache()
+    try:
+        lc = LightClient(
+            CHAIN, TrustOptions(PERIOD, 1, chain.headers[1].hash()),
+            provider, [], LightStore(MemDB()), sequential=True,
+            now_fn=lambda: now)
+        cache = shared_cache()
+        cache.clear()       # the root's own commit
+        with pytest.raises(verifier.ErrInvalidHeader, match=f"#{bad}"):
+            lc.verify_light_block_at_height(chain.n)
+        lanes = {}
+        for h in range(2, height + 1):
+            lb = provider.light_block(h)
+            commit = lb.signed_header.commit
+            for i in range(TAKEN):
+                lanes[h, i] = (
+                    lb.validator_set.get_by_index(i).pub_key.bytes_(),
+                    commit.vote_sign_bytes(CHAIN, i),
+                    commit.signatures[i].signature)
+        false = lanes.pop((height, bad))
+        assert len(cache) == len(lanes)
+        assert all(cache.seen(*t, path="x") for t in lanes.values())
+        assert not cache.seen(*false, path="x")
+        assert cache.insert_counts() == (len(lanes), len(lanes))
+    finally:
+        reset_shared_cache()
+
+
 def test_spans_of_a_tile(monkeypatch, chain):
     trace.enable(seed=3)
     try:

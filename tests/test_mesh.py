@@ -461,11 +461,12 @@ def test_farm_fallback_routes_wide_batches_through_the_warmed_bucket(
     from cometbft_tpu.farm.batcher import _fallback_verify
     from cometbft_tpu.farm.planner import Lane
     from cometbft_tpu.libs import jax_cache
+    from cometbft_tpu.pipeline.cache import SigCache
     from cometbft_tpu.ops import ed25519 as e5
     from cometbft_tpu.ops.pallas_verify import TILE
 
     pubs, msgs, sigs = _batch(128, seed=5)
-    lanes = [Lane(p, m, s, Ed25519PubKey(p), i)
+    lanes = [Lane(p, m, s, Ed25519PubKey(p), i, SigCache.key(p, m, s))
              for i, (p, m, s) in enumerate(zip(pubs, msgs, sigs))]
     calls = []
 

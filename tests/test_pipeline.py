@@ -352,6 +352,204 @@ def test_cache_disabled_capacity_zero():
     assert len(c) == 0
 
 
+def _lane(i: int):
+    return (b"pk%d" % i, b"sign-bytes %d" % (i % 3), b"sig%d" % i)
+
+
+# scripted sequences: ("lookup", lane numbers, path or a path a lane) and
+# ("insert", lane numbers); an insert hands over the keys its lanes were
+# looked up with, as every keyed call site does
+_CACHE_SCRIPTS = {
+    "one-path-under-capacity": (64, [
+        ("lookup", [0, 1, 2, 3], "blocksync"),
+        ("insert", [0, 1, 3]),
+        ("lookup", [0, 1, 2, 3, 4], "blocksync"),
+        ("insert", [2, 4]),
+        ("lookup", [4, 3, 2, 1, 0], "commit")]),
+    "at-and-over-capacity": (4, [
+        ("lookup", [0, 1, 2, 3], "light"),
+        ("insert", [0, 1, 2, 3]),          # exactly at capacity
+        ("lookup", [1, 5, 6], "light"),    # touches 1: 0 is now oldest
+        ("insert", [5, 6, 1]),             # evicts 0 and 2; 1 re-inserted
+        ("lookup", [0, 1, 2, 3, 5, 6, 7, 8, 9, 10, 11], "light"),
+        ("insert", [7, 8, 9, 10, 11]),     # a batch larger than capacity
+        ("lookup", [6, 7, 8, 9, 10, 11], "commit")]),
+    "a-path-a-lane": (3, [
+        ("lookup", [0, 1, 2], ["vote", "ext", "vote"]),
+        ("insert", [0, 2]),
+        ("lookup", [0, 1, 2, 3], ["ext", "ext", "vote", "vote"]),
+        ("insert", [1, 3]),
+        ("lookup", [2, 3, 1, 0], ["vote", "ext", "vote", "ext"])]),
+    "duplicates-in-a-batch": (8, [
+        ("lookup", [0, 0, 1], "blocksync"),
+        ("insert", [0, 0, 1]),
+        ("lookup", [1, 1, 0], "blocksync")]),
+    "capacity-zero": (0, [
+        ("lookup", [0, 1], "vote"),
+        ("insert", [0, 1]),
+        ("lookup", [0, 1], ["vote", "commit"])]),
+}
+
+
+def _play(script, capacity, batched):
+    """Runs a script through `lookup`/`insert` or lane by lane through
+    `seen`/`add`; returns what each lookup answered, the cache and its
+    metrics."""
+    metrics = PipelineMetrics(Registry())
+    c = SigCache(capacity=capacity, metrics=metrics)
+    keys, answers = {}, []
+    for op, lanes, *path in script:
+        if op == "lookup":
+            paths = path[0] if isinstance(path[0], list) \
+                else [path[0]] * len(lanes)
+            if batched:
+                got, hits = c.lookup([_lane(i) for i in lanes], path[0])
+                keys.update(zip(lanes, got))
+            else:
+                hits = [c.seen(*_lane(i), path=p)
+                        for i, p in zip(lanes, paths)]
+            answers.append(hits)
+        elif batched:
+            c.insert([keys[i] for i in lanes])
+        else:
+            for i in lanes:
+                c.add(*_lane(i))
+    return answers, keys, c, metrics
+
+
+@pytest.mark.parametrize("name", sorted(_CACHE_SCRIPTS))
+def test_cache_batch_ops_equal_lane_by_lane(name):
+    """`lookup` and `insert` leave what the same lanes through `seen`
+    and `add` one at a time leave: answers, per-path hits and misses,
+    LRU order, evictions and metric increments; and the keys are
+    `SigCache.key`'s."""
+    capacity, script = _CACHE_SCRIPTS[name]
+    answers, keys, c, m = _play(script, capacity, batched=True)
+    want_answers, _none, ref, ref_m = _play(script, capacity, batched=False)
+    assert keys and all(k == SigCache.key(*_lane(i))
+                        for i, k in keys.items())
+    assert answers == want_answers
+    with c._lock, ref._lock:
+        assert list(c._entries) == list(ref._entries)
+        assert (c.hits, c.misses, c.evictions) == \
+            (ref.hits, ref.misses, ref.evictions)
+        assert len(c._entries) <= max(capacity, 0)
+    assert m.cache_evictions.value() == ref_m.cache_evictions.value()
+    paths = {p for _op, _l, *path in script for p in
+             (path[0] if path and isinstance(path[0], list) else path)}
+    for p in paths:
+        assert m.cache_hits.value(path=p) == ref_m.cache_hits.value(path=p)
+        assert m.cache_misses.value(path=p) == \
+            ref_m.cache_misses.value(path=p)
+    inserted = sum(len(lanes) for op, lanes, *_p in script
+                   if op == "insert") if capacity > 0 else 0
+    assert c.insert_counts() == (inserted, inserted)
+    assert ref.insert_counts() == (inserted, 0)
+    if capacity == 0:   # always miss, never store, count nothing
+        assert not any(h for a in answers for h in a)
+        assert len(c) == 0 and c.hits == c.misses == {}
+
+
+def _only_true_lanes_cached(cache, good, bad):
+    """Every true lane findable under the key its lookup computes, the
+    false one not, nothing else stored, and every insert keyed."""
+    assert len(cache) == len(good)
+    assert all(cache.seen(*t) for t in good)
+    assert not cache.seen(*bad)
+    assert cache.insert_counts() == (len(good), len(good))
+
+
+def _tampered(sig: bytes) -> bytes:
+    return bytes([sig[0] ^ 1]) + sig[1:]
+
+
+def test_settle_inserts_true_lanes_never_the_false_one():
+    """A tile with one false lane among true ones: settle inserts the
+    true lanes, the false lane's commit's too, each on its own verdict
+    and with the key marshal looked it up with."""
+    from cometbft_tpu.engine.blocksync import TileEntry
+    from cometbft_tpu.types.block import Commit, CommitSig
+    cache = SigCache(capacity=1024)
+    v = TiledCommitVerifier(CHAIN.chain_id, batch_size=0, cache=cache)
+    entries, good, bad = [], [], None
+    for h in (1, 2, 3):
+        commit, vals = CHAIN.seen_commits[h - 1], CHAIN.valsets[h - 1]
+        if h == 2:
+            sigs = [CommitSig(cs.block_id_flag, cs.validator_address,
+                              cs.timestamp, _tampered(cs.signature))
+                    if i == 1 else cs
+                    for i, cs in enumerate(commit.signatures)]
+            commit = Commit(commit.height, commit.round, commit.block_id,
+                            sigs)
+        for i, cs in enumerate(commit.signatures):
+            t = (vals.get_by_index(i).pub_key.bytes_(),
+                 commit.vote_sign_bytes(CHAIN.chain_id, i), cs.signature)
+            if (h, i) == (2, 1):
+                bad = t
+            else:
+                good.append(t)
+        entries.append(TileEntry(
+            height=h, block=CHAIN.blocks[h - 1],
+            block_id=CHAIN.block_ids[h - 1], valset=vals, commit=commit))
+    v.verify_tile(entries)
+    assert [e.commit_ok for e in entries] == [True, False, True]
+    _only_true_lanes_cached(cache, good, bad)
+
+
+def test_a_pipelined_catch_up_inserts_every_lane_with_its_key():
+    cache = SigCache(capacity=1024)
+    state, _r, _s, _a = _sync(CHAIN, depth=4, cache=cache)
+    assert state.last_block_height == 12
+    inserted, keyed = cache.insert_counts()
+    assert inserted == keyed == len(cache) > 0
+
+
+class _NativeBatch:
+    """A `crypto.batch` verifier that answers natively."""
+
+    def __init__(self):
+        self.lanes = []
+
+    def add(self, pk, msg, sig):
+        self.lanes.append((pk, msg, sig))
+
+    def verify(self):
+        oks = [pk.verify_signature(m, s) for pk, m, s in self.lanes]
+        return all(oks), oks
+
+
+@pytest.mark.parametrize("route", ["native", "batch"])
+def test_commit_verify_inserts_true_lanes_never_the_false_one(monkeypatch,
+                                                              route):
+    """Both routes of commit verification with one false lane among
+    true ones: the native route inserts what verified before it and
+    names it, the batch route every true lane; never the false one."""
+    import cometbft_tpu.pipeline.cache as pc
+    from cometbft_tpu.crypto import batch as crypto_batch
+    from cometbft_tpu.types import validation
+    from cometbft_tpu.types.block import Commit, CommitSig
+    fresh = SigCache(capacity=256)
+    monkeypatch.setattr(pc, "_shared", fresh)
+    if route == "batch":
+        monkeypatch.setattr(validation, "BATCH_VERIFY_THRESHOLD", 2)
+        monkeypatch.setattr(crypto_batch, "create_batch_verifier",
+                            lambda pk: (_NativeBatch(), True))
+    commit, vals = CHAIN.seen_commits[2], CHAIN.valsets[2]
+    sigs = [CommitSig(cs.block_id_flag, cs.validator_address, cs.timestamp,
+                      _tampered(cs.signature)) if i == 1 else cs
+            for i, cs in enumerate(commit.signatures)]
+    commit = Commit(commit.height, commit.round, commit.block_id, sigs)
+    lanes = [(vals.get_by_index(i).pub_key.bytes_(),
+              commit.vote_sign_bytes(CHAIN.chain_id, i), cs.signature)
+             for i, cs in enumerate(commit.signatures)]
+    with pytest.raises(validation.ErrWrongSignature, match=r"\(#1\)"):
+        validation.verify_commit(CHAIN.chain_id, vals, commit.block_id, 3,
+                                 commit)
+    # the native route stops at the false lane, the batch route does not
+    good = lanes[:1] if route == "native" else lanes[:1] + lanes[2:]
+    _only_true_lanes_cached(fresh, good, lanes[1])
+
+
 def test_tile_cache_skips_device_lanes_same_verdicts():
     """A warm cache marshals ZERO device lanes and still reproduces the
     exact per-commit verdicts (including structural/negative ones)."""

@@ -455,6 +455,28 @@ def test_taint_canaried_path_negative(tmp_path):
     assert res.findings == []
 
 
+@pytest.mark.parametrize("gated", [False, True],
+                         ids=["uncanaried", "canaried"])
+def test_taint_sigcache_keyed_insert(tmp_path, gated):
+    """A batch insert of the lanes a device called true is a sink as
+    `add` is: the verdicts it filters on must be canary-gated first."""
+    files = _taint_tree(
+        "def insert(keys, cache: SigCache):\n"
+        "    client = shared_client()\n"
+        "    _ok, oks = client.submit([], [], []).result()\n"
+        + ("    ok, oks = health.check_canaries(oks, len(keys))\n"
+           "    if not ok:\n"
+           "        return\n" if gated else "")
+        + "    cache.insert([k for k, ok in zip(keys, oks) if ok])\n")
+    files["cometbft_tpu/pipeline/cache.py"] += (
+        "\n"
+        "    def insert(self, keys):\n"
+        "        pass\n")
+    res = lint(tmp_path, files, rules=[R.VerdictTaintRule])
+    assert bool(res.findings) != gated
+    assert all(f.rule == "verdict-taint" for f in res.findings)
+
+
 def test_taint_mempool_check_tx_guard_positive(tmp_path):
     # a raw device verdict deciding admission — the exact invariant
     # ingest/ pins by test, caught statically

@@ -29,7 +29,7 @@ SANITIZERS / GATES — what clears taint:
   * re-binding a name from any clean expression (a CPU re-verify).
 
 SINKS — where a tainted verdict becomes consensus/cache state:
-  * `SigCache.add` (type-resolved receiver),
+  * `SigCache.add` and `SigCache.insert` (type-resolved receiver),
   * attribute calls named `check_tx`, `_apply_one`, or
     `save_light_block` (mempool admission, block apply, farm decision
     commit) — name-matched, because the mempool/reactor seams pass
@@ -89,6 +89,7 @@ GATES = {
 }
 SINK_QUALS = {
     f"{_PKG}.pipeline.cache.SigCache.add",
+    f"{_PKG}.pipeline.cache.SigCache.insert",
 }
 SINK_NAMES = {"check_tx", "_apply_one", "save_light_block",
               "install_adopted"}
