@@ -227,10 +227,10 @@ class VerificationFarm:
                 f"(farm verifies forward only)")
         try:
             target.validate_basic(self.chain_id)
-            steps = planner.plan_update(
-                self.chain_id, latest, target, self.provider, now,
-                session.trusting_period_s, session.trust_level,
-                self.cache, max_fetches=self.max_fetches)
+            steps = list(planner.plan_update(
+                self.chain_id, latest, target, self.provider.light_block,
+                now, session.trusting_period_s, session.trust_level,
+                self.cache, max_fetches=self.max_fetches))
         except (verifier.VerificationError, CommitVerificationError,
                 LightBlockError, ProviderError) as e:
             self._reject(session)

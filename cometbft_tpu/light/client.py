@@ -21,7 +21,7 @@ from ..types.agg_commit import AggregatedCommit
 from ..types.block import SIG_TS_PREFIX
 from ..types.proto import Timestamp
 from ..types import validation
-from . import verifier
+from . import planner, verifier
 from .provider import Provider, ProviderError
 from .store import LightStore
 from .types import LightBlock, LightBlockError
@@ -41,35 +41,72 @@ from .types import LightBlock, LightBlockError
 # flush's fixed part.
 TILE_CHUNKS = 16
 
-# The tiled walk's counters, process-wide like the verified-signature
-# cache it fills: headers trusted, tiles settled, flushes through the
-# crypto.batch seam, and of the lanes the rule took those a flush
+# A bisection settles (one flush, then its steps trusted in order)
+# whenever its unsettled planned lanes reach BISECT_LANES, a sequential
+# tile's on a TPU (16 chunks of 512), on every platform. A primary that
+# forges a pivot whose tallies pass, and above it headers whose sets
+# vouch only for the next, makes the client fetch and hold that many
+# lanes' steps at most before the forgery is refused, and not every
+# height of the gap. An honest update of `light-skip-100` plans 1,616.
+BISECT_LANES = 8192
+
+# The walks' counters, process-wide like the verified-signature cache
+# they fill, one set a walk, each keeping the keys of its own (`_count`).
+# The sequential walk's: headers trusted, tiles settled, flushes through
+# the crypto.batch seam, and of the lanes the rule took those a flush
 # verified, those verified natively and (beside them) those the cache
-# answered; and of the headers trusted, those whose set took the hash
-# of the header before's (`ValidatorSet.adopt_hash_of`); and of the
-# CommitSigs the saves encoded in a commit's one pass, those whose
-# timestamp's seconds field came from an earlier lane of that pass
-# (`types/block.SIG_TS_PREFIX`). One thread's delta is exact.
-_tile_counts = {"headers": 0, "tiles": 0, "flushes": 0, "lanes": 0,
-                "device_lanes": 0, "native_lanes": 0, "cache_hits": 0,
-                "set_hashes_reused": 0, "sig_encodings": 0,
-                "sig_ts_prefix_reused": 0}
-_tile_lock = threading.Lock()
+# answered; of the headers trusted, those whose set took the hash of the
+# header before's (`ValidatorSet.adopt_hash_of`); and of the CommitSigs
+# the saves encoded in a commit's one pass, those whose timestamp's
+# seconds field came from an earlier lane of that pass
+# (`types/block.SIG_TS_PREFIX`). The planned skipping walk's: updates
+# (calls that planned a bisection), steps trusted, pivots fetched,
+# flushes, the lanes the checks took and the cache did not answer
+# (`lanes`, a signature two checks of a step take counted twice), of
+# those the distinct ones (`lanes_distinct`), of which a flush verified
+# `device_lanes` and the rest were verified natively (`native_lanes`),
+# and the lanes the cache answered. One thread's delta is exact.
+_counts = {
+    "tile": dict.fromkeys(
+        ("headers", "tiles", "flushes", "lanes", "device_lanes",
+         "native_lanes", "cache_hits", "set_hashes_reused",
+         "sig_encodings", "sig_ts_prefix_reused"), 0),
+    "bisect": dict.fromkeys(
+        ("updates", "steps", "fetches", "flushes", "lanes",
+         "lanes_distinct", "device_lanes", "native_lanes", "cache_hits"),
+        0)}
+_counts_lock = threading.Lock()
+
+
+def _count(walk: str, numbers: dict) -> None:
+    """Add to `walk`'s counters those of `numbers` it keeps."""
+    with _counts_lock:
+        counts = _counts[walk]
+        for key, n in numbers.items():
+            if key in counts:
+                counts[key] += n
 
 
 def tile_stats() -> dict:
     """Snapshot of the sequential walk's counters."""
-    with _tile_lock:
-        return dict(_tile_counts)
+    with _counts_lock:
+        return dict(_counts["tile"])
+
+
+def bisect_stats() -> dict:
+    """Snapshot of the planned skipping walk's counters."""
+    with _counts_lock:
+        return dict(_counts["bisect"])
 
 
 def _verify_lanes(vals, lanes) -> tuple:
-    """Verdicts of a tile's planned lanes, one a lane, and whether they
-    went through the crypto.batch seam in one flush (the device, on a
-    TPU): the rule, the seam and the reading of the answer are
-    `types/validation._verify_commit_lanes`'s. Fail-closed: a lane
-    counts as verified only on its own verdict, and a verifier that
-    answers for fewer lanes than it was given has refused the rest."""
+    """Verdicts of planned lanes (a tile's, or a bisection's), one a
+    lane, and whether they went through the crypto.batch seam in one
+    flush (the device, on a TPU): the rule, the seam and the reading of
+    the answer are `types/validation._verify_commit_lanes`'s.
+    Fail-closed: a lane counts as verified only on its own verdict, and
+    a verifier that answers for fewer lanes than it was given has
+    refused the rest."""
     if not validation._should_batch_verify(vals, len(lanes)):
         return [lane.pk.verify_signature(lane.msg, lane.sig)
                 for lane in lanes], False
@@ -82,6 +119,12 @@ def _verify_lanes(vals, lanes) -> tuple:
     _all_ok, oks = bv.verify()
     return ([bool(ok) for ok in oks]
             + [False] * (len(lanes) - len(oks))), True
+
+
+def _room(step) -> int:
+    """The lanes a step takes of a flush's budget: at least one, so that
+    a step whose every lane the cache answered still counts."""
+    return max(1, sum(len(check.lanes) for check in step.checks))
 
 
 class LightClientError(Exception):
@@ -218,11 +261,11 @@ class LightClient:
         before it (trusted or, inside the tile, only planned), and
         their commits planned: the lanes the +2/3 rule takes. The lanes
         of the whole tile are then verified in ONE flush, and only then
-        are its headers trusted, in order. No header enters the store
-        before every lane the rule takes of it, and every header before
-        it, has verified true; what fails at header j leaves the store
-        as a walk one header at a time would: everything below j
-        trusted, j and everything after it not."""
+        are its headers trusted, in order (`_settle`). No header enters
+        the store before every lane the rule takes of it, and every
+        header before it, has verified true; what fails at header j
+        leaves the store as a walk one header at a time would:
+        everything below j trusted, j and everything after it not."""
         # whole chunks of the device's lane bucket; 0 where lanes verify
         # natively: a tile is then one header
         budget = TILE_CHUNKS * kernel_width()
@@ -246,32 +289,34 @@ class LightClient:
                                 # still verified and saved first
                                 refused = e
                                 break
-                            cur, h = held[0], h + 1
+                            cur, h = held.lb, h + 1
                         # a header with every lane cached still takes
-                        # room, so that no tile grows without end; a
-                        # commit that cannot be planned takes a tile
-                        planned = held[1]
-                        need = (max(1, len(planned.lanes))
-                                if planned is not None else max(1, budget))
+                        # room, so that no tile grows without end
+                        need = _room(held)
                         if tile and need > room:
                             break
                         tile.append(held)
                         room, held = room - need, None
                 if tile:
-                    self._settle_tile(tile, span)
+                    numbers, failed = self._settle(tile, span, "tile")
+                    span.set_attr("first_height", tile[0].lb.height)
+                    for key in ("headers", "lanes", "cache_hits"):
+                        span.set_attr(key, numbers[key])
+                    if failed is not None:
+                        raise failed
                 if refused is not None:
                     raise refused
 
     def _plan_header(self, cur: LightBlock, height: int,
-                     target: LightBlock, now: Timestamp, cache):
-        """(light block, planned commit, whether its set's hash was
-        reused) of the next header: everything `verify_adjacent` checks
-        of it short of its signatures. The plan is None for a commit
-        whose lanes cannot be planned (an aggregate seal is one pairing
-        check, not lanes). A set equal, member for member, to the header
-        before's takes that set's hash instead of its own merkle;
-        `validate_basic` still binds it to this header's
-        `validators_hash`."""
+                     target: LightBlock, now: Timestamp,
+                     cache) -> planner.VerifyStep:
+        """The next header as a step: everything `verify_adjacent`
+        checks of it short of its signatures, and the lanes its +2/3
+        check takes; a commit sealed by an aggregate (one pairing check,
+        not lanes) is verified here, whole, and takes none. A set equal,
+        member for member, to the header before's takes that set's hash
+        instead of its own merkle; `validate_basic` still binds it to
+        this header's `validators_hash`."""
         nxt = (target if height == target.height
                else self.primary.light_block(height))
         reused = (nxt.validator_set is not None and
@@ -280,102 +325,138 @@ class LightClient:
         verifier.check_adjacent(self.chain_id, cur, nxt,
                                 self.trusting_period, now)
         if isinstance(nxt.signed_header.commit, AggregatedCommit):
-            return nxt, None, reused
-        return (nxt, verifier.plan_own_commit(self.chain_id, nxt, cache),
-                reused)
+            verifier.verify_own_commit(self.chain_id, nxt)
+            checks = []
+        else:
+            checks = [verifier.plan_own_commit(self.chain_id, nxt, cache)]
+        return planner.VerifyStep(nxt, True, checks, reused=reused)
 
-    def _settle_tile(self, tile, span) -> None:
-        """Verify the planned lanes of a tile's headers in one flush,
-        then trust the headers in order up to the first whose lanes did
-        not all verify true, and raise for that one what the walk one
-        header at a time raises."""
+    def _verify_skipping(self, trusted: LightBlock, target: LightBlock,
+                         now: Timestamp) -> None:
+        """Bisection (reference light/client.go:705-772 verifySkipping),
+        planned: the schedule from the trusted header to the target is
+        built with no signature verified (`planner.plan_update`: the
+        reference's pivots, every check that needs no signature, the
+        lanes each check takes), the lanes of every check of its steps
+        are verified in ONE flush, and only then are the steps trusted,
+        in order (`_settle`); a schedule whose lanes pass BISECT_LANES
+        settles in parts of that size. Power is tallied before any
+        signature is verified, whatever the lane count (the reference's
+        batch route). No header enters the store before every lane of
+        every check of its step, and of every step before it, has
+        verified true; what the plan refuses at step j is raised after
+        the steps before it are verified and trusted, as the walk one
+        hop at a time raises it."""
         tracer = shared_tracer()
-        plans = [planned for _lb, planned, _r in tile if planned is not None]
-        lanes = [lane for planned in plans for lane in planned.lanes]
-        hits = sum(planned.cache_hits for planned in plans)
+        fetched = []
+
+        def fetch(height: int) -> LightBlock:
+            fetched.append(height)
+            return self.primary.light_block(height)
+
+        plan = planner.plan_update(
+            self.chain_id, trusted, target, fetch, now,
+            self.trusting_period, self.trust_level, shared_cache(),
+            verifier.CACHE_PATH, whole_seals=True)
+        totals = dict.fromkeys(("steps", "lanes", "lanes_distinct",
+                                "cache_hits"), 0)
+        refused = failed = None
+        planned_all = False
+        with tracer.start("light.bisect", from_height=trusted.height,
+                          height=target.height) as span:
+            while not planned_all and failed is None:
+                steps, room = [], BISECT_LANES
+                with tracer.start("light.bisect.plan", parent=span):
+                    try:
+                        while room > 0:
+                            step = next(plan, None)
+                            if step is None:
+                                planned_all = True
+                                break
+                            steps.append(step)
+                            room -= _room(step)
+                    except Exception as e:
+                        # the steps planned before it are still
+                        # verified and saved first
+                        refused, planned_all = e, True
+                if steps:
+                    numbers, failed = self._settle(steps, span, "bisect")
+                    for key in totals:
+                        totals[key] += numbers[key]
+            span.set_attr("pivots_fetched", len(fetched))
+            for key, n in totals.items():
+                span.set_attr(key, n)
+        _count("bisect", {"updates": 1, "fetches": len(fetched)})
+        if failed is not None:
+            raise failed
+        if refused is not None:
+            raise refused
+
+    def _settle(self, steps, span, walk: str) -> tuple:
+        """Verify the planned lanes of `steps` (a tile's headers, or a
+        bisection's steps) in one flush, a signature that two checks
+        take (same key, sign-bytes and signature: the cache key) as one
+        lane whose verdict both read; then trust the steps in order up
+        to the first with a check whose lane did not verify true.
+        Counts on `walk`'s counters; returns the numbers counted and
+        what the walk one step at a time raises for the step that
+        failed (None where none did)."""
+        tracer = shared_tracer()
+        name = "light" if walk == "tile" else "light.bisect"
+        lanes, slot, ends = [], {}, []
+        for step in steps:
+            for check in step.checks:
+                for lane in check.lanes:
+                    if lane.key not in slot:
+                        slot[lane.key] = len(lanes)
+                        lanes.append(lane)
+            ends.append(len(lanes))
         oks, flushed = [], False
-        with tracer.start("light.verify", parent=span,
+        with tracer.start(f"{name}.verify", parent=span,
                           lanes=len(lanes)) as vspan:
             if lanes:
-                oks, flushed = _verify_lanes(tile[0][0].validator_set,
+                oks, flushed = _verify_lanes(steps[0].lb.validator_set,
                                              lanes)
-            if len(plans) < len(tile):      # the one unplanned commit
-                verifier.verify_own_commit(self.chain_id, tile[0][0])
             device = len(lanes) if flushed else 0
             vspan.set_attr("device_lanes", device)
             vspan.set_attr("native_lanes", len(lanes) - device)
         cache = shared_cache()
-        failed, saved, at = None, 0, 0
+        failed, saved = None, 0
         encoded, ts_reused = SIG_TS_PREFIX
-        with tracer.start("light.save", parent=span) as sspan, \
+        with tracer.start(f"{name}.save", parent=span) as sspan, \
                 insert_span_attrs(cache, sspan):
-            for lb, planned, _r in tile:
-                mine = planned.lanes if planned is not None else ()
-                failed = next((lane for lane, ok in zip(
-                    mine, oks[at:at + len(mine)]) if not ok), None)
-                at += len(mine)
+            for step in steps:
+                failed = next((verifier.wrong_signature(lane, check.kind)
+                               for check in step.checks
+                               for lane in check.lanes
+                               if not oks[slot[lane.key]]), None)
                 if failed is not None:
                     break
-                self.store.save_light_block(lb)
+                self.store.save_light_block(step.lb)
                 saved += 1
             # each lane on its own verdict, as everywhere: the true lanes
-            # of the headers saved and of the one that failed, in one
+            # of the steps saved and of the one that failed, in one
             # insert with their lookups' keys
+            at = ends[saved] if failed is not None else len(lanes)
             cache.insert([lane.key for lane, ok in zip(lanes[:at], oks)
                           if ok])
             encoded = SIG_TS_PREFIX[0] - encoded
             ts_reused = SIG_TS_PREFIX[1] - ts_reused
             sspan.set_attr("sig_encodings", encoded)
             sspan.set_attr("sig_ts_prefix_reused", ts_reused)
-        span.set_attr("first_height", tile[0][0].height)
-        span.set_attr("headers", saved)
-        span.set_attr("lanes", len(lanes))
-        span.set_attr("cache_hits", hits)
-        with _tile_lock:
-            for key, n in (("headers", saved), ("tiles", 1),
-                           ("flushes", int(flushed)),
-                           ("lanes", len(lanes)),
-                           ("device_lanes", device),
-                           ("native_lanes", len(lanes) - device),
-                           ("cache_hits", hits),
-                           ("set_hashes_reused",
-                            sum(r for _lb, _p, r in tile[:saved])),
-                           ("sig_encodings", encoded),
-                           ("sig_ts_prefix_reused", ts_reused)):
-                _tile_counts[key] += n
-        if failed is not None:
-            raise verifier.wrong_signature(failed)
-
-    def _verify_skipping(self, trusted: LightBlock, target: LightBlock,
-                         now: Timestamp) -> None:
-        """Bisection (reference light/client.go:705-772 verifySkipping):
-        try the jump; when the trusted set can't vouch (<1/3 overlap),
-        bisect toward the trusted header until it can."""
-        cur = trusted
-        pivots = [target]
-        while pivots:
-            candidate = pivots[-1]
-            try:
-                if candidate.height == cur.height + 1:
-                    verifier.verify_adjacent(
-                        self.chain_id, cur, candidate,
-                        self.trusting_period, now)
-                else:
-                    verifier.verify_non_adjacent(
-                        self.chain_id, cur, candidate,
-                        self.trusting_period, now, self.trust_level)
-            except verifier.ErrNewValSetCantBeTrusted:
-                mid = (cur.height + candidate.height) // 2
-                if mid in (cur.height, candidate.height):
-                    raise LightClientError(
-                        "bisection cannot make progress")
-                lb = self.primary.light_block(mid)
-                lb.validate_basic(self.chain_id)
-                pivots.append(lb)
-                continue
-            self.store.save_light_block(candidate)
-            cur = candidate
-            pivots.pop()
+        checks = [check for step in steps for check in step.checks]
+        numbers = {"headers": saved, "steps": saved, "tiles": 1,
+                   "flushes": int(flushed),
+                   "lanes": sum(len(check.lanes) for check in checks),
+                   "lanes_distinct": len(lanes), "device_lanes": device,
+                   "native_lanes": len(lanes) - device,
+                   "cache_hits": sum(check.cache_hits for check in checks),
+                   "set_hashes_reused": sum(step.reused
+                                            for step in steps[:saved]),
+                   "sig_encodings": encoded,
+                   "sig_ts_prefix_reused": ts_reused}
+        _count(walk, numbers)
+        return numbers, failed
 
     def _verify_backwards(self, height: int) -> LightBlock:
         """Hash-linked walk down from the closest trusted header above

@@ -42,6 +42,13 @@ class ErrInvalidHeader(VerificationError):
     pass
 
 
+class PlanBudgetExceeded(VerificationError):
+    """The bisection needed more provider fetches than the caller's
+    budget allows (`planner.plan_update`'s `max_fetches`): a byzantine
+    target, or a pathological set-rotation chain, must not let one
+    client pin a shared service."""
+
+
 def _expired(trusted: LightBlock, trusting_period_s: int,
              now: Timestamp) -> bool:
     """reference light/verifier.go:204 HeaderExpired."""
@@ -88,6 +95,20 @@ def check_adjacent(chain_id: str, trusted: LightBlock,
             "untrusted validators_hash != trusted next_validators_hash")
 
 
+def check_non_adjacent(chain_id: str, trusted: LightBlock,
+                       untrusted: LightBlock, trusting_period_s: int,
+                       now: Timestamp,
+                       max_drift_s: int = MAX_CLOCK_DRIFT_SECONDS) -> None:
+    """The part of `verify_non_adjacent` that needs no signature and no
+    tally: a jump, expiry of the trusted header, the untrusted header's
+    structure and times."""
+    if untrusted.height == trusted.height + 1:
+        raise ErrInvalidHeader("use verify_adjacent for adjacent headers")
+    if _expired(trusted, trusting_period_s, now):
+        raise ErrOldHeader("trusted header expired")
+    _validate_untrusted(chain_id, trusted, untrusted, now, max_drift_s)
+
+
 def verify_own_commit(chain_id: str, untrusted: LightBlock) -> None:
     """+2/3 of the header's own set signed its commit."""
     try:
@@ -99,8 +120,8 @@ def verify_own_commit(chain_id: str, untrusted: LightBlock) -> None:
         raise ErrInvalidHeader(f"invalid commit: {e}") from e
 
 
-def plan_own_commit(chain_id: str, untrusted: LightBlock,
-                    cache) -> planner.PlannedCheck:
+def plan_own_commit(chain_id: str, untrusted: LightBlock, cache,
+                    path: str = CACHE_PATH) -> planner.PlannedCheck:
     """`verify_own_commit` with the verification deferred: the lanes
     the rule takes, none verified; raises what it raises for whatever
     no signature decides."""
@@ -109,15 +130,41 @@ def plan_own_commit(chain_id: str, untrusted: LightBlock,
             chain_id, untrusted.validator_set,
             untrusted.signed_header.commit.block_id,
             untrusted.height, untrusted.signed_header.commit, cache,
-            path=CACHE_PATH)
+            path=path)
     except validation.CommitVerificationError as e:
         raise ErrInvalidHeader(f"invalid commit: {e}") from e
 
 
-def wrong_signature(lane: planner.Lane) -> ErrInvalidHeader:
-    """What `verify_own_commit` raises for a planned lane that failed."""
+def plan_trusting_commit(chain_id: str, trusted: LightBlock,
+                         untrusted: LightBlock,
+                         trust_level: validation.Fraction, cache,
+                         path: str = CACHE_PATH) -> planner.PlannedCheck:
+    """The trusting check of `verify_non_adjacent` with the verification
+    deferred: the lanes it takes, none verified; raises what it raises
+    for whatever no signature decides (ErrNewValSetCantBeTrusted where
+    the trusted set's power does not reach `trust_level`)."""
+    try:
+        return planner.plan_commit_trusting(
+            chain_id, trusted.validator_set,
+            untrusted.signed_header.commit, trust_level, cache, path=path)
+    except validation.ErrNotEnoughVotingPowerSigned as e:
+        raise ErrNewValSetCantBeTrusted(str(e)) from e
+    except validation.CommitVerificationError as e:
+        raise ErrInvalidHeader(f"trusting verify failed: {e}") from e
+
+
+# what each check raises over a signature that does not verify
+_CHECK_PREFIX = {"light": "invalid commit",
+                 "trusting": "trusting verify failed"}
+
+
+def wrong_signature(lane: planner.Lane,
+                    kind: str = "light") -> ErrInvalidHeader:
+    """What `verify_own_commit` (`kind` "light") or the trusting check
+    of `verify_non_adjacent` ("trusting") raises for a planned lane
+    that failed."""
     cause = validation.ErrWrongSignature(lane.sig_index, lane.sig)
-    err = ErrInvalidHeader(f"invalid commit: {cause}")
+    err = ErrInvalidHeader(f"{_CHECK_PREFIX[kind]}: {cause}")
     err.__cause__ = cause
     return err
 
@@ -139,11 +186,8 @@ def verify_non_adjacent(chain_id: str, trusted: LightBlock,
                         validation.DEFAULT_TRUST_LEVEL,
                         max_drift_s: int = MAX_CLOCK_DRIFT_SECONDS) -> None:
     """reference light/verifier.go:30-88 VerifyNonAdjacent."""
-    if untrusted.height == trusted.height + 1:
-        raise ErrInvalidHeader("use verify_adjacent for adjacent headers")
-    if _expired(trusted, trusting_period_s, now):
-        raise ErrOldHeader("trusted header expired")
-    _validate_untrusted(chain_id, trusted, untrusted, now, max_drift_s)
+    check_non_adjacent(chain_id, trusted, untrusted, trusting_period_s,
+                       now, max_drift_s)
     try:
         validation.verify_commit_light_trusting(
             chain_id, trusted.validator_set,

@@ -599,3 +599,27 @@ def test_farm_wait_adaptive_early_flush(chain):
     assert ticket.ok()
     assert dt < window * 0.9, \
         f"adaptive wait took {dt:.3f}s, fixed window is {window}s"
+
+
+def test_farm_planner_refuses_aggregate_seals():
+    """An aggregate seal is one pairing check, not signature lanes, and
+    the farm's batcher verifies lanes only: the farm's planner refuses a
+    hop so sealed, where the light client's (`whole_seals`) verifies it
+    whole while planning."""
+    from cometbft_tpu.farm import planner as farm_planner
+    from cometbft_tpu.light import planner as light_planner
+    from cometbft_tpu.light import verifier
+    from cometbft_tpu.types.validation import DEFAULT_TRUST_LEVEL
+    agg = generate_chain(n_blocks=4, n_validators=4, txs_per_block=1,
+                         chain_id="farm-agg", seed=7, key_type="bls12_381",
+                         aggregate=True)
+    provider = ChainLightProvider(agg)
+    trusted, target = provider.light_block(1), provider.light_block(3)
+    args = (agg.chain_id, trusted, target, provider.light_block,
+            Timestamp(1_700_000_000 + 100, 0), TRUST_PERIOD,
+            DEFAULT_TRUST_LEVEL, SigCache(1024))
+    with pytest.raises(verifier.ErrInvalidHeader,
+                       match="trusting verify failed: invalid signature"):
+        list(farm_planner.plan_update(*args))
+    steps = list(light_planner.plan_update(*args, "light", whole_seals=True))
+    assert [(s.lb.height, s.checks) for s in steps] == [(3, [])]

@@ -74,7 +74,7 @@ def check_decisions(records):
     acceptance rules; returns violation strings (empty = all conform).
 
     Each record states one farm/light acceptance as its power tallies
-    (farm/planner._record): `adjacent`, `valhash_bound`, `own_signed` /
+    (light/planner._record): `adjacent`, `valhash_bound`, `own_signed` /
     `own_total` (the header's own claimed set on its commit), and for
     skipping steps `trusted_signed` / `trusted_total` (trusted-set
     power that signed) plus the trust fraction. This is the bridge the
